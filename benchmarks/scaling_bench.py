@@ -177,6 +177,9 @@ def bench_devices(device_counts, iters, timings):
     for ndev in device_counts:
         env = dict(os.environ)
         env["PYTHONPATH"] = str(SRC)
+        # forced host devices: the child stays on the CPU, off any
+        # accelerator this parent process already holds
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={ndev}")
         out = subprocess.run(

@@ -66,6 +66,7 @@ from repro.core.driver import StalenessSchedule, damped_alpha
 from repro.core.flecs import FlecsConfig, FlecsHParams
 from repro.core.traffic import ArrivalSchedule, TrafficModel
 from repro.data.logreg import make_problem
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.baselines import (DianaConfig, DianaHParams, FedNLConfig,
                                    FedNLHParams, GDConfig, GDHParams)
 
@@ -157,6 +158,7 @@ def print_rows(res, ps, budget=0.0):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--d", type=int, default=123)
     ap.add_argument("--iters", type=int, default=200)

@@ -14,9 +14,11 @@ import numpy as np
 from repro.core.driver import run_experiment
 from repro.core.flecs import FlecsConfig, init_state, make_flecs_step
 from repro.data.logreg import make_problem
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     prob = make_problem(d=123, n_workers=20, r=64, mu=1e-3, seed=0)
     local_grad, local_hvp = prob.make_oracles()
 

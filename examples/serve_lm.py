@@ -14,11 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import CPU_CTX, init_params, prefill
 from repro.train.step import make_serve_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--smoke", action="store_true", default=True)

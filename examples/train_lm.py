@@ -20,7 +20,9 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import ATTN_GLOBAL, FFN_DENSE, ModelConfig, uniform_plan
-from repro.core.dl_flecs import FlecsDLConfig, make_flecs_train_step
+from repro.core.dl_flecs import (FlecsDLConfig, init_shifts,
+                                 make_flecs_train_step)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.sharding import batch_specs, named_shardings
 from repro.models.context import ModelContext
 from repro.models.model import init_params
@@ -52,6 +54,7 @@ def token_stream(cfg, rng, batch, seq, n_workers=4):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--preset", choices=["100m"], default=None)
@@ -92,8 +95,7 @@ def main():
         bshard = named_shardings(ba, mesh, batch_specs(ba, mesh, ("data",)))
         lower = make_flecs_train_step(cfg, ctx, fcfg)
         jitted, shifts_abs = lower.build(pa, ba, pshard, bshard)
-        shifts = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
-                              shifts_abs)
+        shifts = init_shifts(shifts_abs)
         t0 = time.time()
         for step_i in range(args.steps):
             batch = next(stream)

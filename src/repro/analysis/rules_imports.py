@@ -1,17 +1,16 @@
 """R4 — jax.experimental access must go through ``repro.compat``.
 
-``shard_map`` moved between jax 0.4 and 0.5 (``jax.experimental.shard_map``
-→ ``jax.sharding``/top-level), and ``axis_size`` similarly has no single
-stable home.  ``src/repro/compat.py`` is the one module allowed to probe
-those locations; everything else imports the shims from it, so a jax
-version bump is a one-file change.  This rule flags:
+``shard_map`` has lived in more than one place in jax
+(``jax.experimental.shard_map``, then top-level), and so has
+``axis_size``.  ``src/repro/compat.py`` is the one module that names
+them; everything else imports them from it, so a move in jax's surface
+is a one-file change.  This rule flags:
 
 * ``import jax.experimental.shard_map`` / ``from jax.experimental[.x]
   import shard_map`` anywhere outside ``compat.py``;
 * ``from jax.experimental import ...`` of the shimmed names generally;
 * attribute chains ``jax.experimental.shard_map...`` /
-  ``jax.lax.axis_size`` / ``lax.axis_size`` used directly (the compat
-  shim ``axis_size`` handles the version probe).
+  ``jax.lax.axis_size`` / ``lax.axis_size`` used directly.
 
 Scope: the whole repo (``src/``, ``scripts/``, ``tests/``, ``examples/``)
 minus ``src/repro/compat.py`` itself.
@@ -76,8 +75,7 @@ def check_compat_imports(ctx: ModuleContext) -> Iterable[Finding]:
                 findings.append(ctx.finding(
                     "R4", node,
                     f"`from {mod} import {', '.join(bad)}` bypasses "
-                    "repro.compat — the 0.4/0.5 shim layer is the only "
-                    "sanctioned import site for "
+                    "repro.compat — the only sanctioned import site for "
                     f"{sorted(SHIMMED_NAMES)}"))
         elif isinstance(node, ast.Attribute):
             chain = _attr_chain(node)
@@ -96,6 +94,6 @@ def check_compat_imports(ctx: ModuleContext) -> Iterable[Finding]:
                 flagged_lines.add(node.lineno)
                 findings.append(ctx.finding(
                     "R4", node,
-                    f"`{'.'.join(chain)}` is not stable across jax "
-                    "versions — use repro.compat.axis_size"))
+                    f"`{'.'.join(chain)}` used directly — use "
+                    "repro.compat.axis_size"))
     return findings

@@ -1,11 +1,8 @@
-"""Version compatibility shims for the installed jax.
+"""The one routing point for jax's mesh primitives.
 
-``jax.shard_map`` (top-level, with ``axis_names``/``check_vma``) only
-exists from jax 0.5; on 0.4.x the same feature lives at
-``jax.experimental.shard_map.shard_map`` with ``auto``/``check_rep``
-(``auto`` is the complement of ``axis_names``: the mesh axes that stay
-under GSPMD instead of going manual).  All shard_map call sites in this
-repo go through :func:`shard_map` so the suite runs on both.
+Every shard_map call site in this repo goes through :func:`shard_map`,
+and every mapped-axis size query through :func:`axis_size` (analysis
+rule R4 checks this), so a change of jax's surface lands in one file.
 """
 from __future__ import annotations
 
@@ -14,29 +11,19 @@ import jax
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """jax.shard_map with the ≥0.5 keyword surface on any installed jax.
+    """``jax.shard_map``.
 
-    axis_names: mesh axes to run manually (None => all of them).
-    check_vma:  the ≥0.5 name for 0.4's check_rep.
+    axis_names: mesh axes to run manually (None => all of them); the
+    others stay under GSPMD.
     """
-    if hasattr(jax, "shard_map"):  # jax >= 0.5
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset()
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` (≥0.5) on any jax: the size of a mapped mesh
-    axis from inside shard_map.  On 0.4.x, psum of 1 over the axis — jax
-    resolves it to a compile-time constant."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """``jax.lax.axis_size``: the size of a mapped mesh axis from inside
+    shard_map."""
+    return jax.lax.axis_size(axis_name)

@@ -426,12 +426,14 @@ class ExperimentPlan:
 @dataclasses.dataclass
 class PlanResult:
     """run_plan output: per-run final sweep states / traces / hparams,
-    keyed by run label (leading [G] grid axis on every array)."""
+    keyed by run label (leading [G] grid axis on every array), and the
+    plan's compiled program (``compiled.as_text()`` is its HLO)."""
     labels: Tuple[str, ...]
     states: Dict[str, Any]
     traces: Dict[str, Any]
     hparams: Dict[str, Any]
     seconds: float
+    compiled: Any = None
 
     def __getitem__(self, label: str):
         return self.states[label], self.traces[label]
@@ -664,12 +666,13 @@ def run_plan(plan: ExperimentPlan) -> PlanResult:
 
     _STATS["programs"] += 1
     t0 = time.perf_counter()
-    out = jax.jit(program)(tuple(states), tuple(hps), tuple(keys))
-    out = jax.block_until_ready(out)
+    args = (tuple(states), tuple(hps), tuple(keys))
+    compiled = jax.jit(program).lower(*args).compile()
+    out = jax.block_until_ready(compiled(*args))
     dt = time.perf_counter() - t0
     return PlanResult(
         labels=tuple(labels),
         states={lab: o[0] for lab, o in zip(labels, out)},
         traces={lab: o[1] for lab, o in zip(labels, out)},
         hparams={lab: hp for lab, hp in zip(labels, hps)},
-        seconds=dt)
+        seconds=dt, compiled=compiled)

@@ -83,6 +83,9 @@ from typing import NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.compressor import ops as kernel_ops
+from repro.numerics import ceil_log2
+
 # Family ids — the lax.switch branch index of every spec-dispatched op.
 FAMILY_IDENTITY = 0
 FAMILY_DITHER = 1
@@ -341,7 +344,7 @@ def dither(key, x, s):
 
 def dither_bits(s):
     """Wire bits/value of s-level dithering, ceil(log2(2s+1)); traced-safe."""
-    return jnp.ceil(jnp.log2(2.0 * s + 1.0))
+    return ceil_log2(2.0 * s + 1.0)
 
 
 def _natural(key, x):
@@ -454,61 +457,38 @@ def _minmax(key, x, frac):
 
 
 # ---------------------------------------------------------------------------
-# Fused-kernel dispatch (the optional repro.kernels.compressor layer)
+# Fused-kernel dispatch (repro.kernels.compressor)
 # ---------------------------------------------------------------------------
-
-_KERNEL_OPS = None      # unresolved; False once probed and unavailable
-
-
-def _kernel_ops():
-    """Resolve the optional fused-kernel layer once.  Returns the
-    ``repro.kernels.compressor.ops`` module, or None when pallas (or the
-    kernel package) is unavailable — callers then fall back to the jnp
-    path, which the kernels are bit-identical to, so the fallback is
-    numerics-free by construction."""
-    global _KERNEL_OPS
-    if _KERNEL_OPS is None:
-        try:
-            from repro.kernels.compressor import ops as kernel_ops
-            _KERNEL_OPS = kernel_ops
-        except ImportError:             # pallas absent: jnp path only
-            _KERNEL_OPS = False
-    return _KERNEL_OPS or None
-
 
 def _dither_impl(key, x, s, use_kernel):
     """Dither branch body: the fused Pallas kernel when requested and
     statically eligible (``ops.supports``), else the jnp reference."""
-    ops = _kernel_ops() if use_kernel else None
-    if ops is not None and ops.supports(x):
-        return ops.fused_dither(key, x, s)[0]
+    if use_kernel and kernel_ops.supports(x):
+        return kernel_ops.fused_dither(key, x, s)[0]
     return _dither(key, x, s)
 
 
 def _topk_impl(key, x, frac, use_kernel):
     """Top-k branch body: fused kernel when eligible, else jnp."""
-    ops = _kernel_ops() if use_kernel else None
-    if ops is not None and ops.supports(x):
-        return ops.fused_topk(key, x, frac)[0]
+    if use_kernel and kernel_ops.supports(x):
+        return kernel_ops.fused_topk(key, x, frac)[0]
     return _topk(key, x, frac)
 
 
 def _dither_bits_impl(s, d, use_kernel):
     """Dither ledger branch: the bits-only kernel shares its formula
     with the fused kernel's in-pass count, so both prices agree."""
-    ops = _kernel_ops() if use_kernel else None
-    if ops is not None:
-        return ops.dither_bits_fused(s, d)
+    if use_kernel:
+        return kernel_ops.dither_bits_fused(s, d)
     return dither_bits(s) * d
 
 
 def _topk_bits_impl(frac, d, kept, use_kernel):
     """Top-k ledger branch (``kept`` precomputed by the caller so the
     jnp expression stays identical to the pre-kernel code)."""
-    ops = _kernel_ops() if use_kernel else None
-    if ops is not None:
-        return ops.topk_bits_fused(frac, d)
-    return kept * (32.0 + jnp.ceil(jnp.log2(jnp.maximum(d, 1.0))))
+    if use_kernel:
+        return kernel_ops.topk_bits_fused(frac, d)
+    return kept * (32.0 + ceil_log2(jnp.maximum(d, 1.0)))
 
 
 # ---------------------------------------------------------------------------
@@ -524,8 +504,8 @@ def compress(spec: CompressorSpec, key, x, use_kernel: bool = False
     families through the fused Pallas kernels
     (``repro.kernels.compressor``, interpret mode off-TPU) when the
     tensor is eligible; identity/natural and the sketch families — and
-    ineligible tensors, and environments without pallas — keep the jnp
-    path.  The kernels are bit-identical to the jnp reference under a
+    tensors past the kernels' documented limit (``ops.supports``) — keep
+    the jnp path.  The kernels are bit-identical to the jnp reference under a
     consistent evaluation context (the differential suite in
     tests/test_kernels.py pins it), so the two paths are interchangeable
     mid-run."""
@@ -565,7 +545,7 @@ def spec_bits(spec: CompressorSpec, d, use_kernel: bool = False
     spec = fill_params(spec)
     d = jnp.asarray(d, jnp.float32)
     kept = jnp.clip(jnp.ceil(spec.frac * d), 1.0, d)
-    idx_bits = 32.0 + jnp.ceil(jnp.log2(jnp.maximum(d, 1.0)))
+    idx_bits = 32.0 + ceil_log2(jnp.maximum(d, 1.0))
     dep = jnp.clip(jnp.floor(spec.params.depth), 1.0,
                    float(SKETCH_DEPTH_MAX))
     wc = jnp.clip(jnp.floor(spec.params.width), 1.0, d)

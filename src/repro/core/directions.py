@@ -13,6 +13,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.numerics import matmul
+
 
 def truncate_eigs(lam, omega: float, Omega: float):
     """Definition 7, with one safeguard deviation (documented in DESIGN.md):
@@ -36,14 +38,14 @@ def truncated_inverse_direction_floored(B, grad, omega, Omega, floor):
     lam, V = jnp.linalg.eigh(0.5 * (B + B.T))
     a = jnp.abs(lam)
     lam_t = jnp.where(a >= floor, jnp.clip(a, omega, Omega), Omega)
-    return -(V @ ((V.T @ grad) / lam_t))
+    return -matmul(V, matmul(V.T, grad) / lam_t)
 
 
 def truncated_inverse_direction(B, grad, omega: float, Omega: float):
     """Alg 4: p = -(|B|_ω^Ω)^{-1} ∇F.  B: [d,d] symmetric."""
     lam, V = jnp.linalg.eigh(0.5 * (B + B.T))
     lam_t = truncate_eigs(lam, omega, Omega)
-    p = -(V @ ((V.T @ grad) / lam_t))
+    p = -matmul(V, matmul(V.T, grad) / lam_t)
     return p
 
 
@@ -56,12 +58,12 @@ def fedsonia_direction(Y_tilde, M, grad, omega: float, Omega: float,
     where g_∥ is the projection of ∇F onto span(Q).
     """
     Q, R = jnp.linalg.qr(Y_tilde)                       # d x m, m x m
-    core = R @ jnp.linalg.pinv(M, rcond=1e-10) @ R.T    # m x m
+    core = matmul(matmul(R, jnp.linalg.pinv(M, rcond=1e-10)), R.T)  # m x m
     lam, V = jnp.linalg.eigh(0.5 * (core + core.T))
     lam_t = truncate_eigs(lam, omega, Omega)
-    Vq = Q @ V                                          # d x m orthonormal
-    coef = Vq.T @ grad                                  # m
-    g_par = Vq @ coef
+    Vq = matmul(Q, V)                                   # d x m orthonormal
+    coef = matmul(Vq.T, grad)                           # m
+    g_par = matmul(Vq, coef)
     g_perp = grad - g_par
-    p = -(Vq @ (coef / lam_t)) - rho * g_perp
+    p = -matmul(Vq, coef / lam_t) - rho * g_perp
     return p

@@ -223,14 +223,25 @@ def make_flecs_train_step(cfg: ModelConfig, ctx: ModelContext,
                    "uplink_mbits": payload_bits / 1e6}
         return new_params, new_shifts, metrics
 
+    ns = lambda sp: jax.sharding.NamedSharding(mesh, sp)
+    is_spec = lambda sp: isinstance(sp, P)
+
+    def param_shardings(pshard):
+        """The step's param shardings: ``pshard`` with the data axes
+        stripped — each worker holds the full model.  jit-level shardings
+        keep the model axis (auto); shard_map in_specs may only mention
+        MANUAL axes, over which params are replicated."""
+        return jax.tree.map(
+            lambda s: ns(strip_data(s.spec if hasattr(s, "spec") else s)),
+            pshard,
+            is_leaf=lambda s: isinstance(s, (jax.sharding.NamedSharding, P)))
+
     def build(params_abs, batch_abs, pshard, bshard):
         """Construct the shard_mapped step + shardings (shared by lower()
-        and the executable path)."""
-        # jit-level shardings keep the model axis (auto); shard_map in_specs
-        # may only mention MANUAL axes — params are replicated over those.
-        pspec_rep = jax.tree.map(
-            lambda s: strip_data(s.spec if hasattr(s, "spec") else s), pshard,
-            is_leaf=lambda s: isinstance(s, (jax.sharding.NamedSharding, P)))
+        and the executable path).  Returns (jitted, shifts_abs): the
+        shifts' shapes carry their shardings, so callers can allocate them
+        in place (``init_shifts``); params go in with
+        ``param_shardings(pshard)``."""
         prep = jax.tree.map(lambda _: P(), params_abs)
         n_data = 1
         for a in axes:
@@ -253,12 +264,17 @@ def make_flecs_train_step(cfg: ModelConfig, ctx: ModelContext,
             in_specs=(prep, sspec, bspec, P()),
             out_specs=(prep, sspec, P()),
             axis_names=set(axes), check_vma=False)
-        ns = lambda sp: jax.sharding.NamedSharding(mesh, sp)
-        psh = jax.tree.map(ns, pspec_rep, is_leaf=lambda sp: isinstance(sp, P))
-        # shifts: let jit infer — the outputs carry auto (model-axis)
-        # shardings propagated by GSPMD that we cannot predict per leaf, and
-        # round-tripping them through an explicit in_sharding would mismatch.
-        jitted = jax.jit(smapped, in_shardings=(psh, None, bshard, None))
+        psh = param_shardings(pshard)
+        ssh = jax.tree.map(ns, sspec, is_leaf=is_spec)
+        # params and shifts are donated and come back with the shardings
+        # they went in with, so each step updates them in place: without
+        # donation a full-width step holds two copies of both.
+        jitted = jax.jit(smapped, in_shardings=(psh, ssh, bshard, None),
+                         out_shardings=(psh, ssh, None),
+                         donate_argnums=(0, 1))
+        shifts_abs = jax.tree.map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            shifts_abs, ssh)
         return jitted, shifts_abs
 
     def lower(params_abs, batch_abs, pshard, bshard):
@@ -267,4 +283,13 @@ def make_flecs_train_step(cfg: ModelConfig, ctx: ModelContext,
         return jitted.lower(params_abs, shifts_abs, batch_abs, step_sds)
 
     lower.build = build
+    lower.param_shardings = param_shardings
     return lower
+
+
+def init_shifts(shifts_abs):
+    """Zero shifts allocated directly with the shardings ``build`` chose —
+    never gathered onto one device first (the per-worker shifts of a
+    full-width model on n workers would not fit there)."""
+    return jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype, device=a.sharding), shifts_abs)

@@ -93,6 +93,7 @@ from repro.core.sketch import sketch
 from repro.core.traffic import (TrafficHParams, TrafficModel, TrafficState,
                                 admit_arrivals, traffic_send)
 from repro.core.updates import direct_update, truncated_lsr1_update
+from repro.numerics import matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -308,9 +309,9 @@ def _worker_messages(local_grad: Callable, local_hvp: Callable,
     def worker(i, hk, Bk, kq, kc):
         g = local_grad(w, i, jax.random.fold_in(k_g, i))
         Y = local_hvp(w, S, i, jax.random.fold_in(k_h, i))
-        M = S.T @ Y                                     # m x m (exact)
+        M = matmul(S.T, Y)                              # m x m (exact)
         c = compress(grad_spec, kq, g - hk, use_kernel)   # grad diff
-        BS = Bk @ S
+        BS = matmul(Bk, S)
         Cm = compress(hess_spec, kc, Y - BS, use_kernel)  # hess diff
         return c, M, Cm, BS
 

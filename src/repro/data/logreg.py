@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.numerics import einsum, matmul
+
 PAPER_DIMS = {"a9a": 123, "gisette": 5000, "real-sim": 20958}
 
 
@@ -37,12 +39,12 @@ class FederatedLogReg:
 
     # ---- objective ------------------------------------------------------
     def local_loss(self, w, i):
-        z = self.b[i] * (self.A[i] @ w)
-        return jnp.mean(jnp.logaddexp(0.0, -z)) + 0.5 * self.mu * w @ w
+        z = self.b[i] * matmul(self.A[i], w)
+        return jnp.mean(jnp.logaddexp(0.0, -z)) + 0.5 * self.mu * matmul(w, w)
 
     def global_loss(self, w):
-        z = self.b * jnp.einsum("nrd,d->nr", self.A, w)
-        return jnp.mean(jnp.logaddexp(0.0, -z)) + 0.5 * self.mu * w @ w
+        z = self.b * einsum("nrd,d->nr", self.A, w)
+        return jnp.mean(jnp.logaddexp(0.0, -z)) + 0.5 * self.mu * matmul(w, w)
 
     def global_grad(self, w):
         return jax.grad(self.global_loss)(w)
@@ -77,8 +79,9 @@ class FederatedLogReg:
             return self.A[i], self.b[i]
 
         def loss(w, Ai, bi):
-            z = bi * (Ai @ w)
-            return jnp.mean(jnp.logaddexp(0.0, -z)) + 0.5 * self.mu * w @ w
+            z = bi * matmul(Ai, w)
+            return (jnp.mean(jnp.logaddexp(0.0, -z))
+                    + 0.5 * self.mu * matmul(w, w))
 
         def local_grad(w, i, key):
             Ai, bi = pick(i, key)
@@ -132,14 +135,14 @@ class VirtualLogReg:
         shift = (jax.random.normal(k_s, (self.d,))
                  * self.heterogeneity * inv)
         A = jax.random.normal(k_a, (self.r, self.d)) * inv + shift
-        p = jax.nn.sigmoid(A @ self.w_true)
+        p = jax.nn.sigmoid(matmul(A, self.w_true))
         b = jnp.where(jax.random.uniform(k_b, (self.r,)) < p, 1.0, -1.0)
         flip = jax.random.uniform(k_f, (self.r,)) < self.label_noise
         return A, jnp.where(flip, -b, b)
 
     def _loss(self, w, Ai, bi):
-        z = bi * (Ai @ w)
-        return jnp.mean(jnp.logaddexp(0.0, -z)) + 0.5 * self.mu * w @ w
+        z = bi * matmul(Ai, w)
+        return jnp.mean(jnp.logaddexp(0.0, -z)) + 0.5 * self.mu * matmul(w, w)
 
     def local_loss(self, w, i):
         return self._loss(w, *self._shard(i))

@@ -36,8 +36,11 @@ All kernels are gridless — the wrapper (ops.py) pads the flattened
 tensor into one [rows, 128] VMEM block and there is no ``pl.program_id``
 — which keeps them safe under ``jax.vmap``: pallas batches a kernel by
 prepending a grid dimension, which would shift any program_id indexing.
-Traced operands (s, frac, d) enter as (1,) f32 arrays, so compressor
+Traced operands (s, frac, d) enter as (1, 1) f32 arrays, so compressor
 levels and fractions stay sweepable grid axes through the kernel path.
+Every scalar operand and the (1, 1) bit-count outputs live in SMEM: the
+TPU lowering cannot store a scalar to VMEM, and a 2-D (1, 1) block stays
+legal when vmap prepends a batch dimension to it.
 Zero padding is harmless by construction: pads cannot change a max-abs
 reduction, dither maps them to 0, and the top-k tie budget never reaches
 them (k counts real elements only, ties at a zero threshold keep pads at
@@ -50,18 +53,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.numerics import ceil_log2
+
+_VMEM = pl.BlockSpec(memory_space=pltpu.VMEM)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _dither_bits_expr(s, d):
     """spec_bits' dither branch: ⌈log2(2s+1)⌉ bits/value × d values."""
-    return jnp.ceil(jnp.log2(2.0 * s + 1.0)) * d
+    return ceil_log2(2.0 * s + 1.0) * d
 
 
 def _topk_bits_expr(frac, d):
     """spec_bits' top-k branch: ⌈frac·d⌉ kept values, each a 32-bit
     payload plus a ⌈log2 d⌉-bit index (dimension-aware)."""
     kept = jnp.clip(jnp.ceil(frac * d), 1.0, d)
-    return kept * (32.0 + jnp.ceil(jnp.log2(jnp.maximum(d, 1.0))))
+    return kept * (32.0 + ceil_log2(jnp.maximum(d, 1.0)))
 
 
 # ---------------------------------------------------------------------------
@@ -74,7 +83,7 @@ def _fused_dither_kernel(x_ref, u_ref, s_ref, out_ref, bits_ref, *, d: int):
     Mirrors ``compressors._dither`` expression-for-expression; ``d`` is
     the REAL element count (pads excluded) so the ledger is exact."""
     x = x_ref[...]
-    s = s_ref[0]
+    s = s_ref[0, 0]
     norm = jnp.max(jnp.abs(x))                   # pads are 0: never the max
     norm = jnp.where(norm == 0, 1.0, norm)
     y = jnp.abs(x) / norm * s                    # in [0, s]
@@ -82,18 +91,20 @@ def _fused_dither_kernel(x_ref, u_ref, s_ref, out_ref, bits_ref, *, d: int):
     p = y - lo                                   # P(round up)
     level = lo + (u_ref[...] < p)
     out_ref[...] = jnp.sign(x) * level * norm / s
-    bits_ref[0] = _dither_bits_expr(s, jnp.float32(d))
+    bits_ref[0, 0] = _dither_bits_expr(s, jnp.float32(d))
 
 
 def fused_dither_call(x2, u2, s1, *, d: int, interpret: bool):
     """Launch the fused dither kernel on a padded [R, 128] block.
 
-    Returns (quantized [R, 128] f32, payload bits (1,) f32)."""
+    Returns (quantized [R, 128] f32, payload bits (1, 1) f32)."""
     R, C = x2.shape
     return pl.pallas_call(
         functools.partial(_fused_dither_kernel, d=d),
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        in_specs=[_VMEM, _VMEM, _SMEM],
+        out_specs=[_VMEM, _SMEM],
         interpret=interpret,
     )(x2, u2, s1)
 
@@ -110,12 +121,16 @@ def _fused_topk_kernel(x_ref, frac_ref, out_ref, bits_ref, *, d: int):
     still compare >= the candidate threshold.  The float-domain keep mask
     then mirrors ``compressors._topk`` exactly: everything strictly above
     the threshold, plus the lowest-index ties up to the remaining budget
-    (tie ranks are row-major across the padded block, matching the
-    flattened order of the real elements; pads are zeros, and the tie
-    budget can reach them only when the threshold is itself 0 AND every
-    real zero is kept — where keeping a pad writes 0, a no-op)."""
+    k - n_above.  The tie cut is a second greedy search, over the flat
+    index (row·128 + lane, the flattened order of the real elements): the
+    largest cut C with #(ties at index < C) <= budget keeps exactly the
+    ties whose 1-based rank is within the budget — the reference's cumsum
+    rank, without a cumsum (which the TPU lowering lacks).  Pads are
+    zeros, and the tie budget can reach them only when the threshold is
+    itself 0 AND every real zero is kept — where keeping a pad writes 0,
+    a no-op."""
     x = x_ref[...]
-    frac = frac_ref[0]
+    frac = frac_ref[0, 0]
     ax = jnp.abs(x)
     k = jnp.clip(jnp.ceil(frac * d).astype(jnp.int32), 1, d)
     bits = jax.lax.bitcast_convert_type(ax, jnp.int32)
@@ -128,28 +143,40 @@ def _fused_topk_kernel(x_ref, frac_ref, out_ref, bits_ref, *, d: int):
     # NB: "pat" not "bits" — this int32 is a float BIT PATTERN for the
     # threshold search, not a wire-cost ledger (R3 guards the latter).
     thresh_pat = jax.lax.fori_loop(0, 31, grow, jnp.int32(0))
-    thresh = jax.lax.bitcast_convert_type(thresh_pat, jnp.float32)
+    # bitcast as a vector: the TPU lowering bitcasts vectors, not scalars
+    thresh = jax.lax.bitcast_convert_type(
+        jnp.broadcast_to(thresh_pat, x.shape), jnp.float32)
     above = ax > thresh
     n_above = jnp.sum(above.astype(jnp.int32))
-    ties = (ax == thresh).astype(jnp.int32)
-    row = jnp.cumsum(ties, axis=1)               # 1-based within each row
-    row_tot = jnp.sum(ties, axis=1, keepdims=True)
-    prefix = jnp.cumsum(row_tot, axis=0) - row_tot
-    tie_rank = row + prefix                      # row-major == flat order
-    keep = above | ((ties > 0) & (tie_rank <= k - n_above))
+    ties = ax == thresh
+    budget = k - n_above
+    rows, lanes = x.shape
+    flat = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * lanes
+            + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+    top = (rows * lanes).bit_length()            # cut ranges over [0, R·128]
+
+    def cut(j, c):
+        cand = c | (jnp.int32(1) << (top - 1 - j))
+        count = jnp.sum((ties & (flat < cand)).astype(jnp.int32))
+        return jnp.where(count <= budget, cand, c)
+
+    tie_cut = jax.lax.fori_loop(0, top, cut, jnp.int32(0))
+    keep = above | (ties & (flat < tie_cut))
     out_ref[...] = jnp.where(keep, x, jnp.zeros((), x.dtype))
-    bits_ref[0] = _topk_bits_expr(frac, jnp.float32(d))
+    bits_ref[0, 0] = _topk_bits_expr(frac, jnp.float32(d))
 
 
 def fused_topk_call(x2, frac1, *, d: int, interpret: bool):
     """Launch the fused top-k kernel on a padded [R, 128] block.
 
-    Returns (sparsified [R, 128] f32, payload bits (1,) f32)."""
+    Returns (sparsified [R, 128] f32, payload bits (1, 1) f32)."""
     R, C = x2.shape
     return pl.pallas_call(
         functools.partial(_fused_topk_kernel, d=d),
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.float32),
-                   jax.ShapeDtypeStruct((1,), jnp.float32)],
+                   jax.ShapeDtypeStruct((1, 1), jnp.float32)],
+        in_specs=[_VMEM, _SMEM],
+        out_specs=[_VMEM, _SMEM],
         interpret=interpret,
     )(x2, frac1)
 
@@ -159,17 +186,19 @@ def fused_topk_call(x2, frac1, *, d: int, interpret: bool):
 # ---------------------------------------------------------------------------
 
 def _dither_bits_kernel(s_ref, d_ref, bits_ref):
-    bits_ref[0] = _dither_bits_expr(s_ref[0], d_ref[0])
+    bits_ref[0, 0] = _dither_bits_expr(s_ref[0, 0], d_ref[0, 0])
 
 
 def _topk_bits_kernel(frac_ref, d_ref, bits_ref):
-    bits_ref[0] = _topk_bits_expr(frac_ref[0], d_ref[0])
+    bits_ref[0, 0] = _topk_bits_expr(frac_ref[0, 0], d_ref[0, 0])
 
 
 def dither_bits_call(s1, d1, *, interpret: bool):
     return pl.pallas_call(
         _dither_bits_kernel,
-        out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        in_specs=[_SMEM, _SMEM],
+        out_specs=_SMEM,
         interpret=interpret,
     )(s1, d1)
 
@@ -177,6 +206,8 @@ def dither_bits_call(s1, d1, *, interpret: bool):
 def topk_bits_call(frac1, d1, *, interpret: bool):
     return pl.pallas_call(
         _topk_bits_kernel,
-        out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        in_specs=[_SMEM, _SMEM],
+        out_specs=_SMEM,
         interpret=interpret,
     )(frac1, d1)

@@ -13,8 +13,9 @@ CPU while a TPU deployment compiles the real thing from the same call
 sites (``compressors.compress(..., use_kernel=True)``).
 
 ``supports(x)`` is the STATIC eligibility gate ``compressors`` consults:
-shapes/dtypes it rejects silently keep the jnp path, which the kernels
-are bit-identical to — so the fallback is numerics-free by construction.
+the documented size and dtype limit of the kernels.  Shapes/dtypes it
+rejects keep the jnp path, which the kernels are bit-identical to — so
+the fallback is numerics-free by construction.
 """
 from __future__ import annotations
 
@@ -29,10 +30,12 @@ from repro.kernels.compressor.compressor import (dither_bits_call,
 _LANES = 128
 
 #: Largest element count the gridless single-block kernels accept: the
-#: whole padded [rows, 128] f32 block (plus uniforms + output) must be
-#: VMEM-resident.  3 blocks x 4 MiB at 2^20 elements fits the ~16 MiB
-#: VMEM of every current TPU generation with headroom.
-MAX_FUSED_ELEMS = 1 << 20
+#: whole padded [rows, 128] f32 block (plus uniforms + output) must sit in
+#: the kernel's scoped VMEM (16 MiB by default on TPU v5e).  Vmapped over
+#: workers, pallas pipelines the batch and double-buffers every block, so
+#: the fused dither kernel at 2^19 elements already exceeds it there; 2^18
+#: compiles for v5e unbatched and vmapped (tests/test_tpu_compile.py).
+MAX_FUSED_ELEMS = 1 << 18
 
 #: Dtypes the kernels accept: computed in f32 exactly like the jnp
 #: reference (`_dither` upcasts to f32 internally); f64 would lose
@@ -66,11 +69,11 @@ def fused_dither(key, x, s, *, interpret=None):
     u = jax.random.uniform(key, x.shape)         # == _dither's draw
     x2, n = _to_rows(x.astype(jnp.float32))
     u2, _ = _to_rows(u)
-    s1 = jnp.asarray(s, jnp.float32).reshape(1)
+    s1 = jnp.asarray(s, jnp.float32).reshape(1, 1)
     out2, bits = fused_dither_call(
         x2, u2, s1, d=n, interpret=_resolve_interpret(interpret))
     out = out2.reshape(-1)[:n].reshape(x.shape).astype(x.dtype)
-    return out, bits[0]
+    return out, bits[0, 0]
 
 
 def fused_topk(key, x, frac, *, interpret=None):
@@ -80,26 +83,26 @@ def fused_topk(key, x, frac, *, interpret=None):
     parity with the reference signature."""
     del key                                      # parity with _topk
     x2, n = _to_rows(x.astype(jnp.float32))
-    f1 = jnp.asarray(frac, jnp.float32).reshape(1)
+    f1 = jnp.asarray(frac, jnp.float32).reshape(1, 1)
     out2, bits = fused_topk_call(
         x2, f1, d=n, interpret=_resolve_interpret(interpret))
     out = out2.reshape(-1)[:n].reshape(x.shape).astype(x.dtype)
-    return out, bits[0]
+    return out, bits[0, 0]
 
 
 def dither_bits_fused(s, d, *, interpret=None):
     """Bits-only ledger query: ``spec_bits``'s dither branch as a kernel
     (s and d both traced)."""
-    s1 = jnp.asarray(s, jnp.float32).reshape(1)
-    d1 = jnp.asarray(d, jnp.float32).reshape(1)
+    s1 = jnp.asarray(s, jnp.float32).reshape(1, 1)
+    d1 = jnp.asarray(d, jnp.float32).reshape(1, 1)
     return dither_bits_call(
-        s1, d1, interpret=_resolve_interpret(interpret))[0]
+        s1, d1, interpret=_resolve_interpret(interpret))[0, 0]
 
 
 def topk_bits_fused(frac, d, *, interpret=None):
     """Bits-only ledger query: ``spec_bits``'s top-k branch as a kernel
     (frac and d both traced)."""
-    f1 = jnp.asarray(frac, jnp.float32).reshape(1)
-    d1 = jnp.asarray(d, jnp.float32).reshape(1)
+    f1 = jnp.asarray(frac, jnp.float32).reshape(1, 1)
+    d1 = jnp.asarray(d, jnp.float32).reshape(1, 1)
     return topk_bits_call(
-        f1, d1, interpret=_resolve_interpret(interpret))[0]
+        f1, d1, interpret=_resolve_interpret(interpret))[0, 0]

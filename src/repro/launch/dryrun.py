@@ -1,13 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Multi-pod dry-run: lower + compile every (arch x input-shape x mesh)
 # combination against the production mesh, record memory/cost/collective
 # analysis for EXPERIMENTS.md §Dry-run and §Roofline.
 #
-# The two XLA_FLAGS lines above MUST stay first: jax locks the device count
-# on first initialization, and the production meshes need 512 placeholder
-# host devices.
+# The environment lines above MUST stay first: jax locks the platform and
+# the device count on first initialization, and the production meshes need
+# 512 placeholder host devices (on the CPU, even where an accelerator is
+# attached).
 #
 # Usage:
 #   PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \

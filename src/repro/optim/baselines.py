@@ -95,6 +95,7 @@ from repro.core.driver import (ASYNC_SALT, COHORT_SALT, MessageBuffer,
                                validate_ps)
 from repro.core.traffic import (TrafficHParams, TrafficModel, TrafficState,
                                 admit_arrivals, traffic_send)
+from repro.numerics import matmul
 
 
 def _grid_axes(*axes, ps=None):
@@ -511,7 +512,7 @@ def make_fednl_sweep_step(cfg: FedNLConfig, local_grad: Callable,
         Hs = 0.5 * (H_bar + H_bar.T) + cfg.mu * jnp.eye(d)
         lam, V = jnp.linalg.eigh(Hs)
         lam = jnp.maximum(jnp.abs(lam), cfg.mu)
-        p = -(V @ ((V.T @ g_bar) / lam))
+        p = -matmul(V, matmul(V.T, g_bar) / lam)
         w = state.w + hp.alpha * p
         # uncompressed gradient + dimension-aware compressed Hessian diff
         bits = state.bits_per_node + mask.astype(
@@ -655,7 +656,7 @@ def make_fednl_async_sweep_step(cfg: FedNLConfig, local_grad: Callable,
             Hs = 0.5 * (means["H"] + means["H"].T) + cfg.mu * jnp.eye(d)
             lam, V = jnp.linalg.eigh(Hs)
             lam = jnp.maximum(jnp.abs(lam), cfg.mu)
-            p = -(V @ ((V.T @ means["g"]) / lam))
+            p = -matmul(V, matmul(V.T, means["g"]) / lam)
             return state.w + hp.alpha * p, jnp.linalg.norm(p)
 
         # the eigh only runs (per scan step) on flush rounds

@@ -93,3 +93,28 @@ def test_param_budget_matches_names():
         pa = abstract_params(get_config(arch))
         n = sum(int(np.prod(l.shape)) for l in jax.tree.leaves(pa))
         assert abs(n - n_exp) / n_exp < 0.06, (arch, n, n_exp)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to jax; otherwise the
+    cache goes to the checkout's fixed .jax_cache directory."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(compile_cache.CHECKOUT_CACHE)
+            assert got.endswith(".jax_cache")
+            assert (compile_cache.CHECKOUT_CACHE.parent / "src").is_dir()
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -110,7 +110,7 @@ def test_natural_unbiased(x):
 
 
 @settings(max_examples=25, deadline=None)
-@given(st.lists(st.floats(0.01, 100, allow_nan=False, width=16),
+@given(st.lists(st.floats(2**-7, 100, allow_nan=False, width=16),
                 min_size=1, max_size=48),
        st.lists(st.integers(1, 8), min_size=1, max_size=3),
        st.sampled_from([np.float32, np.float16]),

@@ -107,14 +107,11 @@ from repro.launch.mesh import make_debug_mesh
 from repro.launch.sharding import batch_specs, named_shardings
 from repro.models.context import ModelContext
 from repro.models.model import init_params
-from repro.core.dl_flecs import FlecsDLConfig, make_flecs_train_step
+from repro.core.dl_flecs import (FlecsDLConfig, init_shifts,
+                                 make_flecs_train_step)
 
 cfg = get_config("tinyllama-1.1b", smoke=True)
-# jax 0.4.x: XLA's partitioner crashes (IsManualSubgroup check) on the
-# partial-auto shard_map when the auto (model) axis is nontrivial; test the
-# model-sharded layout only on jax >= 0.5 and the data-only mesh otherwise.
-shape = (4, 2) if hasattr(jax, "shard_map") else (8, 1)
-mesh = make_debug_mesh(shape, ("data", "model"))
+mesh = make_debug_mesh((4, 2), ("data", "model"))
 ctx = ModelContext(mesh=mesh, data_axes=("data",), moe_impl="ref")
 params = init_params(cfg, jax.random.key(0), jnp.float32)
 pa = jax.eval_shape(lambda: params)
@@ -126,7 +123,7 @@ ba = jax.eval_shape(lambda: batch)
 bshard = named_shardings(ba, mesh, batch_specs(ba, mesh, ("data",)))
 lower = make_flecs_train_step(cfg, ctx, FlecsDLConfig(alpha=2e-1, m=0))
 jitted, shifts_abs = lower.build(pa, ba, pshard, bshard)
-shifts = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), shifts_abs)
+shifts = init_shifts(shifts_abs)
 p = params
 losses = []
 for step in range(6):
@@ -233,7 +230,7 @@ os_ = named_shardings(oa, mesh)
 bs = named_shardings(ba, mesh, batch_specs(ba, mesh, ("data",)))
 # out_shardings pinned to the input shardings: without them the compiler
 # may emit differently-sharded outputs and the second call then fails the
-# strict in_shardings check on committed arrays (jax 0.4.x).
+# strict in_shardings check on committed arrays.
 step = jax.jit(make_train_step(cfg, ctx, opt, microbatches=2),
                in_shardings=(ps, os_, bs), out_shardings=(ps, os_, None))
 losses = []
