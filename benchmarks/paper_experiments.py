@@ -134,7 +134,7 @@ def fig1_flecs_vs_cgd(prob, iters=300, every=5):
     res = assert_one_compile(lambda: run_plan(fig1_plan(prob, iters)))
     results = {}
     us = {}
-    dt = res.seconds / (iters * len(FIG1_MS) * len(FIG1_FAMILIES)) * 1e6
+    dt = res.run_s / (iters * len(FIG1_MS) * len(FIG1_FAMILIES)) * 1e6
     for m in FIG1_MS:
         tr = res.traces[f"m{m}"]
         for g, name in enumerate(FIG1_FAMILIES):
@@ -215,7 +215,7 @@ def baselines_comparison(prob, iters=200):
     out = {}
     for lab in res.labels:
         n_it = res.traces[lab]["F"].shape[1]
-        dt = res.seconds / (len(res.labels) * n_it) * 1e6
+        dt = res.run_s / (len(res.labels) * n_it) * 1e6
         out[lab] = (_trace_rows(res.traces[lab], 0, n_it), dt)
     return out
 
@@ -434,7 +434,7 @@ def ablation_grid(prob, iters=200):
     hp = res.hparams["grid"]
     sts, tr = res["grid"]
     G = hp.alpha.shape[0]
-    dt = res.seconds / (iters * G) * 1e6
+    dt = res.run_s / (iters * G) * 1e6
     rows = [{"grad_s": float(hp.grad_s[g]), "hess_s": float(hp.hess_s[g]),
              "beta": float(hp.beta[g]), "F": float(tr["F"][g, -1]),
              "grad_sq": float(tr["grad_sq"][g, -1]),

@@ -427,13 +427,21 @@ class ExperimentPlan:
 class PlanResult:
     """run_plan output: per-run final sweep states / traces / hparams,
     keyed by run label (leading [G] grid axis on every array), and the
-    plan's compiled program (``compiled.as_text()`` is its HLO)."""
+    plan's compiled program (``compiled.as_text()`` is its HLO).
+
+    Host seconds: ``compile_s`` traces, lowers and compiles the program
+    (or loads it from the persistent cache), ``run_s`` executes it once
+    until its outputs are ready; ``seconds`` is their sum.  A profiler
+    trace shows the two as the host spans ``plan.compile`` and
+    ``plan.run``."""
     labels: Tuple[str, ...]
     states: Dict[str, Any]
     traces: Dict[str, Any]
     hparams: Dict[str, Any]
     seconds: float
     compiled: Any = None
+    compile_s: float = 0.0
+    run_s: float = 0.0
 
     def __getitem__(self, label: str):
         return self.states[label], self.traces[label]
@@ -665,14 +673,18 @@ def run_plan(plan: ExperimentPlan) -> PlanResult:
                      for fn, hp, st, ks in zip(fns, hps, states, keyss))
 
     _STATS["programs"] += 1
-    t0 = time.perf_counter()
     args = (tuple(states), tuple(hps), tuple(keys))
-    compiled = jax.jit(program).lower(*args).compile()
-    out = jax.block_until_ready(compiled(*args))
-    dt = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("plan.compile"):
+        compiled = jax.jit(program).lower(*args).compile()
+    t1 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("plan.run"):
+        out = jax.block_until_ready(compiled(*args))
+    t2 = time.perf_counter()
     return PlanResult(
         labels=tuple(labels),
         states={lab: o[0] for lab, o in zip(labels, out)},
         traces={lab: o[1] for lab, o in zip(labels, out)},
         hparams={lab: hp for lab, hp in zip(labels, hps)},
-        seconds=dt, compiled=compiled)
+        seconds=t2 - t0, compiled=compiled, compile_s=t1 - t0,
+        run_s=t2 - t1)

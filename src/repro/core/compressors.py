@@ -94,6 +94,14 @@ FAMILY_TOPK = 3
 FAMILY_COUNT_SKETCH = 4
 FAMILY_MINMAX = 5
 
+#: ``jax.named_scope`` of each family's implementation, by family id: the
+#: compiled instructions of a branch carry ``compress.<family>`` in their
+#: ``op_name``, so a trace tells the branches apart even where a batched
+#: family id runs every branch.  Identity has no operations to name.
+COMPRESS_SCOPES = ("compress.identity", "compress.dither",
+                   "compress.natural", "compress.topk",
+                   "compress.count_sketch", "compress.minmax")
+
 #: Static row capacity of the count-sketch accumulator.  ``depth`` is a
 #: TRACED parameter clipped to [1, SKETCH_DEPTH_MAX]; the accumulator is
 #: allocated at the static maximum so depth can ride a sweep axis without
@@ -347,6 +355,7 @@ def dither_bits(s):
     return ceil_log2(2.0 * s + 1.0)
 
 
+@jax.named_scope(COMPRESS_SCOPES[FAMILY_NATURAL])
 def _natural(key, x):
     """Natural compression [13]: keep the exponent, round the mantissa to a
     power of two stochastically.  Unbiased with ω = 1/8 (tight at p = 1/3)."""
@@ -435,12 +444,14 @@ def count_sketch_decode(key, table, x_like, params: SketchParams):
     return out.reshape(x_like.shape).astype(x_like.dtype)
 
 
+@jax.named_scope(COMPRESS_SCOPES[FAMILY_COUNT_SKETCH])
 def _count_sketch(key, x, params: SketchParams):
     """Q(x) = decode(encode(x)) — the flat (single-message) sketch path."""
     return count_sketch_decode(key, count_sketch_encode(key, x, params),
                                x, params)
 
 
+@jax.named_scope(COMPRESS_SCOPES[FAMILY_MINMAX])
 def _minmax(key, x, frac):
     """Min-max / iceberg sampling: coordinate i survives with probability
     p_i = min(1, k·|x_i|/||x||₁) and ships x_i/p_i — exactly unbiased
@@ -460,6 +471,7 @@ def _minmax(key, x, frac):
 # Fused-kernel dispatch (repro.kernels.compressor)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope(COMPRESS_SCOPES[FAMILY_DITHER])
 def _dither_impl(key, x, s, use_kernel):
     """Dither branch body: the fused Pallas kernel when requested and
     statically eligible (``ops.supports``), else the jnp reference."""
@@ -468,6 +480,7 @@ def _dither_impl(key, x, s, use_kernel):
     return _dither(key, x, s)
 
 
+@jax.named_scope(COMPRESS_SCOPES[FAMILY_TOPK])
 def _topk_impl(key, x, frac, use_kernel):
     """Top-k branch body: fused kernel when eligible, else jnp."""
     if use_kernel and kernel_ops.supports(x):

@@ -111,6 +111,27 @@ from jax.sharding import Mesh, PartitionSpec
 
 from repro.compat import shard_map
 
+# ---------------------------------------------------------------------------
+# Phase names of a round
+# ---------------------------------------------------------------------------
+# Each phase of a federated round runs under a ``jax.named_scope`` of these
+# names.  A scope is trace-time metadata only: it becomes the ``op_name``
+# of the compiled instructions (``metadata={op_name="…/fed.oracle/…"}``)
+# and adds no operation, so a profiler trace of the compiled program can
+# be read by phase.  Inside ``fed.compress.*`` each compressor family
+# opens ``compress.<family>`` (``compressors.COMPRESS_SCOPES``).
+
+SCOPE_ORACLE = "fed.oracle"                 # local gradient, HVPs, M = SᵀY
+SCOPE_COMPRESS_GRAD = "fed.compress.grad"   # Q(g - h), the gradient message
+SCOPE_COMPRESS_HESS = "fed.compress.hess"   # Q(Y - BS), the Hessian message
+SCOPE_CURVATURE = "fed.curvature"           # B·S, the B update, B̄
+SCOPE_SERVER = "fed.server"                 # aggregation, direction, w/h/bits
+SCOPE_RECORD = "fed.record"                 # the scan's record(state)
+
+#: Every round phase, in round order.
+ROUND_SCOPES = (SCOPE_ORACLE, SCOPE_COMPRESS_GRAD, SCOPE_COMPRESS_HESS,
+                SCOPE_CURVATURE, SCOPE_SERVER, SCOPE_RECORD)
+
 
 def bits_dtype():
     """Accumulator dtype for cumulative bit counters.
@@ -496,7 +517,8 @@ def _scan_body(step: Callable, record: Optional[Callable],
     def body(st, k):
         st, aux = step(st, k)
         if record is not None:
-            aux = {**aux, **record(st)}
+            with jax.named_scope(SCOPE_RECORD):
+                aux = {**aux, **record(st)}
         return st, _cast_traces(aux, trace_dtype, keep)
     return body
 
@@ -780,6 +802,28 @@ _FROZEN_ZERO_KEYS: Sequence[str] = ("n_active", "n_arrived", "flushed",
                                     "dir_norm")
 
 
+@jax.named_scope(SCOPE_SERVER)
+def _freeze(active, new_state, state, aux):
+    """The budget gate of :func:`freeze_on_bit_budget`: ``new_state``
+    where ``active``, else ``state``; aux ledgers follow the frozen state
+    and the activity counters read zero."""
+    sel = lambda new, old: jnp.where(active, new, old)         # noqa: E731
+    frozen = jax.tree.map(sel, new_state, state)
+    if isinstance(aux, dict):
+        aux = dict(aux)
+        if "bits_per_node" in aux:
+            aux["bits_per_node"] = frozen.bits_per_node
+        if "edge_bits" in aux and getattr(frozen, "edge_bits",
+                                          None) is not None:
+            aux["edge_bits"] = frozen.edge_bits
+        if "buffered" in aux and hasattr(frozen, "acc_n"):
+            aux["buffered"] = frozen.acc_n
+        for k in _FROZEN_ZERO_KEYS:
+            if k in aux:
+                aux[k] = sel(aux[k], jnp.zeros_like(aux[k]))
+    return frozen, aux
+
+
 def freeze_on_bit_budget(sweep_step: Callable) -> Callable:
     """Budget-freeze scan mode: wrap a sweep step so that once a grid
     point's cumulative per-node bits (``max_i state.bits_per_node[i]``)
@@ -807,23 +851,10 @@ def freeze_on_bit_budget(sweep_step: Callable) -> Callable:
             raise ValueError(
                 "bit_budget requires a state carrying a bits_per_node "
                 f"ledger, got {type(state).__name__}")
-        active = jnp.max(bits) < budget
+        with jax.named_scope(SCOPE_SERVER):
+            active = jnp.max(bits) < budget
         new_state, aux = sweep_step(hp, state, key)
-        sel = lambda new, old: jnp.where(active, new, old)     # noqa: E731
-        frozen = jax.tree.map(sel, new_state, state)
-        if isinstance(aux, dict):
-            aux = dict(aux)
-            if "bits_per_node" in aux:
-                aux["bits_per_node"] = frozen.bits_per_node
-            if "edge_bits" in aux and getattr(frozen, "edge_bits",
-                                              None) is not None:
-                aux["edge_bits"] = frozen.edge_bits
-            if "buffered" in aux and hasattr(frozen, "acc_n"):
-                aux["buffered"] = frozen.acc_n
-            for k in _FROZEN_ZERO_KEYS:
-                if k in aux:
-                    aux[k] = sel(aux[k], jnp.zeros_like(aux[k]))
-        return frozen, aux
+        return _freeze(active, new_state, state, aux)
 
     return step
 

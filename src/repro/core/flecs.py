@@ -84,7 +84,9 @@ from repro.core.driver import (ASYNC_SALT, COHORT_SALT, MessageBuffer,
                                buffer_send, cohort_indices, damped_alpha,
                                fedbuff_accumulate, init_buffer, masked_mean,
                                resolve_participation, sample_delays,
-                               validate_ps)
+                               validate_ps, SCOPE_COMPRESS_GRAD,
+                               SCOPE_COMPRESS_HESS, SCOPE_CURVATURE,
+                               SCOPE_ORACLE, SCOPE_SERVER)
 from repro.core.hierarchy import (EDGE_SALT, HierarchyConfig, charge_edges,
                                   edge_combine, edge_combine_cohort,
                                   edge_round_bits, init_edge_bits,
@@ -307,12 +309,16 @@ def _worker_messages(local_grad: Callable, local_hvp: Callable,
     n = h.shape[0]
 
     def worker(i, hk, Bk, kq, kc):
-        g = local_grad(w, i, jax.random.fold_in(k_g, i))
-        Y = local_hvp(w, S, i, jax.random.fold_in(k_h, i))
-        M = matmul(S.T, Y)                              # m x m (exact)
-        c = compress(grad_spec, kq, g - hk, use_kernel)   # grad diff
-        BS = matmul(Bk, S)
-        Cm = compress(hess_spec, kc, Y - BS, use_kernel)  # hess diff
+        with jax.named_scope(SCOPE_ORACLE):
+            g = local_grad(w, i, jax.random.fold_in(k_g, i))
+            Y = local_hvp(w, S, i, jax.random.fold_in(k_h, i))
+            M = matmul(S.T, Y)                          # m x m (exact)
+        with jax.named_scope(SCOPE_COMPRESS_GRAD):
+            c = compress(grad_spec, kq, g - hk, use_kernel)   # grad diff
+        with jax.named_scope(SCOPE_CURVATURE):
+            BS = matmul(Bk, S)
+        with jax.named_scope(SCOPE_COMPRESS_HESS):
+            Cm = compress(hess_spec, kc, Y - BS, use_kernel)  # hess diff
         return c, M, Cm, BS
 
     if ids is None:
@@ -330,6 +336,7 @@ def _worker_messages(local_grad: Callable, local_hvp: Callable,
     return jax.vmap(worker)(ids, h, B, ks_q, ks_c)
 
 
+@jax.named_scope(SCOPE_SERVER)
 def _direction(cfg: FlecsConfig, g_tilde, Y_tilde, M_bar, B_bar):
     """Search-direction dispatch (Alg 4 variants / Alg 5) from the server
     aggregates — shared by the synchronous round and the async flush."""
@@ -343,6 +350,7 @@ def _direction(cfg: FlecsConfig, g_tilde, Y_tilde, M_bar, B_bar):
                               cfg.Omega, cfg.rho_val)
 
 
+@jax.named_scope(SCOPE_CURVATURE)
 def _update_B(cfg: FlecsConfig, beta, B, Y_tilde_i, M_all, S_of_t, t):
     """Per-worker Hessian-approximation update (Alg 2 / Alg 3), shared by
     the synchronous round and the async arrival path.  ``beta`` may be
@@ -371,6 +379,43 @@ def _hierarchy_guards(cfg: FlecsConfig, hp, state, n: int) -> None:
             "FlecsConfig.hierarchy requires init_state(..., n_edges="
             "cfg.hierarchy.n_edges) so the backhaul ledger exists")
     validate_hierarchy(cfg.hierarchy, n)
+
+
+@jax.named_scope(SCOPE_SERVER)
+def _aggregate(cfg: FlecsConfig, hp, state, key, mask, mask_loc, g_i, Y_i,
+               M_i, axis: Optional[str], n: int):
+    """Server aggregation of one synchronous round: the masked means of
+    the reconstructed messages, or the hierarchy's edge combine, over the
+    full federation (rebuilt by ``all_gather`` under sharding).  Returns
+    (g̃, Ỹ, M̄, active count, edge ledger)."""
+    d, m = state.h.shape[1], cfg.m
+    if axis is None:
+        n_active = jnp.sum(mask)
+    else:
+        gather = lambda x: jax.lax.all_gather(x, axis, tiled=True)  # noqa: E731
+        g_i, Y_i, M_i = gather(g_i), gather(Y_i), gather(M_i)
+        # psum of per-device {0,1} counts: integer-exact, == jnp.sum(mask)
+        n_active = jax.lax.psum(jnp.sum(mask_loc), axis)
+
+    if cfg.hierarchy is None:
+        return (masked_mean(g_i, mask), masked_mean(Y_i, mask),
+                masked_mean(M_i, mask), n_active, state.edge_bits)
+    _hierarchy_guards(cfg, hp, state, n)
+    E = cfg.hierarchy.n_edges
+    k_e = jax.random.fold_in(key, EDGE_SALT)
+    denom = jnp.maximum(jnp.sum(mask), 1.0)
+    g_sum, edge_active = edge_combine(
+        hp.edge_spec, jax.random.fold_in(k_e, 0), g_i, mask, E,
+        cfg.use_kernel)
+    Y_sum, _ = edge_combine(hp.edge_spec, jax.random.fold_in(k_e, 1),
+                            Y_i, mask, E, cfg.use_kernel)
+    M_sum, _ = edge_combine(hp.edge_spec, jax.random.fold_in(k_e, 2),
+                            M_i, mask, E, cfg.use_kernel)
+    g_tilde, Y_tilde, M_bar = g_sum / denom, Y_sum / denom, M_sum / denom
+    edge_bits_new = charge_edges(
+        state.edge_bits, edge_active,
+        edge_round_bits(hp.edge_spec, d, m, cfg.use_kernel))
+    return g_tilde, Y_tilde, M_bar, n_active, edge_bits_new
 
 
 def _flecs_round(cfg: FlecsConfig, local_grad: Callable, local_hvp: Callable,
@@ -416,70 +461,47 @@ def _flecs_round(cfg: FlecsConfig, local_grad: Callable, local_hvp: Callable,
         cfg.use_kernel, ids=ids, n_total=n)
 
     # --- per-worker server state (local rows under sharding) --------------
-    g_tilde_i = c_all + state.h                          # [n_loc, d]
-    Y_tilde_i = C_all + BS_all                           # [n_loc, d, m]
+    with jax.named_scope(SCOPE_SERVER):
+        g_tilde_i = c_all + state.h                      # [n_loc, d]
+        Y_tilde_i = C_all + BS_all                       # [n_loc, d, m]
 
     B_upd = _update_B(cfg, hp.beta, state.B, Y_tilde_i, M_all,
                       lambda ti: S, jnp.zeros((n_loc,), jnp.float32))
-    # only sampled workers communicated a Hessian difference this round
-    B_new = jnp.where(mask_loc[:, None, None] > 0, B_upd, state.B)
+    with jax.named_scope(SCOPE_CURVATURE):
+        # only sampled workers communicated a Hessian difference this round
+        B_new = jnp.where(mask_loc[:, None, None] > 0, B_upd, state.B)
 
     # --- full-federation aggregates (replicated under sharding) -----------
-    if axis is None:
-        g_i, Y_i, M_i = g_tilde_i, Y_tilde_i, M_all
-        n_active = jnp.sum(mask)
-    else:
-        gather = lambda x: jax.lax.all_gather(x, axis, tiled=True)  # noqa: E731
-        g_i, Y_i, M_i = gather(g_tilde_i), gather(Y_tilde_i), gather(M_all)
-        # psum of per-device {0,1} counts: integer-exact, == jnp.sum(mask)
-        n_active = jax.lax.psum(jnp.sum(mask_loc), axis)
-
-    if cfg.hierarchy is not None:
-        _hierarchy_guards(cfg, hp, state, n)
-        E = cfg.hierarchy.n_edges
-        k_e = jax.random.fold_in(key, EDGE_SALT)
-        denom = jnp.maximum(jnp.sum(mask), 1.0)
-        g_sum, edge_active = edge_combine(
-            hp.edge_spec, jax.random.fold_in(k_e, 0), g_i, mask, E,
-            cfg.use_kernel)
-        Y_sum, _ = edge_combine(hp.edge_spec, jax.random.fold_in(k_e, 1),
-                                Y_i, mask, E, cfg.use_kernel)
-        M_sum, _ = edge_combine(hp.edge_spec, jax.random.fold_in(k_e, 2),
-                                M_i, mask, E, cfg.use_kernel)
-        g_tilde, Y_tilde, M_bar = g_sum / denom, Y_sum / denom, M_sum / denom
-        edge_bits_new = charge_edges(
-            state.edge_bits, edge_active,
-            edge_round_bits(hp.edge_spec, d, m, cfg.use_kernel))
-    else:
-        g_tilde = masked_mean(g_i, mask)
-        Y_tilde = masked_mean(Y_i, mask)
-        M_bar = masked_mean(M_i, mask)
-        edge_bits_new = state.edge_bits
+    g_tilde, Y_tilde, M_bar, n_active, edge_bits_new = _aggregate(
+        cfg, hp, state, key, mask, mask_loc, g_tilde_i, Y_tilde_i, M_all,
+        axis, n)
 
     # B̄ is server-side curvature state, not wire traffic — it stays a flat
     # mean under hierarchy, and the sharded engine only pays the [n, d, d]
     # gather when the direction actually consumes it
-    if cfg.direction == "truncated_inverse" or axis is None:
-        B_full = B_new if axis is None else jax.lax.all_gather(
-            B_new, axis, tiled=True)
-        B_bar = masked_mean(B_full, mask)
-    else:
-        B_bar = jnp.zeros((d, d), jnp.float32)
+    with jax.named_scope(SCOPE_CURVATURE):
+        if cfg.direction == "truncated_inverse" or axis is None:
+            B_full = B_new if axis is None else jax.lax.all_gather(
+                B_new, axis, tiled=True)
+            B_bar = masked_mean(B_full, mask)
+        else:
+            B_bar = jnp.zeros((d, d), jnp.float32)
 
     p = _direction(cfg, g_tilde, Y_tilde, M_bar, B_bar)
-    w_new = state.w + hp.alpha * p
-    h_new = state.h + hp.gamma * mask_loc[:, None] * c_all
+    with jax.named_scope(SCOPE_SERVER):
+        w_new = state.w + hp.alpha * p
+        h_new = state.h + hp.gamma * mask_loc[:, None] * c_all
 
-    round_bits = _round_bits(hp.grad_spec, hp.hess_spec, d, m,
-                             cfg.use_kernel)
-    bits_new = (state.bits_per_node
-                + mask_loc.astype(state.bits_per_node.dtype) * round_bits)
-    new_state = FlecsState(w_new, h_new, B_new, state.k + 1, bits_new,
-                           edge_bits_new)
-    aux = {"g_tilde_norm": jnp.linalg.norm(g_tilde),
-           "dir_norm": jnp.linalg.norm(p),
-           "n_active": n_active,
-           "bits_per_node": new_state.bits_per_node}
+        round_bits = _round_bits(hp.grad_spec, hp.hess_spec, d, m,
+                                 cfg.use_kernel)
+        bits_new = (state.bits_per_node
+                    + mask_loc.astype(state.bits_per_node.dtype) * round_bits)
+        new_state = FlecsState(w_new, h_new, B_new, state.k + 1, bits_new,
+                               edge_bits_new)
+        aux = {"g_tilde_norm": jnp.linalg.norm(g_tilde),
+               "dir_norm": jnp.linalg.norm(p),
+               "n_active": n_active,
+               "bits_per_node": new_state.bits_per_node}
     if edge_bits_new is not None:
         aux["edge_bits"] = edge_bits_new
     return new_state, aux

@@ -92,7 +92,8 @@ from repro.core.driver import (ASYNC_SALT, COHORT_SALT, MessageBuffer,
                                buffer_send, cohort_indices,
                                fedbuff_accumulate, init_buffer, masked_mean,
                                resolve_participation, sample_delays,
-                               validate_ps)
+                               validate_ps, SCOPE_COMPRESS_GRAD,
+                               SCOPE_ORACLE, SCOPE_SERVER)
 from repro.core.traffic import (TrafficHParams, TrafficModel, TrafficState,
                                 admit_arrivals, traffic_send)
 from repro.numerics import matmul
@@ -183,8 +184,10 @@ def _diana_round(cfg: DianaConfig, local_grad: Callable, hp: DianaHParams,
                                  cfg.sampling, hp.p)                    # [n]
 
     def worker(i, hk, kq):
-        g = local_grad(state.w, i, jax.random.fold_in(k_g, i))
-        return compress(hp.spec, kq, g - hk, cfg.use_kernel)
+        with jax.named_scope(SCOPE_ORACLE):
+            g = local_grad(state.w, i, jax.random.fold_in(k_g, i))
+        with jax.named_scope(SCOPE_COMPRESS_GRAD):
+            return compress(hp.spec, kq, g - hk, cfg.use_kernel)
 
     if axis is None:
         ids, mask_loc = jnp.arange(n), mask
@@ -195,21 +198,22 @@ def _diana_round(cfg: DianaConfig, local_grad: Callable, hp: DianaHParams,
         mask_loc = jax.lax.dynamic_slice_in_dim(mask, idx * n_loc, n_loc)
         ks = jax.random.split(k_q, n)[ids]
     c = jax.vmap(worker)(ids, state.h, ks)
-    g_i = c + state.h
-    if axis is None:
-        g_full, n_active = g_i, jnp.sum(mask)
-    else:
-        g_full = jax.lax.all_gather(g_i, axis, tiled=True)
-        n_active = jax.lax.psum(jnp.sum(mask_loc), axis)  # integer-exact
-    g_tilde = masked_mean(g_full, mask)
-    w = state.w - hp.alpha * g_tilde
-    h = state.h + hp.gamma * mask_loc[:, None] * c
-    bits = state.bits_per_node + mask_loc.astype(
-        state.bits_per_node.dtype) * spec_bits(hp.spec, d, cfg.use_kernel)
-    new = DianaState(w, h, state.k + 1, bits)
-    return new, {"g_tilde_norm": jnp.linalg.norm(g_tilde),
-                 "n_active": n_active,
-                 "bits_per_node": new.bits_per_node}
+    with jax.named_scope(SCOPE_SERVER):
+        g_i = c + state.h
+        if axis is None:
+            g_full, n_active = g_i, jnp.sum(mask)
+        else:
+            g_full = jax.lax.all_gather(g_i, axis, tiled=True)
+            n_active = jax.lax.psum(jnp.sum(mask_loc), axis)  # integer-exact
+        g_tilde = masked_mean(g_full, mask)
+        w = state.w - hp.alpha * g_tilde
+        h = state.h + hp.gamma * mask_loc[:, None] * c
+        bits = state.bits_per_node + mask_loc.astype(
+            state.bits_per_node.dtype) * spec_bits(hp.spec, d, cfg.use_kernel)
+        new = DianaState(w, h, state.k + 1, bits)
+        return new, {"g_tilde_norm": jnp.linalg.norm(g_tilde),
+                     "n_active": n_active,
+                     "bits_per_node": new.bits_per_node}
 
 
 def make_diana_sweep_step(cfg: DianaConfig, local_grad: Callable):

@@ -1,0 +1,137 @@
+"""The phases of a federated round are named in the compiled program.
+
+Every phase opens a ``jax.named_scope`` (``driver.ROUND_SCOPES``) and every
+compressor family's implementation one of its own
+(``compressors.COMPRESS_SCOPES``).  A scope is metadata: it must reach the
+optimized HLO as part of the instructions' ``op_name`` even under the
+grid's ``vmap`` over a traced family id, where every branch of the family
+switch runs.  Also here: the compile clock of ``compile_cache`` and the
+host seconds of ``run_plan``.
+"""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core import api, compressors, driver
+from repro.core.compressors import stack_specs
+from repro.core.flecs import FlecsConfig
+from repro.data.logreg import make_problem
+from repro.launch import compile_cache
+from repro.optim.baselines import DianaConfig, DianaHParams
+
+FAMILIES = ("identity", "dither16", "natural", "topk0.25", "count_sketch8",
+            "minmax0.25")
+
+
+def _op_names(compiled) -> set:
+    return set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+
+
+def _has(names, scope: str) -> bool:
+    return any(re.search(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)", n)
+               for n in names)
+
+
+def _compile(method: str, cfg, hp, prob):
+    spec = api.get_method(method)
+    state = spec.init(prob, prob.A.shape[0], cfg)
+    G = jax.tree.leaves(hp)[0].shape[0]
+    keys = driver.sweep_keys(jax.random.key(0), G, 2)
+    fn = driver.sweep_program(spec.sweep_step(prob, cfg), 2,
+                              record=lambda st: prob.metrics(st.w))
+    return jax.jit(fn).lower(hp, state, keys).compile()
+
+
+@pytest.fixture(scope="module")
+def prob():
+    return make_problem(d=24, n_workers=3, r=8, seed=0)
+
+
+def test_round_scope_names():
+    assert driver.ROUND_SCOPES == ("fed.oracle", "fed.compress.grad",
+                                   "fed.compress.hess", "fed.curvature",
+                                   "fed.server", "fed.record")
+    ids = (compressors.FAMILY_IDENTITY, compressors.FAMILY_DITHER,
+           compressors.FAMILY_NATURAL, compressors.FAMILY_TOPK,
+           compressors.FAMILY_COUNT_SKETCH, compressors.FAMILY_MINMAX)
+    assert [compressors.COMPRESS_SCOPES[i] for i in ids] == [
+        "compress.identity", "compress.dither", "compress.natural",
+        "compress.topk", "compress.count_sketch", "compress.minmax"]
+
+
+def test_flecs_cgd_program_names_every_phase_and_family(prob):
+    cfg = FlecsConfig(m=2)
+    hp = api.get_method("flecs_cgd").grid(
+        grad_specs=stack_specs(*FAMILIES), hess_specs=stack_specs(*FAMILIES))
+    names = _op_names(_compile("flecs_cgd", cfg, hp, prob))
+    for scope in driver.ROUND_SCOPES:
+        assert _has(names, scope), scope
+    for scope in compressors.COMPRESS_SCOPES[1:]:     # identity has no ops
+        for msg in ("fed.compress.grad", "fed.compress.hess"):
+            assert any(msg in n and scope in n for n in names), (msg, scope)
+
+
+def test_diana_program_names_its_phases_and_families(prob):
+    G = len(FAMILIES)
+    hp = DianaHParams(jnp.full((G,), 0.5), jnp.full((G,), 0.5),
+                      stack_specs(*FAMILIES))
+    names = _op_names(_compile("diana", DianaConfig(), hp, prob))
+    for scope in ("fed.oracle", "fed.compress.grad", "fed.server",
+                  "fed.record"):
+        assert _has(names, scope), scope
+    for scope in ("fed.compress.hess", "fed.curvature"):
+        assert not _has(names, scope), scope
+    for scope in compressors.COMPRESS_SCOPES[1:]:
+        assert _has(names, scope), scope
+
+
+def test_scopes_are_metadata_only(prob):
+    """The same round with the scopes taken away compiles to the same
+    instructions: strip the metadata and the instruction numbering."""
+    cfg = FlecsConfig(m=2)
+    hp = api.get_method("flecs_cgd").grid(
+        grad_specs=stack_specs("dither16", "topk0.25"))
+
+    def plain(text):
+        body = text[text.index("\n%"):]           # the computations
+        body = re.sub(r", metadata=\{[^}]*\}", "", body)
+        seen = {}
+        return re.sub(r"%([\w.\-]+)", lambda m: "%v" + str(
+            seen.setdefault(m.group(1), len(seen))), body)
+
+    scoped = _compile("flecs_cgd", cfg, hp, prob).as_text()
+    # the round's ``with`` scopes open nothing; the family scopes,
+    # decorators applied at import, stay
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        unscoped = _compile("flecs_cgd", cfg, hp, prob).as_text()
+    assert "fed.oracle" in scoped and "fed.oracle" not in unscoped
+    assert plain(scoped) == plain(unscoped)
+
+
+def test_compile_clock_counts_nested_spans_once():
+    trace, lower, backend = compile_cache.COMPILE_EVENTS
+    got = compile_cache.span_seconds([
+        (trace, 100.0, 104.0), (trace, 101.0, 102.0),   # traced inside
+        (lower, 104.0, 104.5), (backend, 104.5, 106.0),
+        (backend, 110.0, 111.0)])
+    assert got == {trace: 5.0, lower: 0.5, backend: 2.5, "total": 7.0}
+
+
+def test_compile_clock_sees_a_jit_compile():
+    compile_cache.start_compile_clock()
+    before = compile_cache.compile_seconds()["total"]
+    jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.25).lower(
+        jnp.ones((7, 5))).compile()
+    assert compile_cache.compile_seconds()["total"] > before
+
+
+def test_run_plan_times_compile_and_run_apart(prob):
+    plan = api.ExperimentPlan(problem=prob, runs=(api.MethodRun("diana"),),
+                              iters=3, seed=0)
+    res = api.run_plan(plan)
+    assert res.compile_s > 0 and res.run_s > 0
+    assert res.seconds == pytest.approx(res.compile_s + res.run_s)
