@@ -4,12 +4,14 @@ Three checkers, each returning a list of human-readable failure strings
 (empty = pass):
 
 * :func:`check_switch_tables` — the compressor family registry vs the
-  ``lax.switch`` branch tables in ``compressors.py``: the FAMILY_* ids
-  must be exactly 0..N-1 (a switch clamps out-of-range indices SILENTLY,
-  so a gap or duplicate would route a family to the wrong branch), and
-  each of ``compress`` / ``spec_bits`` / ``spec_omega`` must carry exactly
-  N branches (checked on the AST — a forgotten branch after adding a
-  family is the regression this guards).
+  branch tables in ``compressors.py``: the FAMILY_* ids must be exactly
+  0..N-1 (a switch clamps out-of-range indices SILENTLY, so a gap or
+  duplicate would route a family to the wrong branch), and each of
+  ``compress`` / ``spec_bits`` / ``spec_omega`` must hand its dispatch
+  (``lax.switch``, or ``_family_switch``, which narrows the table to the
+  spec's family set) exactly N branches (checked on the AST — a
+  forgotten branch after adding a family is the regression this
+  guards).
 * :func:`check_round_bits` — every registered :class:`MethodSpec` prices a
   toy problem consistently: grid-shaped output, finite and positive,
   per-point slices agree with the full-grid query (the
@@ -22,7 +24,8 @@ Three checkers, each returning a list of human-readable failure strings
   bodies, every ``bits``-named output leaf carries ``bits_dtype()``, the
   grid axis survives to every output leaf, and every declared hparam leaf
   is actually consumed by the step (a declared-but-dead sweep axis means
-  the figure's axis labels lie).
+  the figure's axis labels lie) — except a compressor-spec leaf that the
+  compressor algebra itself never reads for the spec's family set.
 
 :func:`run_semantic_checks` runs all three — the CLI's ``--layer 2``.
 """
@@ -48,6 +51,11 @@ METHOD_GRIDS = {
 
 _SWITCH_FNS = ("compress", "spec_bits", "spec_omega")
 
+#: The calls that dispatch on the family id, each taking the full branch
+#: table second: ``lax.switch``, and ``compressors._family_switch``, which
+#: switches over the table's entries for the spec's family set only.
+_DISPATCH_CALLS = ("switch", "_family_switch")
+
 
 def _toy_problem():
     from repro.data.logreg import make_problem
@@ -63,8 +71,8 @@ def _method_grid(name: str, spec):
 # ---------------------------------------------------------------------------
 
 def _switch_branch_counts(source: str) -> Dict[str, List[int]]:
-    """{function name: [branch counts of each lax.switch call in it]} for
-    the spec-dispatched entry points."""
+    """{function name: [branch counts of each family dispatch call in
+    it]} for the spec-dispatched entry points."""
     tree = ast.parse(source)
     out: Dict[str, List[int]] = {}
     for fn in tree.body:
@@ -72,9 +80,11 @@ def _switch_branch_counts(source: str) -> Dict[str, List[int]]:
             continue
         counts = []
         for node in ast.walk(fn):
-            if not (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr == "switch"):
+            if not isinstance(node, ast.Call):
+                continue
+            callee = (node.func.attr if isinstance(node.func, ast.Attribute)
+                      else getattr(node.func, "id", None))
+            if callee not in _DISPATCH_CALLS:
                 continue
             if len(node.args) < 2:
                 counts.append(-1)
@@ -107,11 +117,12 @@ def check_switch_tables() -> List[str]:
         got = counts.get(fn)
         if not got:
             problems.append(
-                f"compressors.{fn} has no lax.switch dispatch — the "
-                "family registry and its branch table have diverged")
+                f"compressors.{fn} has no family dispatch (lax.switch or "
+                "_family_switch) — the family registry and its branch "
+                "table have diverged")
         elif any(c != n for c in got):
             problems.append(
-                f"compressors.{fn}: lax.switch branch count {got} != "
+                f"compressors.{fn}: branch table size {got} != "
                 f"{n} registered families {sorted(families)} — every "
                 "family needs exactly one branch in every table")
     return problems
@@ -218,6 +229,49 @@ def _leaf_paths(tree_value):
     return [(jax.tree_util.keystr(path), leaf) for path, leaf in flat]
 
 
+def _consumed(closed) -> List[bool]:
+    """Per input of a closed jaxpr: does an equation or output read it?"""
+    used = set()
+    for eqn in closed.jaxpr.eqns:
+        used.update(map(id, eqn.invars))
+    used.update(map(id, closed.jaxpr.outvars))
+    return [id(v) in used for v in closed.jaxpr.invars]
+
+
+def _spec_leaves_unread(hp) -> set:
+    """Paths of the compressor-spec leaves in ``hp`` that ``compress``,
+    ``spec_bits`` and ``spec_omega`` never read for the spec's family set.
+    ``spec_bits`` keeps its full switch and reads ``family``, ``s``,
+    ``frac``, ``width`` and ``depth`` of every spec, so in practice these
+    are the sketch slots only the count-sketch branches read, such as
+    ``hh_frac`` of a spec without count sketch.  The set pins them, so a
+    step that does not consume them drops no sweep axis."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.compressors import (CompressorSpec, compress,
+                                        spec_bits, spec_omega)
+
+    d = TOY["d"]
+    x = jnp.zeros((d,), jnp.float32)
+
+    def algebra(spec):
+        return (compress(spec, jax.random.key(0), x), spec_bits(spec, d),
+                spec_omega(spec, d))
+
+    unread = set()
+    nodes, _ = jax.tree_util.tree_flatten_with_path(
+        hp, is_leaf=lambda v: isinstance(v, CompressorSpec))
+    for path, spec in nodes:
+        if not isinstance(spec, CompressorSpec):
+            continue
+        flat, _ = jax.tree_util.tree_flatten_with_path(spec)
+        read = _consumed(jax.make_jaxpr(algebra)(spec))
+        unread.update(jax.tree_util.keystr(path + sub)
+                      for (sub, _), r in zip(flat, read) if not r)
+    return unread
+
+
 def check_jaxpr() -> List[str]:
     import jax
     import numpy as np
@@ -241,14 +295,10 @@ def check_jaxpr() -> List[str]:
         # be consumed (a dead sweep axis mislabels the figure)
         hp0 = jax.tree.map(lambda a: a[0], hp)
         closed = jax.make_jaxpr(step)(hp0, state, jax.random.key(0))
-        n_hp = len(jax.tree.leaves(hp0))
-        used = set()
-        for eqn in closed.jaxpr.eqns:
-            used.update(map(id, eqn.invars))
-        used.update(map(id, closed.jaxpr.outvars))
+        pinned = _spec_leaves_unread(hp0)
         hp_names = [p for p, _ in _leaf_paths(hp0)]
-        for (leaf_name, invar) in zip(hp_names, closed.jaxpr.invars[:n_hp]):
-            if id(invar) not in used:
+        for leaf_name, used in zip(hp_names, _consumed(closed)):
+            if not used and leaf_name not in pinned:
                 problems.append(
                     f"{name}: declared hparam leaf {leaf_name} is never "
                     "consumed by the step — the sweep axis is dead and "

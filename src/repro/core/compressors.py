@@ -14,6 +14,14 @@ dispatch on the family id via ``lax.switch``, so a whole grid of compressor
 choices (levels, fractions, sketch widths, even families) becomes a
 vmappable axis: one compiled program sweeps every point (see
 ``repro.core.flecs``'s ``make_flecs_sweep_step`` / ``driver.run_sweep``).
+Under the grid's ``vmap`` the family id is batched, and a switch over a
+batched index runs every branch and selects.  So a spec also carries, as
+static pytree structure, the set of family ids its ``family`` leaf can
+hold (``CompressorSpec.families``), and ``compress`` and ``spec_omega``
+switch over that set only: a level-only grid calls its one branch
+directly, a two-family axis selects between two, and only a grid that
+holds all six runs all six.  ``spec_bits`` keeps the full switch (its
+docstring says why).
 ``compress`` and ``spec_bits`` take a static ``use_kernel`` flag that swaps
 the dither and top-k branch bodies for the fused Pallas kernels
 (``repro.kernels.compressor`` — bit-identical, interpret mode off-TPU);
@@ -78,7 +86,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import NamedTuple, Optional, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +94,7 @@ import jax.numpy as jnp
 from repro.kernels.compressor import ops as kernel_ops
 from repro.numerics import ceil_log2
 
-# Family ids — the lax.switch branch index of every spec-dispatched op.
+# Family ids — the branch index of every spec-dispatched op's table.
 FAMILY_IDENTITY = 0
 FAMILY_DITHER = 1
 FAMILY_NATURAL = 2
@@ -94,10 +102,16 @@ FAMILY_TOPK = 3
 FAMILY_COUNT_SKETCH = 4
 FAMILY_MINMAX = 5
 
+#: Every family id, in branch order: the family set of a spec whose
+#: ``family`` leaf the code cannot see.
+ALL_FAMILIES = (FAMILY_IDENTITY, FAMILY_DITHER, FAMILY_NATURAL, FAMILY_TOPK,
+                FAMILY_COUNT_SKETCH, FAMILY_MINMAX)
+
 #: ``jax.named_scope`` of each family's implementation, by family id: the
 #: compiled instructions of a branch carry ``compress.<family>`` in their
 #: ``op_name``, so a trace tells the branches apart even where a batched
-#: family id runs every branch.  Identity has no operations to name.
+#: family id runs every branch of the spec's family set.  Identity has no
+#: operations to name.
 COMPRESS_SCOPES = ("compress.identity", "compress.dither",
                    "compress.natural", "compress.topk",
                    "compress.count_sketch", "compress.minmax")
@@ -144,11 +158,48 @@ class CompressorSpec(NamedTuple):
             elsewhere).  Trailing and defaulted (R5): legacy 3-field
             construction still works and is normalized by
             :func:`fill_params` at every entry point.
+    families: the sorted FAMILY_* ids ``family`` can hold.  Static: it
+            is the pytree node's aux data, not a leaf, so it lives
+            through ``jit`` arguments, ``vmap``, ``jax.tree.map`` and
+            ``shard_map`` specs, and the dispatch sees it while tracing.
+            The constructors set it, :func:`stack_specs` unions it, and
+            ``None`` (bare construction) means all six.  ``_replace`` of
+            ``family`` without ``families`` resets it to all six: a set
+            that leaves out an id the leaf holds would clamp to a wrong
+            branch.  Specs with different sets are different pytree
+            structures, so stack them with :func:`stack_specs`, not a
+            ``jax.tree.map`` over the specs.
     """
     family: jnp.ndarray
     s: jnp.ndarray
     frac: jnp.ndarray
     params: Optional[SketchParams] = None
+    families: Optional[Tuple[int, ...]] = None
+
+
+def family_set(spec: CompressorSpec) -> Tuple[int, ...]:
+    """The family ids ``spec.family`` can hold (all six when unknown)."""
+    return ALL_FAMILIES if spec.families is None else spec.families
+
+
+_SPEC_KEYS = tuple(jax.tree_util.GetAttrKey(f)
+                   for f in CompressorSpec._fields[:4])
+_namedtuple_replace = CompressorSpec._replace
+
+
+def _spec_replace(self, **fields):
+    if "family" in fields and "families" not in fields:
+        fields["families"] = None
+    return _namedtuple_replace(self, **fields)
+
+
+# typing.NamedTuple refuses a ``_replace`` in the class body
+CompressorSpec._replace = _spec_replace
+jax.tree_util.register_pytree_with_keys(
+    CompressorSpec,
+    lambda spec: (tuple(zip(_SPEC_KEYS, spec[:4])), family_set(spec)),
+    lambda families, leaves: CompressorSpec(*leaves, families=families),
+    lambda spec: (spec[:4], family_set(spec)))
 
 
 def fill_params(spec: CompressorSpec) -> CompressorSpec:
@@ -163,7 +214,8 @@ def fill_params(spec: CompressorSpec) -> CompressorSpec:
 
 def identity_spec() -> CompressorSpec:
     return CompressorSpec(jnp.int32(FAMILY_IDENTITY), jnp.float32(1.0),
-                          jnp.float32(1.0), default_sketch_params())
+                          jnp.float32(1.0), default_sketch_params(),
+                          (FAMILY_IDENTITY,))
 
 
 def dither_spec(s) -> CompressorSpec:
@@ -172,12 +224,13 @@ def dither_spec(s) -> CompressorSpec:
     s = jnp.asarray(s, jnp.float32)
     return CompressorSpec(jnp.full(s.shape, FAMILY_DITHER, jnp.int32), s,
                           jnp.ones(s.shape, jnp.float32),
-                          default_sketch_params(s.shape))
+                          default_sketch_params(s.shape), (FAMILY_DITHER,))
 
 
 def natural_spec() -> CompressorSpec:
     return CompressorSpec(jnp.int32(FAMILY_NATURAL), jnp.float32(1.0),
-                          jnp.float32(1.0), default_sketch_params())
+                          jnp.float32(1.0), default_sketch_params(),
+                          (FAMILY_NATURAL,))
 
 
 def topk_spec(frac) -> CompressorSpec:
@@ -186,7 +239,7 @@ def topk_spec(frac) -> CompressorSpec:
     frac = jnp.asarray(frac, jnp.float32)
     return CompressorSpec(jnp.full(frac.shape, FAMILY_TOPK, jnp.int32),
                           jnp.ones(frac.shape, jnp.float32), frac,
-                          default_sketch_params(frac.shape))
+                          default_sketch_params(frac.shape), (FAMILY_TOPK,))
 
 
 def count_sketch_spec(width=DEFAULT_SKETCH_WIDTH, depth=DEFAULT_SKETCH_DEPTH,
@@ -200,7 +253,8 @@ def count_sketch_spec(width=DEFAULT_SKETCH_WIDTH, depth=DEFAULT_SKETCH_DEPTH,
     return CompressorSpec(
         jnp.full(width.shape, FAMILY_COUNT_SKETCH, jnp.int32),
         jnp.ones(width.shape, jnp.float32), jnp.ones(width.shape, jnp.float32),
-        SketchParams(width, bcast(depth), bcast(hh_frac)))
+        SketchParams(width, bcast(depth), bcast(hh_frac)),
+        (FAMILY_COUNT_SKETCH,))
 
 
 def minmax_spec(frac) -> CompressorSpec:
@@ -210,7 +264,7 @@ def minmax_spec(frac) -> CompressorSpec:
     frac = jnp.asarray(frac, jnp.float32)
     return CompressorSpec(jnp.full(frac.shape, FAMILY_MINMAX, jnp.int32),
                           jnp.ones(frac.shape, jnp.float32), frac,
-                          default_sketch_params(frac.shape))
+                          default_sketch_params(frac.shape), (FAMILY_MINMAX,))
 
 
 # ---------------------------------------------------------------------------
@@ -508,6 +562,22 @@ def _topk_bits_impl(frac, d, kept, use_kernel):
 # Unified spec-dispatched ops (lax.switch over the family id)
 # ---------------------------------------------------------------------------
 
+def _family_switch(spec: CompressorSpec, branches):
+    """``lax.switch(spec.family, branches)`` over the families ``spec`` can
+    hold only.  ``branches`` is the full table, one thunk per FAMILY_* id.
+
+    Under a batched family id (the sweep grid's ``vmap``) a switch runs
+    every branch and selects, so a branch the set leaves out is work never
+    done; the branches it keeps compute what they computed in the full
+    switch.  The id indexes by its place in the sorted set, which is the
+    id itself (clamped alike) when the set holds all six, and a one-branch
+    switch calls its branch directly."""
+    families = family_set(spec)
+    place = jnp.searchsorted(jnp.asarray(families, jnp.int32), spec.family,
+                             method="compare_all")
+    return jax.lax.switch(place, [branches[f] for f in families])
+
+
 def compress(spec: CompressorSpec, key, x, use_kernel: bool = False
              ) -> jnp.ndarray:
     """Q(x) under ``spec`` — every field may be traced, so the compressor
@@ -523,8 +593,8 @@ def compress(spec: CompressorSpec, key, x, use_kernel: bool = False
     tests/test_kernels.py pins it), so the two paths are interchangeable
     mid-run."""
     spec = fill_params(spec)
-    return jax.lax.switch(
-        spec.family,
+    return _family_switch(
+        spec,
         (lambda: x,
          lambda: _dither_impl(key, x, spec.s, use_kernel),
          lambda: _natural(key, x),
@@ -554,6 +624,13 @@ def spec_bits(spec: CompressorSpec, d, use_kernel: bool = False
     ``use_kernel=True`` prices the dither/top-k branches through the
     bits-only ledger kernels, which share their formulas with the fused
     value kernels' in-pass counts — EXACTLY the numbers above.
+
+    The price keeps the full six-branch switch, whatever the spec's
+    family set: its branches are a few scalar operations a point, so
+    narrowing them saves nothing, and for a static spec a price that
+    folds to a constant early in XLA:CPU's pipeline changes how a
+    thinned scan's loop-invariant data is compiled, so thinned and dense
+    traces stop matching bit for bit.
     """
     spec = fill_params(spec)
     d = jnp.asarray(d, jnp.float32)
@@ -593,8 +670,8 @@ def spec_omega(spec: CompressorSpec, d) -> jnp.ndarray:
     d = jnp.asarray(d, jnp.float32)
     kept = jnp.clip(jnp.ceil(spec.frac * d), 1.0, d)
     wc = jnp.clip(jnp.floor(spec.params.width), 1.0, d)
-    return jax.lax.switch(
-        spec.family,
+    return _family_switch(
+        spec,
         (lambda: jnp.float32(0.0),
          lambda: d / (4.0 * spec.s * spec.s),
          lambda: jnp.float32(1.0 / 8.0),
@@ -725,8 +802,11 @@ def stack_specs(*specs: Union[str, CompressorSpec, Compressor]
     FLECS-vs-FLECS-CGD comparison as a single vmappable grid axis (the
     lax.switch dispatch keys on the traced family id per grid point).
     Inputs go through :func:`make_spec`, so names, specs, and Compressors
-    mix freely and sketch params are normalized before stacking."""
+    mix freely and sketch params are normalized before stacking.  The
+    stacked spec's family set is the union of its inputs' sets."""
     stacked = [make_spec(s) for s in specs]
+    families = tuple(sorted(set().union(*map(family_set, stacked))))
+    stacked = [s._replace(families=families) for s in stacked]
     return jax.tree.map(lambda *leaves: jnp.stack(leaves), *stacked)
 
 

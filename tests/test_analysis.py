@@ -439,6 +439,23 @@ def test_semantic_switch_branch_counter_sees_missing_branch():
                                           "spec_bits": [4]}
 
 
+def test_semantic_switch_branch_counter_sees_family_switch_tables():
+    # the narrowing dispatch takes the full table too: a table handed to
+    # _family_switch is counted like a lax.switch table
+    from repro.analysis.semantic import _switch_branch_counts
+    src = textwrap.dedent("""
+        def compress(spec, key, x):
+            return _family_switch(spec, (lambda: x, lambda: -x))
+
+        def spec_omega(spec, d):
+            return _family_switch(
+                spec, (lambda: d, lambda: d, lambda: d, lambda: d,
+                       lambda: d, lambda: d))
+        """)
+    assert _switch_branch_counts(src) == {"compress": [2],
+                                          "spec_omega": [6]}
+
+
 def test_semantic_round_bits_all_methods():
     from repro.analysis.semantic import METHOD_GRIDS, check_round_bits
     from repro.core.api import method_names
@@ -482,6 +499,43 @@ def test_semantic_jaxpr_catches_dead_hparam_axis():
     finally:
         del api._REGISTRY["_bad_gd"]
     assert problems and "never consumed" in problems[0]
+
+
+def test_semantic_jaxpr_catches_dead_spec_level_axis():
+    """A one-family spec leaves its id unread by the compressor algebra,
+    but not its level: a step that pins the dithering level of a
+    level-only grid is still caught."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from repro.analysis.semantic import check_jaxpr
+    from repro.core import api
+    from repro.optim import baselines
+
+    def dead_level_step(prob, cfg):
+        inner = baselines.make_diana_sweep_step(cfg,
+                                                prob.make_oracles()[0])
+
+        def step(hp, state, key):
+            fixed = hp._replace(spec=hp.spec._replace(s=jnp.float32(64.0)))
+            return inner(fixed, state, key)
+
+        return step
+
+    good = api.get_method("diana")
+    bad = dataclasses.replace(good, name="_bad_diana",
+                              sweep_step=dead_level_step,
+                              grid=lambda **kw: good.grid(
+                                  levels=(16.0, 64.0)))
+    api._REGISTRY["_bad_diana"] = bad
+    try:
+        problems = [p for p in check_jaxpr()
+                    if p.startswith("_bad_diana")]
+    finally:
+        del api._REGISTRY["_bad_diana"]
+    assert len(problems) == 1 and ".spec.s " in problems[0]
+    assert "never consumed" in problems[0]
 
 
 def test_run_semantic_checks_aggregates():

@@ -5,8 +5,9 @@ compressor family's implementation one of its own
 (``compressors.COMPRESS_SCOPES``).  A scope is metadata: it must reach the
 optimized HLO as part of the instructions' ``op_name`` even under the
 grid's ``vmap`` over a traced family id, where every branch of the family
-switch runs.  Also here: the compile clock of ``compile_cache`` and the
-host seconds of ``run_plan``.
+switch over the spec's family set runs, and the branches of families the
+set leaves out are not in the program at all.  Also here: the compile
+clock of ``compile_cache`` and the host seconds of ``run_plan``.
 """
 import contextlib
 import re
@@ -72,6 +73,29 @@ def test_flecs_cgd_program_names_every_phase_and_family(prob):
     for scope in compressors.COMPRESS_SCOPES[1:]:     # identity has no ops
         for msg in ("fed.compress.grad", "fed.compress.hess"):
             assert any(msg in n and scope in n for n in names), (msg, scope)
+
+
+@pytest.mark.parametrize("extra, present", [
+    ((), ("compress.dither", "compress.topk")),
+    (("count_sketch64",), ("compress.dither", "compress.topk",
+                           "compress.count_sketch")),
+], ids=["cell_axis", "with_count_sketch"])
+def test_family_axis_compiles_only_its_branches(prob, extra, present):
+    """The benchmark cell's plan: gradient family axis {dither64,
+    topk0.1}, Hessian messages dither64 at every point.  Its optimized
+    program holds the branches of those families only; a family added
+    to the axis brings its branch back."""
+    cfg = FlecsConfig(m=2, hessian_update="direct", direction="fedsonia",
+                      use_kernel=True)
+    hp = api.get_method("flecs_cgd").grid(
+        alphas=(0.5,), grad_specs=stack_specs("dither64", "topk0.1", *extra))
+    names = _op_names(_compile("flecs_cgd", cfg, hp, prob))
+    for scope in compressors.COMPRESS_SCOPES[1:]:
+        assert _has(names, scope) == (scope in present), scope
+    hess = {n for n in names if "fed.compress.hess" in n}
+    assert any("compress.dither" in n for n in hess)
+    for scope in ("compress.topk", "compress.count_sketch"):
+        assert not any(scope in n for n in hess), scope
 
 
 def test_diana_program_names_its_phases_and_families(prob):

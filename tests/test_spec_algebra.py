@@ -20,12 +20,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.compressors import (FAMILY_COUNT_SKETCH, FAMILY_DITHER,
-                                    FAMILY_IDENTITY, Compressor, compress,
-                                    count_sketch_spec, dither_spec,
+from repro.core.compressors import (ALL_FAMILIES, FAMILY_COUNT_SKETCH,
+                                    FAMILY_DITHER, FAMILY_IDENTITY,
+                                    FAMILY_MINMAX, FAMILY_NATURAL,
+                                    FAMILY_TOPK, Compressor, CompressorSpec,
+                                    compress, count_sketch_spec,
+                                    dither_spec, family_set, fill_params,
                                     identity_spec, make_spec, minmax_spec,
                                     natural_spec, random_dithering,
-                                    spec_bits, spec_omega, topk_spec)
+                                    spec_bits, spec_omega, stack_specs,
+                                    topk_spec)
 from repro.core.driver import (StalenessSchedule, damped_alpha,
                                run_async_sweep, run_experiment, run_sweep,
                                sample_delays)
@@ -77,9 +81,9 @@ def test_traced_family_axis_in_one_program(rng):
     """A grid whose axis varies the FAMILY (not just a level) runs as one
     vmapped program — the lax.switch dispatch the CI pin exercises."""
     x = jnp.asarray(rng.normal(size=30), jnp.float32)
-    specs = jax.tree.map(lambda *a: jnp.stack(a), identity_spec(),
-                         dither_spec(16.0), natural_spec(), topk_spec(0.2),
-                         count_sketch_spec(16.0, 3.0), minmax_spec(0.2))
+    specs = stack_specs(identity_spec(), dither_spec(16.0), natural_spec(),
+                        topk_spec(0.2), count_sketch_spec(16.0, 3.0),
+                        minmax_spec(0.2))
     key = jax.random.key(0)
     ys = jax.jit(jax.vmap(lambda sp: compress(sp, key, x)))(specs)
     assert ys.shape == (6, 30)
@@ -192,6 +196,147 @@ def test_deprecated_constructor_aliases_warn_and_delegate():
     with pytest.warns(DeprecationWarning):
         with pytest.raises(ValueError, match="valid names"):
             spec_from_name("nope")
+
+
+# ---------------------------------------------------------------------------
+# Family sets: the static structure the dispatch switches over
+# ---------------------------------------------------------------------------
+
+SIX = ("identity", "dither16", "natural", "topk0.2", "count_sketch16",
+       "minmax0.2")
+
+
+@pytest.mark.parametrize("build, families", [
+    (identity_spec, (FAMILY_IDENTITY,)),
+    (lambda: dither_spec(64.0), (FAMILY_DITHER,)),
+    (lambda: dither_spec(jnp.asarray([16.0, 64.0])), (FAMILY_DITHER,)),
+    (natural_spec, (FAMILY_NATURAL,)),
+    (lambda: topk_spec(0.1), (FAMILY_TOPK,)),
+    (count_sketch_spec, (FAMILY_COUNT_SKETCH,)),
+    (lambda: minmax_spec(0.25), (FAMILY_MINMAX,)),
+    (lambda: make_spec("dither64"), (FAMILY_DITHER,)),
+    (lambda: make_spec("topk", frac=0.3), (FAMILY_TOPK,)),
+    (lambda: make_spec("count_sketch8"), (FAMILY_COUNT_SKETCH,)),
+    (lambda: make_spec(random_dithering(16)), (FAMILY_DITHER,)),
+    (lambda: stack_specs("dither64", "dither16"), (FAMILY_DITHER,)),
+    (lambda: stack_specs("topk0.1", "dither64"), (FAMILY_DITHER,
+                                                  FAMILY_TOPK)),
+    (lambda: stack_specs("minmax0.1", CompressorSpec(
+        jnp.int32(FAMILY_DITHER), jnp.float32(64.0), jnp.float32(1.0))),
+     ALL_FAMILIES),
+    (lambda: stack_specs(*SIX), ALL_FAMILIES),
+], ids=["identity", "dither", "dither_grid", "natural", "topk",
+        "count_sketch", "minmax", "make_spec_name", "make_spec_kw",
+        "make_spec_sketch", "make_spec_compressor", "stack_one_family",
+        "stack_union", "stack_unknown", "stack_six"])
+def test_constructors_set_family_set(build, families):
+    spec = build()
+    assert family_set(spec) == families
+    # every id the leaf holds is in the set (a switch would clamp one
+    # that is not)
+    assert set(np.asarray(spec.family).ravel().tolist()) <= set(families)
+
+
+def _traced_set(spec):
+    seen = []
+
+    def f(sp):
+        seen.append(family_set(sp))
+        return compress(sp, jax.random.key(0), jnp.ones(8))
+
+    jax.jit(f)(spec)
+    return seen[0]
+
+
+@pytest.mark.parametrize("transform", [
+    "jit_argument", "vmap", "tree_map", "device_put", "fill_params",
+    "replace_params", "shard_map_specs", "flecs_grid", "flecs_grid_edges",
+])
+def test_family_set_survives(transform):
+    from jax.sharding import PartitionSpec
+
+    from repro.core.api import get_method
+
+    axis = stack_specs("dither64", "topk0.1")
+    want = (FAMILY_DITHER, FAMILY_TOPK)
+    if transform == "jit_argument":
+        got = _traced_set(jax.tree.map(lambda a: a[0], axis))
+    elif transform == "vmap":
+        seen = []
+        jax.vmap(lambda sp: seen.append(family_set(sp)) or sp.s)(axis)
+        got = seen[0]
+    elif transform == "tree_map":
+        got = family_set(jax.tree.map(lambda a: jnp.repeat(a, 3), axis))
+    elif transform == "device_put":
+        got = family_set(jax.device_put(axis))
+    elif transform == "fill_params":
+        legacy = CompressorSpec(axis.family, axis.s, axis.frac,
+                                families=axis.families)
+        got = family_set(fill_params(legacy))
+    elif transform == "replace_params":
+        got = family_set(axis._replace(s=axis.s * 2.0))
+    elif transform == "shard_map_specs":
+        specs = jax.tree.map(lambda _: PartitionSpec(), axis)
+        assert (jax.tree.structure(specs, is_leaf=lambda v: isinstance(
+            v, PartitionSpec)) == jax.tree.structure(axis))
+        got = family_set(specs)
+    else:
+        edges = dict(edge_levels=(8.0, 64.0)) if "edges" in transform \
+            else {}
+        hp = get_method("flecs_cgd").grid(alphas=(0.5,), grad_specs=axis,
+                                          **edges)
+        assert family_set(hp.hess_spec) == (FAMILY_DITHER,)
+        assert jnp.shape(hp.hess_spec.family) == jnp.shape(hp.alpha)
+        got = family_set(hp.grad_spec)
+    assert got == want
+
+
+def test_spec_from_bare_arrays_holds_all_families():
+    bare = CompressorSpec(jnp.int32(FAMILY_DITHER), jnp.float32(64.0),
+                          jnp.float32(1.0))
+    assert family_set(bare) == ALL_FAMILIES
+    assert family_set(fill_params(bare)) == ALL_FAMILIES
+    assert family_set(jax.tree.map(lambda a: a, bare)) == ALL_FAMILIES
+    assert _traced_set(bare) == ALL_FAMILIES
+    # a family id replaced with a value the code cannot see: all six
+    assert family_set(
+        dither_spec(64.0)._replace(family=jnp.int32(FAMILY_TOPK))) \
+        == ALL_FAMILIES
+    # unknown and explicit all-six sets are one structure
+    assert (jax.tree.structure(bare) == jax.tree.structure(
+        bare._replace(families=ALL_FAMILIES)))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["jnp", "kernel"])
+@pytest.mark.parametrize("names", [
+    ("dither64", "dither16"), ("dither64", "topk0.1"), SIX,
+], ids=["one_family", "two_families", "six_families"])
+def test_narrowed_dispatch_is_bit_identical(rng, names, use_kernel):
+    """Under vmap over a stacked spec, the switch over the spec's family
+    set gives exactly what a literal six-branch ``lax.switch`` on the
+    family id gives, each branch a one-family spec of that id.
+    (``spec_bits`` keeps its full switch; it is compared all the same.)"""
+    spec = stack_specs(*names)
+    d = 40
+    x = jnp.asarray(rng.normal(size=(len(names), d)), jnp.float32)
+    keys = jax.random.split(jax.random.key(11), len(names))
+
+    def algebra(sp, k, xi):
+        return (compress(sp, k, xi, use_kernel=use_kernel),
+                spec_bits(sp, d, use_kernel=use_kernel),
+                spec_omega(sp, d))
+
+    def literal_switch(sp, k, xi):
+        return jax.lax.switch(sp.family, [
+            lambda f=f: algebra(CompressorSpec(*sp[:4], families=(f,)),
+                                k, xi)
+            for f in ALL_FAMILIES])
+
+    run = jax.jit(lambda fn, *a: jax.vmap(fn)(*a), static_argnums=0)
+    for a, b in zip(run(algebra, spec, keys, x),
+                    run(literal_switch, spec, keys, x)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------
