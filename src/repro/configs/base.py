@@ -40,6 +40,7 @@ class SSMConfig:
     expand: int = 2
     conv_width: int = 4
     chunk: int = 128             # SSD chunk length
+    conv_bias: bool = False      # a bias on the depthwise conv over x, B, C
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,6 +70,9 @@ class ModelConfig:
     norm_eps: float = 1e-6
     use_post_norms: bool = False  # gemma2/3 post-attn/post-ffn norms
     tie_embeddings: bool = False
+    # Input embedding multiplier; None keeps the legacy rule (×√d_model
+    # where use_post_norms or tie_embeddings), a number is used as given.
+    embed_multiplier: Optional[float] = None
     act: str = "silu"            # silu | gelu
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None

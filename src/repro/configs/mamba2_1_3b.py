@@ -1,4 +1,11 @@
-"""mamba2-1.3b [ssm] — SSD (state-space duality), attention-free [arXiv:2405.21060]."""
+"""mamba2-1.3b [ssm] — SSD (state-space duality), attention-free [arXiv:2405.21060].
+
+Published settings (state-spaces/mamba2-1.3b and the ``Mamba2`` layer's
+defaults): vocab 50277 padded to a multiple of 16, chunk 256, a bias on the
+depthwise conv.  The published model ties its embedding with no input
+multiplier; this entry keeps an untied head, which the benchmark's
+``mamba2-1.3b-l16`` builder overrides (ROADMAP §3).
+"""
 from repro.configs.base import FFN_NONE, SSM, SSMConfig, ModelConfig, uniform_plan
 
 CONFIG = ModelConfig(
@@ -10,8 +17,9 @@ CONFIG = ModelConfig(
     n_kv_heads=64,
     head_dim=64,
     d_ff=0,
-    vocab=50280,
+    vocab=50288,
     layer_plan=uniform_plan(48, SSM, FFN_NONE),
-    ssm=SSMConfig(d_state=128, head_dim=64, expand=2, conv_width=4, chunk=128),
+    ssm=SSMConfig(d_state=128, head_dim=64, expand=2, conv_width=4, chunk=256,
+                  conv_bias=True),
     source="arXiv:2405.21060",
 )

@@ -23,6 +23,12 @@ Mapping (DESIGN.md §3):
     columns, jvp-of-grad once per column), FedSONIA direction per tensor.
     B ≡ 0 (the paper's experimental init) makes Ỹ = C(Y) + 0 — no d×m
     state is ever stored; sketches are regenerated from the step index.
+  * one step is one FLECS-CGD round, and its phases run under the
+    federated engine's round scopes (``driver.ROUND_SCOPES``): the loss,
+    gradient and HVPs under ``fed.oracle``, the quantizer under
+    ``fed.compress.grad`` / ``fed.compress.hess`` (with ``compress.dither``
+    inside, as ``COMPRESS_SCOPES`` names it), the psum, the shift and
+    parameter updates and the metrics under ``fed.server``.
 """
 from __future__ import annotations
 
@@ -35,14 +41,17 @@ import numpy as np
 
 from repro.compat import axis_size, shard_map
 from repro.configs.base import ModelConfig
-from repro.core.driver import bits_dtype
-from repro.core.compressors import (dither_spec, identity_spec,
+from repro.core.driver import (SCOPE_COMPRESS_GRAD, SCOPE_COMPRESS_HESS,
+                               SCOPE_ORACLE, SCOPE_SERVER, bits_dtype)
+from repro.core.compressors import (COMPRESS_SCOPES, FAMILY_DITHER,
+                                    dither_spec, identity_spec,
                                     psum_level_cap, shared_scale_levels,
                                     spec_bits)
 from repro.models.context import ModelContext
 from repro.train.step import _loss_fn
 
 P = jax.sharding.PartitionSpec
+SCOPE_DITHER = COMPRESS_SCOPES[FAMILY_DITHER]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,7 +132,9 @@ def make_flecs_train_step(cfg: ModelConfig, ctx: ModelContext,
                           bookkeeping: h̄⁺ = h̄ + γ c̄; NO communication)}.
         """
         axis = axes if len(axes) > 1 else axes[0]
-        loss, grads = jax.value_and_grad(_loss_fn)(params, batch, cfg, ctx_in)
+        with jax.named_scope(SCOPE_ORACLE):
+            loss, grads = jax.value_and_grad(_loss_fn)(params, batch, cfg,
+                                                       ctx_in)
         leaves, treedef = jax.tree.flatten(grads)
         h_own = [h[0] for h in jax.tree.leaves(shifts["own"])]
         h_mean = jax.tree.leaves(shifts["mean"])
@@ -143,28 +154,34 @@ def make_flecs_train_step(cfg: ModelConfig, ctx: ModelContext,
         g_tilde, new_own, new_mean = [], [], []
         for i, (g, ho, hm) in enumerate(zip(leaves, h_own, h_mean)):
             if not fcfg.compress:
-                g_avg = jax.lax.pmean(g.astype(jnp.float32), axis)
+                with jax.named_scope(SCOPE_SERVER):
+                    g_avg = jax.lax.pmean(g.astype(jnp.float32), axis)
                 g_tilde.append(g_avg)
                 new_own.append(ho)
                 new_mean.append(hm)
                 payload_bits += spec_bits(identity_spec(), g.size)
                 continue
             key = jax.random.fold_in(key0, i)
-            delta = g.astype(jnp.float32) - ho.astype(jnp.float32)
-            levels, scale = shared_scale_levels(key, delta, gspec.s, axis)
+            with jax.named_scope(SCOPE_COMPRESS_GRAD), \
+                    jax.named_scope(SCOPE_DITHER):
+                delta = g.astype(jnp.float32) - ho.astype(jnp.float32)
+                levels, scale = shared_scale_levels(key, delta, gspec.s,
+                                                    axis)
             payload_bits += spec_bits(gspec, delta.size)
-            # f16 psum: the compressed collective (wire = 2 bytes/elem).
-            # f16 holds integers exactly up to 2048, so with s·n < 2048 the
-            # sum of n workers' levels is exact; XLA PROMOTES s16 all-reduce
-            # back to f32 (observed in the lowered HLO), f16 it keeps.
-            summed = jax.lax.psum(levels.astype(jnp.float16), axis)
-            q_own = levels.astype(jnp.float32) * scale          # own Q(δ_i)
-            q_mean = summed.astype(jnp.float32) * scale / n     # c̄
-            g_tilde.append(q_mean + hm.astype(jnp.float32))
-            new_own.append((ho.astype(jnp.float32)
-                            + fcfg.gamma * q_own).astype(ho.dtype))
-            new_mean.append((hm.astype(jnp.float32)
-                             + fcfg.gamma * q_mean).astype(hm.dtype))
+            with jax.named_scope(SCOPE_SERVER):
+                # f16 psum: the compressed collective (wire = 2 bytes/elem).
+                # f16 holds integers exactly up to 2048, so with s·n < 2048
+                # the sum of n workers' levels is exact; XLA PROMOTES s16
+                # all-reduce back to f32 (observed in the lowered HLO), f16
+                # it keeps.
+                summed = jax.lax.psum(levels.astype(jnp.float16), axis)
+                q_own = levels.astype(jnp.float32) * scale      # own Q(δ_i)
+                q_mean = summed.astype(jnp.float32) * scale / n  # c̄
+                g_tilde.append(q_mean + hm.astype(jnp.float32))
+                new_own.append((ho.astype(jnp.float32)
+                                + fcfg.gamma * q_own).astype(ho.dtype))
+                new_mean.append((hm.astype(jnp.float32)
+                                 + fcfg.gamma * q_mean).astype(hm.dtype))
         g_tilde = jax.tree.unflatten(treedef, g_tilde)
         new_shifts = {
             "own": jax.tree.unflatten(treedef, [h[None] for h in new_own]),
@@ -179,48 +196,59 @@ def make_flecs_train_step(cfg: ModelConfig, ctx: ModelContext,
             # with the same int8/int16 integer collective.
             y_cols_all = [[] for _ in p_leaves]
             for col in range(fcfg.m):
-                tang_col = jax.tree.unflatten(treedef, [
-                    _tensor_sketch(step_idx, i, p.shape, fcfg.m)[:, col]
-                    .reshape(p.shape).astype(p.dtype)
-                    for i, p in enumerate(p_leaves)])
-                gfun = lambda pp: jax.grad(_loss_fn)(pp, batch, cfg, ctx_in)
-                _, hv = jax.jvp(gfun, (params,), (tang_col,))
+                with jax.named_scope(SCOPE_ORACLE):
+                    tang_col = jax.tree.unflatten(treedef, [
+                        _tensor_sketch(step_idx, i, p.shape, fcfg.m)[:, col]
+                        .reshape(p.shape).astype(p.dtype)
+                        for i, p in enumerate(p_leaves)])
+                    gfun = lambda pp: jax.grad(_loss_fn)(pp, batch, cfg,
+                                                         ctx_in)
+                    _, hv = jax.jvp(gfun, (params,), (tang_col,))
                 for i, y in enumerate(jax.tree.leaves(hv)):
                     key = jax.random.fold_in(jax.random.fold_in(key0, col),
                                              1000 + i)
                     if fcfg.compress:
-                        lv, sc = shared_scale_levels(
-                            key, y.astype(jnp.float32), gspec.s, axis)
+                        with jax.named_scope(SCOPE_COMPRESS_HESS), \
+                                jax.named_scope(SCOPE_DITHER):
+                            lv, sc = shared_scale_levels(
+                                key, y.astype(jnp.float32), gspec.s, axis)
                         payload_bits += spec_bits(gspec, y.size)
-                        y_bar = (jax.lax.psum(lv.astype(jnp.float16), axis)
-                                 .astype(jnp.float32) * sc / n)
+                        with jax.named_scope(SCOPE_SERVER):
+                            y_bar = (jax.lax.psum(lv.astype(jnp.float16),
+                                                  axis)
+                                     .astype(jnp.float32) * sc / n)
                     else:
-                        y_bar = jax.lax.pmean(y.astype(jnp.float32), axis)
+                        with jax.named_scope(SCOPE_SERVER):
+                            y_bar = jax.lax.pmean(y.astype(jnp.float32),
+                                                  axis)
                         payload_bits += spec_bits(identity_spec(), y.size)
                     y_cols_all[i].append(y_bar.reshape(-1))
             directions = []
-            for i, g in enumerate(jax.tree.leaves(g_tilde)):
-                V = _tensor_sketch(step_idx, i, g.shape, fcfg.m)   # [d, m]
-                Y = jnp.stack(y_cols_all[i], axis=1)               # [d, m]
-                M = V.T @ Y                                        # [m, m]
-                p_dir = _fedsonia_tensor(Y, M, g.reshape(-1).astype(jnp.float32),
-                                         fcfg)
-                directions.append(p_dir.reshape(g.shape))
+            with jax.named_scope(SCOPE_SERVER):
+                for i, g in enumerate(jax.tree.leaves(g_tilde)):
+                    V = _tensor_sketch(step_idx, i, g.shape, fcfg.m)  # [d, m]
+                    Y = jnp.stack(y_cols_all[i], axis=1)              # [d, m]
+                    M = V.T @ Y                                       # [m, m]
+                    p_dir = _fedsonia_tensor(
+                        Y, M, g.reshape(-1).astype(jnp.float32), fcfg)
+                    directions.append(p_dir.reshape(g.shape))
             update = jax.tree.unflatten(treedef, directions)
         else:
             update = jax.tree.map(lambda g: -g, g_tilde)
 
-        new_params = jax.tree.map(
-            lambda p, u: (p.astype(jnp.float32)
-                          + fcfg.alpha * u).astype(p.dtype), params, update)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
-                             for g in jax.tree.leaves(g_tilde)))
-        # uplink_mbits: the idealized per-worker payload (spec_bits of the
-        # wire spec — what a parameter-server federation would ship); the
-        # ring all-reduce actually carries the 16-bit accumulation width,
-        # a fixed 16/ceil(log2(2s+1)) factor on top
-        metrics = {"loss": jax.lax.pmean(loss, axis), "grad_norm": gnorm,
-                   "uplink_mbits": payload_bits / 1e6}
+        with jax.named_scope(SCOPE_SERVER):
+            new_params = jax.tree.map(
+                lambda p, u: (p.astype(jnp.float32)
+                              + fcfg.alpha * u).astype(p.dtype),
+                params, update)
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                 for g in jax.tree.leaves(g_tilde)))
+            # uplink_mbits: the idealized per-worker payload (spec_bits of
+            # the wire spec — what a parameter-server federation would
+            # ship); the ring all-reduce actually carries the 16-bit
+            # accumulation width, a fixed 16/ceil(log2(2s+1)) factor on top
+            metrics = {"loss": jax.lax.pmean(loss, axis), "grad_norm": gnorm,
+                       "uplink_mbits": payload_bits / 1e6}
         return new_params, new_shifts, metrics
 
     ns = lambda sp: jax.sharding.NamedSharding(mesh, sp)

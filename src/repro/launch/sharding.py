@@ -60,6 +60,9 @@ _RULES: Dict[str, Dict[int, str]] = {
     "conv_x": {-1: MODEL},
     "conv_B": {},
     "conv_C": {},
+    "conv_x_bias": {-1: MODEL},
+    "conv_B_bias": {},
+    "conv_C_bias": {},
     "out_proj": {-2: MODEL, -1: DATA},
     # RG-LRU
     "w_in": {-1: MODEL, -2: DATA},

@@ -123,7 +123,9 @@ def embed_inputs(params, batch, cfg: ModelConfig):
         pad = x.shape[1] - n_img
         img_full = jnp.pad(img, ((0, 0), (0, pad), (0, 0)))
         x = jnp.where(pos < n_img, img_full, x)
-    if cfg.use_post_norms or cfg.tie_embeddings:   # gemma-style scaling
+    if cfg.embed_multiplier is not None:
+        x = x * float(cfg.embed_multiplier)
+    elif cfg.use_post_norms or cfg.tie_embeddings:   # gemma-style scaling
         x = x * float(np.sqrt(cfg.d_model))
     return x
 

@@ -7,6 +7,11 @@ O(1) in sequence length, which is why mamba2 runs the long_500k shape.
 Projections are stored UNFUSED (separate z/x/B/C/dt weights) so the inner
 dim (d_inner) and head dim can be cleanly sharded over the model axis —
 a fused in_proj would force resharding at the split points (DESIGN.md §5).
+The depthwise conv over x, B and C likewise keeps one weight (and, with
+``SSMConfig.conv_bias``, one bias) per channel group.
+
+``ssd_scan`` runs under the ``jax.named_scope`` ``SCOPE_SSD``, so its
+compiled instructions can be read by name in a profiler trace.
 """
 from __future__ import annotations
 
@@ -15,6 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.layers import causal_conv1d, rms_norm
+
+SCOPE_SSD = "ssm.ssd"           # the chunked SSD scan, fwd and bwd
+CONV_GROUPS = ("x", "B", "C")   # the channel groups of the depthwise conv
 
 
 def _dims(cfg):
@@ -30,7 +38,7 @@ def init_ssm(key, cfg, dtype):
     D = cfg.d_model
     ks = jax.random.split(key, 9)
     sc = 1.0 / np.sqrt(D)
-    return {
+    params = {
         "in_z": (jax.random.normal(ks[0], (D, d_inner)) * sc).astype(dtype),
         "in_x": (jax.random.normal(ks[1], (D, d_inner)) * sc).astype(dtype),
         "in_B": (jax.random.normal(ks[2], (D, N)) * sc).astype(dtype),
@@ -47,6 +55,14 @@ def init_ssm(key, cfg, dtype):
         "out_proj": (jax.random.normal(ks[8], (d_inner, D))
                      / np.sqrt(d_inner)).astype(dtype),
     }
+    if s.conv_bias:
+        widths = {"x": d_inner, "B": N, "C": N}
+        bk = jax.random.split(jax.random.fold_in(key, 9), len(CONV_GROUPS))
+        bound = 1.0 / np.sqrt(s.conv_width)   # Conv1d's init, fan-in K
+        for k, g in zip(bk, CONV_GROUPS):
+            params[f"conv_{g}_bias"] = jax.random.uniform(
+                k, (widths[g],), jnp.float32, -bound, bound).astype(dtype)
+    return params
 
 
 def _segsum(a):
@@ -59,6 +75,7 @@ def _segsum(a):
     return jnp.where(mask, diff, -jnp.inf)
 
 
+@jax.named_scope(SCOPE_SSD)
 def ssd_scan(xh, dt, A_log, B_mat, C_mat, chunk, init_state=None):
     """Chunked SSD.  xh: [B,S,H,P]; dt: [B,S,H]; B_mat/C_mat: [B,S,N].
 
@@ -117,15 +134,24 @@ def _project(params, x):
     return z, xin, B_in, C_in, dt
 
 
+def _conv(params, g, x, state, cfg):
+    """Depthwise causal conv of channel group ``g`` (x, B or C), plus its
+    bias where the config has one (``Mamba2``'s ``conv1d`` over xBC), then
+    SiLU.  Returns (activation, new conv state)."""
+    y, new_state = causal_conv1d(x, params[f"conv_{g}"], state)
+    if cfg.ssm.conv_bias:
+        y = y + params[f"conv_{g}_bias"].astype(y.dtype)
+    return jax.nn.silu(y), new_state
+
+
 def ssm_forward(params, x, cfg, *, state=None, conv_state=None):
     """Full-sequence mixer.  x: [B,S,D] -> (y [B,S,D], (state, convs))."""
     d_inner, H, P, N = _dims(cfg)
     z, xin, B_in, C_in, dt = _project(params, x)
     cs = conv_state or {"x": None, "B": None, "C": None}
-    xin, cx = causal_conv1d(xin, params["conv_x"], cs["x"])
-    B_in, cb = causal_conv1d(B_in, params["conv_B"], cs["B"])
-    C_in, cc = causal_conv1d(C_in, params["conv_C"], cs["C"])
-    xin, B_in, C_in = (jax.nn.silu(t) for t in (xin, B_in, C_in))
+    xin, cx = _conv(params, "x", xin, cs["x"], cfg)
+    B_in, cb = _conv(params, "B", B_in, cs["B"], cfg)
+    C_in, cc = _conv(params, "C", C_in, cs["C"], cfg)
     xh = xin.reshape(*x.shape[:2], H, P)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     y, state = ssd_scan(xh, dt, params["A_log"], B_in, C_in,
@@ -141,10 +167,9 @@ def ssm_decode(params, x, cache, cfg):
     """One-token decode.  x: [B,1,D]; cache: {"state","conv_x","conv_B","conv_C"}."""
     d_inner, H, P, N = _dims(cfg)
     z, xin, B_in, C_in, dt = _project(params, x)
-    xin, cx = causal_conv1d(xin, params["conv_x"], cache["conv_x"])
-    B_in, cb = causal_conv1d(B_in, params["conv_B"], cache["conv_B"])
-    C_in, cc = causal_conv1d(C_in, params["conv_C"], cache["conv_C"])
-    xin, B_in, C_in = (jax.nn.silu(t) for t in (xin, B_in, C_in))
+    xin, cx = _conv(params, "x", xin, cache["conv_x"], cfg)
+    B_in, cb = _conv(params, "B", B_in, cache["conv_B"], cfg)
+    C_in, cc = _conv(params, "C", C_in, cache["conv_C"], cfg)
     xh = xin[:, 0].reshape(-1, H, P).astype(jnp.float32)
     B1 = B_in[:, 0].astype(jnp.float32)
     C1 = C_in[:, 0].astype(jnp.float32)

@@ -136,6 +136,55 @@ def test_scopes_are_metadata_only(prob):
     assert plain(scoped) == plain(unscoped)
 
 
+def _dl_step_text(m):
+    """The DL trainer's FLECS-CGD step for a small published-form mamba2
+    (conv bias, tied embedding unscaled), compiled on one CPU device."""
+    import dataclasses
+
+    import numpy as np
+    from repro.configs import get_config
+    from repro.core.dl_flecs import FlecsDLConfig, make_flecs_train_step
+    from repro.launch.sharding import batch_specs, named_shardings
+    from repro.models.context import ModelContext
+    from repro.models.model import init_params
+    cfg = dataclasses.replace(get_config("mamba2-1.3b", smoke=True),
+                              tie_embeddings=True, embed_multiplier=1.0)
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                             ("data", "model"))
+    ctx = ModelContext(mesh=mesh, data_axes=("data",), remat=True)
+    pa = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0),
+                                            jnp.float32))
+    ba = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
+          for k in ("tokens", "labels")}
+    step = make_flecs_train_step(cfg, ctx, FlecsDLConfig(m=m))
+    return step(pa, ba, named_shardings(pa, mesh), named_shardings(
+        ba, mesh, batch_specs(ba, mesh, ("data",)))).compile().as_text()
+
+
+def test_dl_step_names_every_phase():
+    """One DL step is one FLECS-CGD round: its phases carry the round
+    scopes, the quantizer ``compress.dither``, the SSD scan ``ssm.ssd``;
+    taking the ``with`` scopes away changes nothing but metadata."""
+    scoped = _dl_step_text(1)
+    names = set(re.findall(r'op_name="([^"]*)"', scoped))
+    for scope in ("fed.oracle", "fed.compress.grad", "fed.compress.hess",
+                  "fed.server", "ssm.ssd"):
+        assert _has(names, scope), scope
+    for msg in ("fed.compress.grad", "fed.compress.hess"):
+        assert any(msg in n and "compress.dither" in n for n in names), msg
+    assert any("fed.oracle" in n and "ssm.ssd" in n for n in names)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        unscoped = _dl_step_text(1)
+    assert "fed.oracle" not in unscoped
+
+    def plain(text):
+        body = text[text.index("\n%"):]
+        return re.sub(r", metadata=\{[^}]*\}", "", body)
+
+    assert plain(scoped) == plain(unscoped)
+
+
 def test_compile_clock_counts_nested_spans_once():
     trace, lower, backend = compile_cache.COMPILE_EVENTS
     got = compile_cache.span_seconds([
