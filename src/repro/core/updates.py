@@ -11,6 +11,15 @@ entries whose |l_jj| ≥ ω (safeguard against tiny curvature denominators).
 
 Direct update (Alg 3):
     B̃ = Ỹ M† Ỹᵀ;   B⁺ = (1-β) B + β B̃.
+
+Both take a symmetric B, as the engine's B always is (B⁰ = 0 and each
+update keeps it symmetric), and symmetrise only their m×m factors.  For
+symmetric B the symmetric part of the update is then exact algebra:
+sym((1-β)B + βỸPỸᵀ) = (1-β)B + βỸ·sym(P)·Ỹᵀ.  Symmetrising the [d, d]
+result instead would transpose it, which XLA:TPU lowers to a copy of B
+(a read and a write of the whole state) and which keeps the update from
+writing B in place: the update is one rank-m product with an elementwise
+epilogue that reads B once.
 """
 from __future__ import annotations
 
@@ -24,7 +33,7 @@ def _sym(a):
 
 
 def truncated_lsr1_update(B, Y_tilde, M, S, omega: float):
-    """Alg 2.  B: [d,d]; Y_tilde: [d,m]; M: [m,m]; S: [d,m]."""
+    """Alg 2.  B: [d,d] symmetric; Y_tilde: [d,m]; M: [m,m]; S: [d,m]."""
     BS = matmul(B, S)
     R = Y_tilde - BS                         # d x m residual
     G = _sym(M - matmul(S.T, BS))            # m x m  (= Sᵀ(H - B)S residual)
@@ -35,11 +44,10 @@ def truncated_lsr1_update(B, Y_tilde, M, S, omega: float):
     # (observed: NaN within ~100 iterations on the logreg problem).
     inv = jnp.sign(lam) / jnp.maximum(jnp.abs(lam), omega)
     W = matmul(R, U)
-    return _sym(B + matmul(W * inv[None, :], W.T)), G
+    return B + matmul(W * inv[None, :], W.T), G
 
 
 def direct_update(B, Y_tilde, M, beta: float):
-    """Alg 3.  B⁺ = (1-β) B + β Ỹ M† Ỹᵀ."""
-    B_tilde = matmul(matmul(Y_tilde, jnp.linalg.pinv(M, rcond=1e-10)),
-                     Y_tilde.T)
-    return _sym((1.0 - beta) * B + beta * B_tilde)
+    """Alg 3.  B⁺ = (1-β) B + β Ỹ sym(M†) Ỹᵀ, for symmetric B."""
+    P = _sym(jnp.linalg.pinv(M, rcond=1e-10))
+    return (1.0 - beta) * B + beta * matmul(matmul(Y_tilde, P), Y_tilde.T)

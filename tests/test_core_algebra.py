@@ -8,7 +8,8 @@ from repro.core.directions import (fedsonia_direction,
                                    truncated_inverse_direction)
 from repro.core.hessian import hvp, sketched_hessian
 from repro.core.sketch import sketch
-from repro.core.updates import direct_update, truncated_lsr1_update
+from repro.core.updates import _sym, direct_update, truncated_lsr1_update
+from repro.numerics import matmul
 
 
 # --- sketches --------------------------------------------------------------
@@ -98,6 +99,37 @@ def test_lsr1_secant_on_sketch():
     M = S.T @ Y
     B1, G = truncated_lsr1_update(B0, Y, M, S, omega=1e-8)
     np.testing.assert_allclose(B1 @ S, Y, rtol=5e-3, atol=5e-3)
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize("rule, beta", [("direct", 0.25), ("direct", 1.0),
+                                        ("lsr1", None)])
+def test_update_of_symmetric_B_matches_symmetrised_update(rule, beta):
+    """For symmetric B, symmetrising only the m×m factor gives what
+    symmetrising the [d, d] result gave, and B⁺ is symmetric."""
+    rng = np.random.default_rng(6)
+    d, m = 48, 4
+    H, B0 = _psd(rng, d), _psd(rng, d)
+    S = jnp.asarray(rng.normal(size=(d, m)) / np.sqrt(m), jnp.float32)
+    # compression noise on Ỹ; M = SᵀY is not exactly symmetric in f32
+    Y = matmul(H, S)
+    Yt = Y + 0.05 * jnp.asarray(rng.normal(size=(d, m)), jnp.float32)
+    M = matmul(S.T, Y)
+    if rule == "direct":
+        got = direct_update(B0, Yt, M, beta)
+        P = jnp.linalg.pinv(M, rcond=1e-10)
+        old = _sym((1.0 - beta) * B0 + beta * matmul(matmul(Yt, P), Yt.T))
+    else:
+        got, G = truncated_lsr1_update(B0, Yt, M, S, omega=1e-3)
+        lam, U = jnp.linalg.eigh(G)
+        inv = jnp.sign(lam) / jnp.maximum(jnp.abs(lam), 1e-3)
+        W = matmul(Yt - matmul(B0, S), U)
+        old = _sym(B0 + matmul(W * inv[None, :], W.T))
+    assert _rel(got, old) <= 1e-5
+    assert _rel(got, got.T) <= 1e-6
 
 
 # --- directions (Lemma 9 invariant) ---------------------------------------

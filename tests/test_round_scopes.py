@@ -98,6 +98,74 @@ def test_family_axis_compiles_only_its_branches(prob, extra, present):
         assert not any(scope in n for n in hess), scope
 
 
+def _computations(text) -> dict:
+    """HLO text by computation: name -> its instruction lines."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = []
+        elif name and line.startswith(" "):
+            comps[name].append(line)
+    return comps
+
+
+def _while_body(text) -> list:
+    """The instructions of every while body and of what it calls."""
+    comps = _computations(text)
+    todo = [c for lines in comps.values() for line in lines
+            for c in re.findall(r"\bbody=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"\b(?:calls|to_apply|body|condition)="
+                               r"%([\w.\-]+)", line)
+            for group in re.findall(r"\b(?:branch|called)_computations="
+                                    r"\{([^}]*)\}", line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return [line for c in seen for line in comps[c]]
+
+
+def _cell_plan(prob):
+    cfg = FlecsConfig(m=2, hessian_update="direct", direction="fedsonia",
+                      use_kernel=True)
+    hp = api.get_method("flecs_cgd").grid(
+        alphas=(0.5,), grad_specs=stack_specs("dither64", "topk0.1"))
+    return cfg, hp
+
+
+def test_curvature_update_neither_copies_nor_transposes_B(prob):
+    """The benchmark cell's plan updates B in one pass: no instruction of
+    the round loop copies or transposes a whole [G, n, d, d] state."""
+    cfg, hp = _cell_plan(prob)
+    (n, _, d), G = prob.A.shape, 2
+    text = _compile("flecs_cgd", cfg, hp, prob).as_text()
+    body = _while_body(text)
+    assert any(f"f32[{G},{n},{d},{d}]" in line for line in body)
+    whole_B = re.compile(rf"= f32\[{G},{n},{d},{d}\]\{{[^}}]*\}} "
+                         r"(copy|transpose)\(")
+    assert not [line for line in body if whole_B.search(line)]
+
+
+def test_engine_B_stays_symmetric(prob):
+    """B is symmetrised only through the update's m×m factor: after 30
+    rounds every worker's B is still symmetric to float32 rounding."""
+    cfg, hp = _cell_plan(prob)
+    spec, T = api.get_method("flecs_cgd"), 30
+    state = spec.init(prob, prob.A.shape[0], cfg)
+    keys = driver.sweep_keys(jax.random.key(1), 2, T)
+    fn = driver.sweep_program(spec.sweep_step(prob, cfg), T)
+    B = jax.jit(fn)(hp, state, keys)[0].B                  # [G, n, d, d]
+    asym = jnp.linalg.norm(B - B.swapaxes(-1, -2), axis=(-2, -1))
+    assert float(jnp.min(jnp.linalg.norm(B, axis=(-2, -1)))) > 0
+    assert float(jnp.max(asym / jnp.linalg.norm(B, axis=(-2, -1)))) <= 1e-6
+
+
 def test_diana_program_names_its_phases_and_families(prob):
     G = len(FAMILIES)
     hp = DianaHParams(jnp.full((G,), 0.5), jnp.full((G,), 0.5),
