@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import functools
 import time
 from pathlib import Path
 
@@ -30,7 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.compressors import Compressor, compress, make_spec
-from repro.core.flecs import FlecsConfig, init_state, make_flecs_step
+from repro.core.flecs import (FlecsConfig, hparams_from_config, init_state,
+                              make_flecs_sweep_step)
 from repro.data.logreg import make_problem
 
 OUT = Path(__file__).resolve().parent / "out" / "kernel_bench.json"
@@ -93,7 +95,8 @@ def run(csv_rows: list, *, toy: bool = False, iters: int = 20):
         for m in ((1, 4) if toy else (1, 4, 8)):
             cfg = FlecsConfig(m=m, grad_compressor="dither64",
                               hess_compressor="dither64")
-            step = jax.jit(make_flecs_step(cfg, lg, lh))
+            step = jax.jit(functools.partial(
+                make_flecs_sweep_step(cfg, lg, lh), hparams_from_config(cfg)))
             st = init_state(jnp.zeros(prob.d), prob.n_workers)
 
             def f(st, key):

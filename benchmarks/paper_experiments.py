@@ -44,6 +44,7 @@ Standalone smoke entries (the CI sweep-smoke / plan-smoke jobs)::
 """
 from __future__ import annotations
 
+import functools
 import json
 import time
 from pathlib import Path
@@ -55,17 +56,22 @@ import numpy as np
 from repro.core import api
 from repro.core.api import ExperimentPlan, MethodRun, get_method, run_plan
 from repro.core.compressors import stack_specs
-from repro.core.driver import (StalenessSchedule, run_async_sweep,
-                               run_experiment, run_sweep)
+from repro.core.driver import (AsyncHParams, StalenessSchedule,
+                               init_async_state, make_async_step,
+                               run_async_sweep, run_experiment, run_sweep)
 from repro.core.flecs import (FlecsConfig, async_hparam_grid, bits_per_round,
-                              hparam_grid, init_async_state, init_state,
-                              make_flecs_async_step,
-                              make_flecs_async_sweep_step, make_flecs_step,
-                              make_flecs_sweep_step)
+                              hparam_grid, hparams_from_config, init_state,
+                              make_flecs_async_hooks, make_flecs_sweep_step)
 from repro.data.logreg import make_problem
 from repro.optim.baselines import DianaConfig, FedNLConfig, GDConfig
 
 OUT = Path(__file__).resolve().parent / "out"
+
+
+def _flecs_step(cfg, lg, lh):
+    """The FLECS sweep step at the config's own hparams point."""
+    return functools.partial(make_flecs_sweep_step(cfg, lg, lh),
+                             hparams_from_config(cfg))
 
 
 def assert_one_compile(run):
@@ -158,7 +164,7 @@ def fig3_iterate_updates(prob, iters=300):
     ):
         cfg = FlecsConfig(m=4, grad_compressor="dither64",
                           hess_compressor="dither64", **kw)
-        step = make_flecs_step(cfg, lg, lh)
+        step = _flecs_step(cfg, lg, lh)
         st = init_state(jnp.zeros(prob.d), prob.n_workers)
         rows, dt = _trajectory(step, st, prob, iters)
         results[name] = rows
@@ -176,7 +182,7 @@ def comm_table(prob):
                                  ("FLECS-CGD", "dither64", 8)):
             cfg = FlecsConfig(m=m, grad_compressor=gc,
                               hess_compressor="dither64")
-            step = make_flecs_step(cfg, lg, lh)
+            step = _flecs_step(cfg, lg, lh)
             st = init_state(jnp.zeros(prob.d), prob.n_workers)
             st, _ = run_experiment(step, st, jax.random.key(0), 1)
             measured = float(st.bits_per_node[0])
@@ -228,7 +234,7 @@ def ablation_dither_levels(prob, iters=200):
     for s in (4, 16, 64, 128):
         cfg = FlecsConfig(m=1, grad_compressor=f"dither{s}",
                           hess_compressor=f"dither{s}")
-        step = make_flecs_step(cfg, lg, lh)
+        step = _flecs_step(cfg, lg, lh)
         st, tr = run_experiment(step, init_state(jnp.zeros(prob.d),
                                                  prob.n_workers),
                                 jax.random.key(0), iters,
@@ -456,8 +462,10 @@ def async_grid(prob, iters=600):
     taus = [0, 2, 4]
     Ks = sorted({1.0, float(max(1, n // 4)), float(n)})
     ahp = async_hparam_grid(taus, Ks, alpha=1.0, auto_damp=(p, n))
-    sweep = make_flecs_async_sweep_step(cfg, lg, lh)
-    st0 = init_async_state(jnp.zeros(prob.d), n, cfg.m, max(taus))
+    hooks = make_flecs_async_hooks(cfg, lg, lh)
+    sweep = make_async_step(hooks)
+    st0 = init_async_state(hooks, init_state(jnp.zeros(prob.d), n),
+                           hparams_from_config(cfg), max(taus))
     t0 = time.perf_counter()
     sts, tr = run_async_sweep(sweep, ahp, st0, jax.random.key(0), iters,
                               record=lambda st: prob.metrics(st.w))
@@ -491,10 +499,14 @@ def staleness_ablation(prob, iters=600):
                               participation=p, sampling="choice")
             sched = StalenessSchedule(kind, tau=tau, q=0.5)
             K = n if (tau == 0 and p == 1.0) else max(1, n // 4)
-            step = make_flecs_async_step(cfg, lg, lh, sched, buffer_k=K)
+            hooks = make_flecs_async_hooks(cfg, lg, lh)
+            hp = hparams_from_config(cfg)
+            step = functools.partial(
+                make_async_step(hooks, sched.kind, sched.q),
+                AsyncHParams(hp, jnp.int32(tau), jnp.float32(K)))
             st, tr = run_experiment(
-                step, init_async_state(jnp.zeros(prob.d), n, cfg.m,
-                                       sched.max_delay),
+                step, init_async_state(hooks, init_state(jnp.zeros(prob.d), n),
+                                       hp, sched.max_delay),
                 jax.random.key(0), iters, record_every=5,
                 record=lambda st: prob.metrics(st.w))
             # record_every=5 thins traces on device; arrival-weighted

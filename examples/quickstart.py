@@ -7,12 +7,15 @@ random-dithering compression) on a synthetic heterogeneous federation and
 prints objective / gradient norm / communicated bits per node.  The whole
 trajectory is one compiled lax.scan program (``repro.core.driver``).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.driver import run_experiment
-from repro.core.flecs import FlecsConfig, init_state, make_flecs_step
+from repro.core.flecs import (FlecsConfig, hparams_from_config, init_state,
+                              make_flecs_sweep_step)
 from repro.data.logreg import make_problem
 from repro.launch.compile_cache import enable_compile_cache
 
@@ -28,7 +31,9 @@ def main():
         hess_compressor="dither64",
         alpha=1.0, beta=1.0, gamma=1.0,
     )
-    step = make_flecs_step(cfg, local_grad, local_hvp)
+    # the sweep step at the config's own hparams point: one run
+    step = functools.partial(make_flecs_sweep_step(cfg, local_grad, local_hvp),
+                             hparams_from_config(cfg))
     state = init_state(jnp.zeros(prob.d), prob.n_workers)
 
     iters = 201

@@ -5,8 +5,9 @@ Scope resolution (shared by both rules): within each module under
 
 * every function whose whole body IS a traced round (``_flecs_round``), and
 * every function def nested inside a step factory (``make_*_step`` /
-  ``make_*_sweep_step``) — the closures those factories return are exactly
-  the step/scan bodies ``driver.run_experiment`` compiles, and
+  ``make_*_sweep_step``) or a method's async-hooks factory
+  (``make_*_async_hooks``) — the closures those factories return are
+  exactly the step/scan bodies ``driver.run_experiment`` compiles, and
 * every module-level function transitively *called* from either of the
   above (per-module resolution: cross-module calls such as
   ``driver.masked_mean`` are linted when their own module is linted).
@@ -40,7 +41,7 @@ from typing import Dict, Iterable, List, Set, Tuple
 from repro.analysis.engine import Finding, ModuleContext, rule
 
 #: Factory / round-function names whose closures form the traced set.
-TRACED_ROOT_RE = re.compile(r"^(make_\w+_step|_flecs_round)$")
+TRACED_ROOT_RE = re.compile(r"^(make_\w+_(step|hooks)|_flecs_round)$")
 
 #: (file basename, root function name) -> justification.  Loops inside
 #: these roots' traced closures are deliberate and safe.

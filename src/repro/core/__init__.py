@@ -6,9 +6,10 @@ Traced compressor algebra (specs as vmappable sweep axes; make_spec is
 the one constructor — names, specs, and Compressors all normalize there):
     from repro.core.compressors import make_spec, compress, spec_bits
 Exact mode (paper-scale problems):
-    from repro.core.flecs import FlecsConfig, init_state, make_flecs_step
-Experiment engine (lax.scan runs, client sampling, vmapped sweeps):
-    from repro.core.driver import run_experiment, run_sweep, run_async_sweep
+    from repro.core.flecs import FlecsConfig, init_state, make_flecs_sweep_step
+Experiment engine (lax.scan runs, client sampling, vmapped sweeps, the
+buffered-async engine):
+    from repro.core.driver import run_experiment, run_sweep, make_async_step
 Production traffic simulation (arrivals, availability, admission):
     from repro.core.traffic import TrafficModel, ArrivalSchedule
 DL-scale trainer (TPU-pod realization):
@@ -30,21 +31,22 @@ from repro.core.compressors import (FAMILY_COUNT_SKETCH, FAMILY_DITHER,
                                     SketchParams, spec_bits, spec_bits_many,
                                     spec_commutes_with_sum, spec_from_name,
                                     spec_omega, stack_specs, topk_spec)
-from repro.core.driver import (COHORT_SALT, cohort_indices, damped_alpha,
+from repro.core.driver import (COHORT_SALT, AsyncHParams, AsyncHooks,
+                               AsyncState, cohort_indices, damped_alpha,
                                freeze_on_bit_budget, hparams_bit_budget,
-                               iters_for_bit_budget, participation_mask,
+                               init_async_state, iters_for_bit_budget,
+                               make_async_step, participation_mask,
                                resolve_participation, run_async_sweep,
                                run_experiment, run_sharded_sweep, run_sweep,
                                sweep_keys, sweep_program, worker_mesh)
-from repro.core.flecs import (FlecsAsyncHParams, FlecsCohortState,
-                              FlecsConfig, FlecsHParams, FlecsState,
-                              async_hparam_grid, bits_per_round,
+from repro.core.flecs import (FlecsCohortState, FlecsConfig, FlecsHParams,
+                              FlecsState, async_hparam_grid, bits_per_round,
                               hparam_grid, hparams_round_bits,
                               init_cohort_state, init_state,
+                              make_flecs_async_hooks,
                               make_flecs_cohort_sweep_step,
                               make_flecs_sharded_sweep_step,
-                              make_flecs_step, make_flecs_sweep_step,
-                              sharded_state_specs)
+                              make_flecs_sweep_step, sharded_state_specs)
 from repro.core.hierarchy import (EDGE_SALT, HierarchyConfig, charge_edges,
                                   edge_combine, edge_combine_cohort,
                                   edge_of, edge_round_bits, init_edge_bits,
@@ -70,18 +72,20 @@ __all__ = ["Compressor", "CompressorSpec", "FAMILY_COUNT_SKETCH",
            "stack_specs", "topk_spec",
            "ARRIVAL_SALT", "AVAILABLE", "AVAIL_SALT", "AdmissionPolicy",
            "ArrivalSchedule", "AvailabilityModel", "BUSY",
-           "COHORT_SALT", "DROPPED", "EDGE_SALT", "FlecsAsyncHParams",
-           "FlecsCohortState", "FlecsConfig", "FlecsHParams", "FlecsState",
+           "AsyncHParams", "AsyncHooks", "AsyncState",
+           "COHORT_SALT", "DROPPED", "EDGE_SALT", "FlecsCohortState", "FlecsConfig", "FlecsHParams", "FlecsState",
            "HierarchyConfig", "TrafficHParams", "TrafficModel",
            "TrafficState", "admit_arrivals", "async_hparam_grid",
            "availability_step", "available_mask", "bits_per_round",
            "charge_edges", "cohort_indices", "damped_alpha", "edge_combine",
            "edge_combine_cohort", "edge_of", "edge_round_bits",
            "freeze_on_bit_budget", "hparam_grid", "hparams_bit_budget",
-           "hparams_round_bits", "init_cohort_state", "init_edge_bits",
-           "init_state", "init_traffic_state", "iters_for_bit_budget",
-           "make_flecs_cohort_sweep_step", "make_flecs_sharded_sweep_step",
-           "make_flecs_step", "make_flecs_sweep_step", "participation_mask",
+           "hparams_round_bits", "init_async_state", "init_cohort_state",
+           "init_edge_bits", "init_state", "init_traffic_state",
+           "iters_for_bit_budget", "make_async_step",
+           "make_flecs_async_hooks", "make_flecs_cohort_sweep_step",
+           "make_flecs_sharded_sweep_step", "make_flecs_sweep_step",
+           "participation_mask",
            "replay_delays", "resolve_participation", "run_async_sweep",
            "run_experiment", "run_sharded_sweep", "run_sweep",
            "sharded_state_specs", "sketch", "stationary_distribution",

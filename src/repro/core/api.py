@@ -7,11 +7,11 @@ module makes a whole comparison figure a single declarative object:
 
 * :class:`MethodSpec` — a method as *data*: ``init(problem, n, cfg)``, one
   sweep-native ``step(hp, state, key)``, an hparam pytree with
-  ``grid(...)`` / ``from_config(...)`` constructors, and optional async
-  variants on the shared ``MessageBuffer`` machinery.  :func:`get_method`
-  resolves ``"flecs" | "flecs_cgd" | "diana" | "fednl" | "gd"``; the legacy
-  ``make_*_step`` entry points are concrete specializations of the same
-  sweep steps, so the registry changes no numerics.
+  ``grid(...)`` / ``from_config(...)`` constructors, and its hooks into
+  the driver's one buffered-async engine (``driver.make_async_step``).
+  :func:`get_method` resolves ``"flecs" | "flecs_cgd" | "diana" |
+  "fednl" | "gd"``; a single run is the same sweep step at one hparams
+  point, so the registry changes no numerics.
 * :class:`MethodRun` — one *structural segment* of a figure: a method, its
   static config (sampling kind, FLECS's sketch size m, FedNL's μ — the
   things that change array shapes or code paths), and a [G] hparam grid
@@ -37,8 +37,8 @@ Key streams (reproducibility contract): run ``j`` of a plan sweeps with
 ``fold_in(key(plan.seed), j)``, and its grid point ``g`` consumes the
 stream ``split(split(fold_in(key(seed), j), G)[g], iters)`` — exactly what
 a standalone ``run_experiment(step_g, state, split(fold_in(key, j), G)[g],
-iters)`` would use.  tests/test_api.py pins ``run_plan`` against the
-legacy per-method paths with exact bit ledgers for all five methods.
+iters)`` would use.  tests/test_api.py pins ``run_plan`` against
+single-point runs of each method with exact bit ledgers for all five.
 
 Compile accounting: every :func:`run_plan` call jits ONE fresh program
 whose trace increments :func:`plan_compiles` — the one-compile-per-figure
@@ -88,9 +88,10 @@ import numpy as np
 
 from repro.core import flecs
 from repro.core.compressors import make_spec
-from repro.core.driver import (StalenessSchedule, bits_dtype,
-                               hparams_bit_budget, iters_for_bit_budget,
-                               sweep_keys, sweep_program)
+from repro.core.driver import (AsyncHParams, StalenessSchedule, bits_dtype,
+                               check_buffer_slots, hparams_bit_budget,
+                               init_async_state, iters_for_bit_budget,
+                               make_async_step, sweep_keys, sweep_program)
 from repro.core.traffic import (TrafficModel, init_traffic_state,
                                 traffic_hparams)
 from repro.optim import baselines
@@ -112,16 +113,11 @@ class MethodSpec:
     sweep_step:      (problem, cfg) -> step(hp, state, key) with every hp
                      field traced (``driver.run_sweep``-compatible).
     grid:            keyword axes -> [G] hparam pytree (cartesian).
-    from_config:     (cfg) -> scalar hparam point (what the legacy
-                     ``make_*_step`` wrappers specialize at).
-    init_async / async_sweep_step / async_wrap: the FedBuff-style buffered
-                     engine (None => the method has no async variant);
-                     ``async_sweep_step(problem, cfg, delay_kind, q,
-                     traffic)`` takes the plan's optional
-                     ``repro.core.traffic`` model; ``async_wrap(hp, tau,
-                     buffer_k)`` broadcasts the traced staleness axes over
-                     the grid (the plan lowering then attaches the traced
-                     traffic leaves).
+    from_config:     (cfg) -> scalar hparam point (a single run's hparams).
+    async_hooks:     (problem, cfg) -> ``driver.AsyncHooks``, the method's
+                     part of the buffered-async engine
+                     (``driver.make_async_step``); None => the method has
+                     no async variant.
     round_bits:      (problem, cfg, hp) -> per-participating-worker uplink
                      bits of one round at each grid point ([G]) — the
                      spec-aware wire-price query ``plan.bit_budget`` uses
@@ -135,16 +131,8 @@ class MethodSpec:
     sweep_step: Callable[[Any, Any], Callable]
     grid: Callable[..., Any]
     from_config: Callable[[Any], Any]
-    init_async: Optional[Callable] = None
-    async_sweep_step: Optional[Callable] = None
-    async_wrap: Optional[Callable] = None
+    async_hooks: Optional[Callable] = None
     round_bits: Optional[Callable] = None
-
-
-def _broadcast(hp, tau, buffer_k, wrapper):
-    G = jax.tree.leaves(hp)[0].shape[0]
-    return wrapper(hp, jnp.full((G,), tau, jnp.int32),
-                   jnp.full((G,), buffer_k, jnp.float32))
 
 
 def _flecs_grid(alphas=(1.0,), gammas=(1.0,), betas=(1.0,),
@@ -242,14 +230,8 @@ def _flecs_spec(name: str, default_grad: str) -> MethodSpec:
             cfg, *prob.make_oracles()),
         grid=grid,
         from_config=flecs.hparams_from_config,
-        init_async=lambda prob, n, cfg, max_delay: flecs.init_async_state(
-            jnp.zeros(prob.d), n, cfg.m, max_delay),
-        async_sweep_step=lambda prob, cfg, kind, q, traffic=None:
-            flecs.make_flecs_async_sweep_step(cfg, *prob.make_oracles(),
-                                              delay_kind=kind, q=q,
-                                              traffic=traffic),
-        async_wrap=lambda hp, tau, K: _broadcast(
-            hp, tau, K, flecs.FlecsAsyncHParams),
+        async_hooks=lambda prob, cfg: flecs.make_flecs_async_hooks(
+            cfg, *prob.make_oracles()),
         round_bits=lambda prob, cfg, hp: flecs.hparams_round_bits(
             cfg, hp, prob.d),
     )
@@ -295,14 +277,8 @@ register_method(MethodSpec(
         cfg, prob.make_oracles()[0]),
     grid=baselines.diana_hparam_grid,
     from_config=baselines.diana_hparams_from_config,
-    init_async=lambda prob, n, cfg, max_delay: baselines.init_diana_async(
-        jnp.zeros(prob.d), n, max_delay),
-    async_sweep_step=lambda prob, cfg, kind, q, traffic=None:
-        baselines.make_diana_async_sweep_step(
-            cfg, prob.make_oracles()[0], delay_kind=kind, q=q,
-            traffic=traffic),
-    async_wrap=lambda hp, tau, K: _broadcast(
-        hp, tau, K, baselines.DianaAsyncHParams),
+    async_hooks=lambda prob, cfg: baselines.make_diana_async_hooks(
+        cfg, prob.make_oracles()[0]),
     round_bits=lambda prob, cfg, hp: baselines.diana_round_bits(
         cfg, hp, prob.d),
 ))
@@ -316,14 +292,8 @@ register_method(MethodSpec(
         cfg, prob.make_oracles()[0], _local_hessian(prob)),
     grid=baselines.fednl_hparam_grid,
     from_config=baselines.fednl_hparams_from_config,
-    init_async=lambda prob, n, cfg, max_delay: baselines.init_fednl_async(
-        jnp.zeros(prob.d), n, max_delay),
-    async_sweep_step=lambda prob, cfg, kind, q, traffic=None:
-        baselines.make_fednl_async_sweep_step(
-            cfg, prob.make_oracles()[0], _local_hessian(prob),
-            delay_kind=kind, q=q, traffic=traffic),
-    async_wrap=lambda hp, tau, K: _broadcast(
-        hp, tau, K, baselines.FedNLAsyncHParams),
+    async_hooks=lambda prob, cfg: baselines.make_fednl_async_hooks(
+        cfg, prob.make_oracles()[0], _local_hessian(prob)),
     round_bits=lambda prob, cfg, hp: baselines.fednl_round_bits(
         cfg, hp, prob.d),
 ))
@@ -337,14 +307,8 @@ register_method(MethodSpec(
         cfg, prob.make_oracles()[0], prob.n_workers),
     grid=baselines.gd_hparam_grid,
     from_config=baselines.gd_hparams_from_config,
-    init_async=lambda prob, n, cfg, max_delay: baselines.init_gd_async(
-        jnp.zeros(prob.d), n, max_delay),
-    async_sweep_step=lambda prob, cfg, kind, q, traffic=None:
-        baselines.make_gd_async_sweep_step(
-            cfg, prob.make_oracles()[0], prob.n_workers,
-            delay_kind=kind, q=q, traffic=traffic),
-    async_wrap=lambda hp, tau, K: _broadcast(
-        hp, tau, K, baselines.GDAsyncHParams),
+    async_hooks=lambda prob, cfg: baselines.make_gd_async_hooks(
+        cfg, prob.make_oracles()[0], prob.n_workers),
     round_bits=lambda prob, cfg, hp: baselines.gd_round_bits(
         cfg, hp, prob.d),
 ))
@@ -572,16 +536,20 @@ def _resolve(plan: ExperimentPlan, run: MethodRun):
         hp, bud = cross_bit_budget(hp, budgets)
     n = plan.problem.n_workers
     if plan.staleness is not None:
-        if spec.async_sweep_step is None:
+        if spec.async_hooks is None:
             raise ValueError(
                 f"method {spec.name!r} has no async variant — drop it from "
                 "the plan or clear plan.staleness")
         sched = plan.staleness
-        step = spec.async_sweep_step(plan.problem, cfg, sched.kind, sched.q,
-                                     plan.traffic)
-        state = spec.init_async(plan.problem, n, cfg, sched.max_delay)
-        if not hasattr(hp, "tau"):
-            hp = spec.async_wrap(hp, sched.tau, plan.buffer_k)
+        hooks = spec.async_hooks(plan.problem, cfg)
+        step = make_async_step(hooks, sched.kind, sched.q, plan.traffic)
+        state = init_async_state(hooks, spec.init(plan.problem, n, cfg),
+                                 spec.from_config(cfg), sched.max_delay)
+        if not isinstance(hp, AsyncHParams):
+            # the plan's staleness point, broadcast over the run's grid
+            G = _grid_size(hp)
+            hp = AsyncHParams(hp, jnp.full((G,), sched.tau, jnp.int32),
+                              jnp.full((G,), plan.buffer_k, jnp.float32))
         if plan.traffic is not None:
             # seed the availability chain and broadcast the model's traced
             # leaves over the run's [G] grid (a run whose async hparams
@@ -593,23 +561,14 @@ def _resolve(plan: ExperimentPlan, run: MethodRun):
                 G = _grid_size(hp)
                 hp = hp._replace(traffic=jax.tree.map(
                     lambda a: jnp.broadcast_to(a, (G,) + a.shape), thp))
-        # the run_async_sweep buffer-shape guard: a user-supplied tau grid
-        # exceeding the schedule's max_delay would wrap modulo the buffer
-        # slots and silently behave as a shorter delay
-        slots = state.buf.occupied.shape[0]
-        tau_max = int(jnp.max(hp.tau))
-        if tau_max + 1 > slots:
-            raise ValueError(
-                f"run {spec.name!r}: shared MessageBuffer has {slots} "
-                f"slot(s) but the hparam grid reaches tau={tau_max}; raise "
-                f"plan.staleness.tau to >= {tau_max}")
+        check_buffer_slots(hp, state)
     else:
         if plan.traffic is not None:
             raise ValueError(
                 "plan.traffic rides the async engine's buffered path — set "
                 "plan.staleness (tau=0 for synchronous-delay traffic) or "
                 "drop the traffic model")
-        if hasattr(hp, "tau"):
+        if isinstance(hp, AsyncHParams):
             raise ValueError(
                 f"run {spec.name!r}: async hparams (tau/buffer_k axes) "
                 "require plan.staleness — set a StalenessSchedule or pass "

@@ -75,26 +75,21 @@ the scan state:
 
 Arrived updates accumulate in a FedBuff aggregation buffer; once ``K``
 updates have buffered, the server applies one aggregate step and resets the
-buffer.  Communication bits are charged at the *arrival* round.  With
-``tau = 0`` and ``K = n`` (full participation) — or ``K = 1`` under client
-sampling — the async engine provably collapses to the synchronous one
-(tested in tests/test_async_aggregation.py).
+buffer.  Communication bits are charged at the *arrival* round.  This is
+ONE composition, :func:`make_async_step`, for every method; a method
+supplies only its :class:`AsyncHooks`.  With ``tau = 0`` and ``K = n``
+(full participation) — or ``K = 1`` under client sampling — it collapses
+to the synchronous round (tests/test_async_aggregation.py).
 
-Async quickstart (FLECS-CGD, fixed 2-round delay, half the clients)::
+Async quickstart (FLECS-CGD, fixed 2-round delay, half the clients) — a
+plan with a ``staleness`` runs every method on this engine::
 
-    from repro.core.driver import StalenessSchedule, run_experiment
-    from repro.core.flecs import (FlecsConfig, init_async_state,
-                                  make_flecs_async_step)
-
-    cfg = FlecsConfig(m=2, alpha=0.5, participation=0.5, sampling="choice")
-    sched = StalenessSchedule(kind="fixed", tau=2)
-    step = make_flecs_async_step(cfg, local_grad, local_hvp, sched,
-                                 buffer_k=4)
-    state = init_async_state(w0, n_workers=8, m=cfg.m,
-                             max_delay=sched.max_delay)
-    state, traces = run_experiment(
-        step, state, jax.random.key(0), iters=600,
-        record=lambda st: {"F": prob.global_loss(st.w)})
+    plan = ExperimentPlan(
+        problem=prob, iters=600, buffer_k=4,
+        runs=(MethodRun("flecs_cgd", cfg=FlecsConfig(
+            m=2, alpha=0.5, participation=0.5, sampling="choice")),),
+        staleness=StalenessSchedule(kind="fixed", tau=2))
+    traces = run_plan(plan).traces["flecs_cgd"]
     # traces["bits_per_node"]: bits charged at the round each message
     #                          *arrives*, not when it was computed.
     # traces["staleness_mean"]: average age (rounds) of applied updates.
@@ -148,7 +143,7 @@ def _concrete_nonpositive(p) -> bool:
     values only exist at run time) report False — their grids are validated
     at construction instead."""
     try:
-        return bool(jnp.any(p <= 0))
+        return bool(jnp.any(p <= 0))  # repro-lint: disable=R2 -- trace-time probe of a concrete value; a tracer raises and takes the except path
     except jax.errors.ConcretizationTypeError:
         return False
 
@@ -182,11 +177,11 @@ def participation_mask(key, n: int, p=1.0, kind: str = "bernoulli",
     Both kinds are pure functions of (key, n, p, kind, cohort) and trace
     cleanly under jit/vmap/scan.
     """
-    rows = n if cohort is None else int(cohort)
+    rows = n if cohort is None else int(cohort)  # repro-lint: disable=R2 -- static Python cohort size
     if not isinstance(p, (int, float)):
         try:
             # any CONCRETE scalar (numpy/jax) stays on the static path
-            p = float(p)
+            p = float(p)  # repro-lint: disable=R2 -- trace-time probe of a concrete p; a tracer raises and takes the traced path
         except (TypeError, jax.errors.ConcretizationTypeError):
             # traced path: p only exists at run time (a jit argument or a
             # vmapped sweep axis)
@@ -211,7 +206,7 @@ def participation_mask(key, n: int, p=1.0, kind: str = "bernoulli",
                 f"kind='choice', which always samples at least one worker)")
         return (jax.random.uniform(key, (rows,)) < p).astype(jnp.float32)
     if kind == "choice":
-        k = max(1, int(round(p * rows)))
+        k = max(1, int(round(p * rows)))  # repro-lint: disable=R2 -- p is a Python float here (the static path)
         perm = jax.random.permutation(key, rows)
         return (perm < k).astype(jnp.float32)
     raise ValueError(f"unknown sampling kind: {kind!r}")
@@ -263,10 +258,10 @@ def masked_sum(x: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(mask.reshape(shape) * x, axis=0)
 
 
-# fold_in salt for the async steps' per-round delay key.  Deriving the
+# fold_in salt for the async engine's per-round delay key.  Deriving the
 # delay key via fold_in (not by widening the step key's split) keeps each
 # method's synchronous key split untouched, which is what makes tau=0
-# trace-exact.  All async step makers share this constant.
+# trace-exact (:func:`make_async_step`).
 ASYNC_SALT = 0x5A17
 
 # fold_in salt for the cohort steps' per-round selection key.  Like
@@ -455,7 +450,7 @@ def buffer_receive(buf: MessageBuffer, k):
 
 
 def fedbuff_accumulate(acc, acc_n, contributions, arrived, buffer_k: int):
-    """One round of FedBuff server bookkeeping, shared by every async step.
+    """One round of FedBuff server bookkeeping (:func:`make_async_step`).
 
     acc:           pytree of running sums since the last flush.
     contributions: matching pytree of per-worker [n, ...] values; rows with
@@ -485,6 +480,164 @@ def applied_staleness(k, msg_t, arrived):
     stamp, averaged over the arrival mask (0 when nothing arrived)."""
     return (jnp.sum(arrived * (jnp.float32(k) - msg_t))
             / jnp.maximum(jnp.sum(arrived), 1.0))
+
+
+# ---------------------------------------------------------------------------
+# The buffered-async engine: one FedBuff composition for every method
+# ---------------------------------------------------------------------------
+
+class AsyncHParams(NamedTuple):
+    """Async sweep point: a method's synchronous hparams plus the staleness
+    axes.
+
+      hp       — the method's sync hparams (alpha possibly auto-damped; see
+                 :func:`damped_alpha`)
+      tau      — int32 delay-model bound (fixed delay / uniform-geometric
+                 cap), traced per grid point
+      buffer_k — float32 FedBuff flush threshold, traced per grid point
+      traffic  — optional traced ``repro.core.traffic`` leaves (rate
+                 tables, availability transitions, admission caps)
+    """
+    hp: Any
+    tau: jnp.ndarray
+    buffer_k: jnp.ndarray
+    traffic: Optional[Any] = None
+
+
+class AsyncState(NamedTuple):
+    """A method's synchronous state plus the in-flight and FedBuff buffers.
+
+    sync:    the method's own state (``FlecsState``, ``DianaState``, …);
+             its fields read through, so ``state.w`` and
+             ``state.bits_per_node`` work on the async state too.
+    buf:     :class:`MessageBuffer` of the method's messages plus ``t``,
+             the compute round (staleness accounting, sketch regeneration).
+    acc:     FedBuff running sums of the arrival contributions since the
+             last flush; ``acc_n`` counts the buffered updates.
+    traffic: optional availability chain state (``repro.core.traffic``).
+    """
+    sync: Any
+    buf: MessageBuffer
+    acc: Any
+    acc_n: jnp.ndarray
+    traffic: Optional[Any] = None
+
+    def __getattr__(self, name):
+        return getattr(self.sync, name)
+
+
+class AsyncHooks(NamedTuple):
+    """What one method adds to :func:`make_async_step`.
+
+    cfg:     the method's static config (``participation``, ``sampling``).
+    n_keys:  how many ways the round key splits — the method's synchronous
+             split; the last key draws participation, the others go to
+             ``compute``.
+    compute: (hp, state, keys) -> work, a no-argument function returning
+             the dict of [n, ...] worker messages at the current iterate.
+             The engine runs ``work`` only on rounds where someone sends;
+             what ``compute`` does before returning it runs every round.
+    arrive:  (hp, state, msg, arrived) -> (state', contributions): the
+             per-worker server learning and billing of this round's
+             arrivals, and the dict of [n, ...] values the FedBuff buffer
+             averages (its ``"g"`` entry is the gradient estimate).
+    flush:   (hp, state, means, flush) -> (w', aux): the server step from
+             the buffered means where ``flush``, and its extra aux entries.
+    """
+    cfg: Any
+    n_keys: int
+    compute: Callable
+    arrive: Callable
+    flush: Callable
+
+
+def init_async_state(hooks: AsyncHooks, state, hp,
+                     max_delay: int) -> AsyncState:
+    """The async state around a method's initial synchronous ``state``: an
+    empty buffer shaped like its messages and zero FedBuff sums, both from
+    ``jax.eval_shape`` of the hooks at the hparams point ``hp``."""
+    n = state.bits_per_node.shape[0]
+
+    def shapes():
+        keys = jax.random.split(jax.random.key(0), hooks.n_keys)[:-1]
+        msg = {**hooks.compute(hp, state, keys)(),
+               "t": jnp.zeros((n,), jnp.float32)}
+        return msg, hooks.arrive(hp, state, msg, msg["t"])[1]
+
+    proto, contrib = jax.eval_shape(shapes)
+    acc = jax.tree.map(lambda c: jnp.zeros(c.shape[1:], c.dtype), contrib)
+    return AsyncState(state, init_buffer(proto, max_delay), acc,
+                      jnp.zeros((), jnp.float32))
+
+
+def make_async_step(hooks: AsyncHooks, delay_kind: str = "fixed",
+                    q: float = 0.5, traffic=None):
+    """Build step(ahp: AsyncHParams, state: AsyncState, key) -> (state,
+    aux), the buffered round of the method ``hooks`` describe.  Per round:
+    sample clients, excluding busy workers; the sampled workers compute
+    at the current iterate (``hooks.compute``); messages are filed under
+    arrival round ``k + delay``; this round's arrivals learn and are
+    billed (``hooks.arrive``) and join the FedBuff buffer; once
+    ``buffer_k`` updates have buffered the server steps from their means
+    (``hooks.flush``) and the buffer resets.  tau, buffer_k and every sync
+    hparam are traced (:func:`run_async_sweep`).  The method's own key
+    split and ``fold_in(key, ASYNC_SALT)`` for the delays keep its
+    synchronous key stream.  A ``traffic`` model (``repro.core.traffic``)
+    layers arrivals, availability and admission on the same path — only
+    admitted arrivals bill or learn; ``traffic=None`` is the plain engine.
+    """
+    # imported here: repro.core.traffic imports this module
+    from repro.core.traffic import admit_arrivals, traffic_send
+    cfg = hooks.cfg
+
+    def step(ahp: AsyncHParams, state: AsyncState, key):
+        hp, st = ahp.hp, state.sync
+        n = st.bits_per_node.shape[0]
+        *keys, k_p = jax.random.split(key, hooks.n_keys)   # == sync split
+        k_tau = jax.random.fold_in(key, ASYNC_SALT)
+        mask = resolve_participation(k_p, n, cfg.participation,
+                                     cfg.sampling, hp.p)
+        base_delays = sample_delays(delay_kind, k_tau, n, ahp.tau, q)
+        if traffic is None:
+            send_mask = mask * (1.0 - buffer_busy(state.buf))
+            delays, tstate = base_delays, state.traffic
+        else:
+            send_mask, delays, tstate = traffic_send(
+                traffic, ahp.traffic, state.traffic, state.buf, mask, key,
+                st.k, ahp.tau, base_delays)
+
+        # cond-gate the worker compute: in a fixed-delay cycle most rounds
+        # send nothing (everyone is busy), so skip the n oracle calls on
+        # those rounds — the results would be all-masked anyway
+        work = hooks.compute(hp, st, keys)
+        msgs = jax.lax.cond(
+            jnp.any(send_mask > 0),
+            lambda _: work(),
+            lambda _: {name: jnp.zeros(s.shape[1:], s.dtype)
+                       for name, s in state.buf.slots.items() if name != "t"},
+            None)
+        msgs = {**msgs, "t": jnp.full((n,), st.k, jnp.float32)}
+        buf = buffer_send(state.buf, msgs, send_mask, delays, st.k)
+        buf, msg, arrived = buffer_receive(buf, st.k)
+        arrived = admit_arrivals(traffic, ahp.traffic, arrived, msg["t"],
+                                 st.k)
+
+        sync, contrib = hooks.arrive(hp, st, msg, arrived)
+        acc, acc_n, means, flush, reset = fedbuff_accumulate(
+            state.acc, state.acc_n, contrib, arrived, ahp.buffer_k)
+        w, flush_aux = hooks.flush(hp, st, means, flush)
+        new = AsyncState(sync._replace(w=w, k=st.k + 1), buf, reset(acc),
+                         reset(acc_n), tstate)
+        aux = {"g_tilde_norm": jnp.linalg.norm(means["g"]), **flush_aux,
+               "n_active": jnp.sum(send_mask),
+               "n_arrived": jnp.sum(arrived),
+               "buffered": new.acc_n,
+               "flushed": flush.astype(jnp.float32),
+               "staleness_mean": applied_staleness(st.k, msg["t"], arrived),
+               "bits_per_node": new.bits_per_node}
+        return new, aux
+
+    return step
 
 
 # ---------------------------------------------------------------------------
@@ -738,40 +891,32 @@ def run_sharded_sweep(sweep_step: Callable, hparams, state, key, iters: int,
     return jax.jit(prog)(hparams, state, keys)
 
 
+def check_buffer_slots(ahp: AsyncHParams, state: AsyncState) -> None:
+    """Reject a tau grid whose largest delay does not fit the shared
+    buffer: slot indices wrap modulo its size, so an oversized tau would
+    silently run as a shorter delay."""
+    slots, tau_max = state.buf.occupied.shape[0], int(jnp.max(ahp.tau))
+    if tau_max + 1 > slots:
+        raise ValueError(
+            f"shared MessageBuffer has {slots} slot(s) but the grid "
+            f"reaches tau={tau_max}; raise the schedule's max delay "
+            f"(plan.staleness.tau, init_async_state's max_delay) to >= "
+            f"{tau_max}")
+
+
 def run_async_sweep(sweep_step: Callable, hparams, state, key, iters: int,
                     record: Optional[Callable] = None,
                     record_every: int = 1, trace_dtype=None):
-    """Vmapped async/buffered sweep: a (tau, buffer_k, …) grid as ONE
-    device program.
-
-    sweep_step: (hp, state, key) -> (state, aux), e.g. from
-                ``repro.core.flecs.make_flecs_async_sweep_step`` — the
-                delays and flush threshold are traced per grid point.
-    hparams:    pytree with a leading [G] grid axis carrying a ``tau``
-                leaf (int delays) — e.g. ``flecs.FlecsAsyncHParams`` from
-                ``flecs.async_hparam_grid``.
-    state:      ONE shared initial async state whose ``buf``
-                :class:`MessageBuffer` must have max(tau)+1 slots — every
-                grid point runs in the same buffer shape, with shorter
-                delays simply leaving the later slots unused.  (A per-point
-                buffer shape would make the grid unvmappable.)
-
-    Key streams, record_every and trace_dtype follow :func:`run_sweep`
-    exactly, so grid point g reproduces the standalone async run with key
-    ``split(key, G)[g]`` bit-for-bit — including the tau=0 point, which
-    collapses to the synchronous engine.
+    """Vmapped async/buffered sweep: a (tau, buffer_k, …) grid of
+    :class:`AsyncHParams` through a :func:`make_async_step` step as ONE
+    device program.  Every grid point runs in the shared ``state``'s
+    max-delay buffer shape (shorter delays leave the later slots unused;
+    a per-point shape would make the grid unvmappable), checked by
+    :func:`check_buffer_slots`.  Key streams, record_every and trace_dtype
+    follow :func:`run_sweep`, so point g reproduces the standalone async
+    run with key ``split(key, G)[g]`` bit-for-bit.
     """
-    taus = getattr(hparams, "tau", None)
-    if taus is not None:
-        buf = getattr(state, "buf", None)
-        if buf is not None:
-            slots = buf.occupied.shape[0]
-            tau_max = int(jnp.max(taus))
-            if tau_max + 1 > slots:
-                raise ValueError(
-                    f"shared MessageBuffer has {slots} slot(s) but the grid "
-                    f"reaches tau={tau_max}; init the async state with "
-                    f"max_delay >= {tau_max}")
+    check_buffer_slots(hparams, state)
     return run_sweep(sweep_step, hparams, state, key, iters, record=record,
                      record_every=record_every, trace_dtype=trace_dtype)
 
@@ -782,15 +927,12 @@ def run_async_sweep(sweep_step: Callable, hparams, state, key, iters: int,
 
 def hparams_bit_budget(hp):
     """The traced per-point bit budget carried by an hparam pytree, or
-    None.  Sync hparams carry it as a ``bit_budget`` field; async hparams
-    (``FlecsAsyncHParams`` and friends) carry it on their inner sync
-    ``hp`` — the budget gates *arrival-billed* bits the same way."""
-    budget = getattr(hp, "bit_budget", None)
-    if budget is None:
-        inner = getattr(hp, "hp", None)
-        if inner is not None:
-            budget = getattr(inner, "bit_budget", None)
-    return budget
+    None.  Sync hparams carry it as a ``bit_budget`` field;
+    :class:`AsyncHParams` carry it on their inner sync ``hp`` — the budget
+    gates *arrival-billed* bits the same way."""
+    if isinstance(hp, AsyncHParams):
+        hp = hp.hp
+    return getattr(hp, "bit_budget", None)
 
 
 # Aux trace keys zeroed on frozen rounds: once the budget is exhausted

@@ -22,18 +22,12 @@ direct-update beta, and full ``CompressorSpec``s for the gradient AND
 Hessian compressors — see ``repro.core.compressors``).  ``_flecs_round``
 consumes the hparams as traced values, so:
 
-  * ``make_flecs_step(cfg, …)`` is a *specialization* of
-    ``make_flecs_sweep_step`` at the concrete ``hparams_from_config(cfg)``
-    point — there is no parallel static round implementation to drift;
+  * a single run is ``make_flecs_sweep_step`` at one hparams point, e.g.
+    ``functools.partial(step, hparams_from_config(cfg))`` — there is no
+    parallel static round implementation to drift;
   * ``driver.run_sweep`` vmaps a whole (alpha × gamma × beta × grad_s ×
     hess_s) grid through one compiled program, with exact per-point bit
     ledgers (``compressors.spec_bits`` is traced too).
-
-The async engine gets the same treatment: :class:`FlecsAsyncHParams` adds
-traced ``tau`` (delay) and ``buffer_k`` (FedBuff flush threshold) axes, and
-``make_flecs_async_step`` specializes ``make_flecs_async_sweep_step`` so a
-(tau × buffer_k) staleness grid runs under ``driver.run_async_sweep`` as
-one program sharing a max-delay ``MessageBuffer`` shape.
 
 Partial participation (beyond-paper axis, FedNL/FedLab-style): set
 ``FlecsConfig.participation < 1`` and each round draws a client mask via
@@ -54,9 +48,11 @@ accumulated.  The worker's shift h^i and approximation B^i are updated —
 and its bits charged — at the *arrival* round; a worker with a message in
 flight is busy and is not sampled again, which keeps the shift algebra
 exact (every c^i is reconstructed against the same h^i it was compressed
-against).  With ``tau=0`` (and ``buffer_k=n`` at full participation, or
-``buffer_k=1`` under sampling) the async step reproduces the synchronous
-one trace-for-trace (tests/test_async_aggregation.py).
+against).  It runs on the driver's one FedBuff engine
+(``driver.make_async_step``) over :func:`make_flecs_async_hooks`, with a
+traced (tau × buffer_k) grid from :func:`async_hparam_grid`.  With
+``tau=0`` (and ``buffer_k=n`` at full participation, or ``buffer_k=1``
+under sampling) it reproduces the synchronous round trace-for-trace.
 
 Communication accounting (per *participating* worker per iteration, bits;
 ``FlecsState.bits_per_node`` is a per-worker [n] vector):
@@ -78,12 +74,9 @@ from repro.core.compressors import (CompressorSpec, compress, dither_spec,
 from repro.core.directions import (fedsonia_direction,
                                    truncated_inverse_direction,
                                    truncated_inverse_direction_floored)
-from repro.core.driver import (ASYNC_SALT, COHORT_SALT, MessageBuffer,
-                               StalenessSchedule, applied_staleness,
-                               bits_dtype, buffer_busy, buffer_receive,
-                               buffer_send, cohort_indices, damped_alpha,
-                               fedbuff_accumulate, init_buffer, masked_mean,
-                               resolve_participation, sample_delays,
+from repro.core.driver import (COHORT_SALT, AsyncHParams, AsyncHooks,
+                               bits_dtype, cohort_indices, damped_alpha,
+                               masked_mean, resolve_participation,
                                validate_ps, SCOPE_COMPRESS_GRAD,
                                SCOPE_COMPRESS_HESS, SCOPE_CURVATURE,
                                SCOPE_ORACLE, SCOPE_SERVER)
@@ -92,8 +85,6 @@ from repro.core.hierarchy import (EDGE_SALT, HierarchyConfig, charge_edges,
                                   edge_round_bits, init_edge_bits,
                                   validate_hierarchy)
 from repro.core.sketch import sketch
-from repro.core.traffic import (TrafficHParams, TrafficModel, TrafficState,
-                                admit_arrivals, traffic_send)
 from repro.core.updates import direct_update, truncated_lsr1_update
 from repro.numerics import matmul
 
@@ -179,8 +170,7 @@ class FlecsHParams(NamedTuple):
 
 
 def hparams_from_config(cfg: FlecsConfig) -> FlecsHParams:
-    """The concrete hparams point a static ``make_flecs_step(cfg)`` run
-    specializes the sweep step at."""
+    """The concrete hparams point of a single run of ``cfg``."""
     return FlecsHParams(jnp.float32(cfg.alpha), jnp.float32(cfg.gamma),
                         jnp.float32(cfg.beta),
                         make_spec(cfg.grad_compressor),
@@ -423,8 +413,8 @@ def _flecs_round(cfg: FlecsConfig, local_grad: Callable, local_hvp: Callable,
                  axis: Optional[str] = None, n_total: Optional[int] = None):
     """One round of Algorithm 1 with client sampling.
 
-    Every ``hp`` field may be traced (sweep path) or concrete (the static
-    ``make_flecs_step`` specialization); structural choices (m, Hessian
+    Every ``hp`` field may be traced (sweep path) or concrete (a single
+    run at one hparams point); structural choices (m, Hessian
     update rule, direction, sampling kind, hierarchy shape) stay static
     from cfg.
 
@@ -511,25 +501,10 @@ def make_flecs_sweep_step(cfg: FlecsConfig, local_grad: Callable,
                           local_hvp: Callable):
     """Build step(hp: FlecsHParams, state, key) -> (state, aux) whose step
     sizes, beta, and BOTH compressor specs are traced, for
-    ``driver.run_sweep`` — the single round implementation every other step
-    maker specializes."""
+    ``driver.run_sweep`` — the single round implementation; a single run
+    is this step at one hparams point."""
     def step(hp: FlecsHParams, state: FlecsState, key) -> tuple:
         return _flecs_round(cfg, local_grad, local_hvp, hp, state, key)
-
-    return step
-
-
-def make_flecs_step(cfg: FlecsConfig,
-                    local_grad: Callable,      # (w, worker_id, key) -> g
-                    local_hvp: Callable):      # (w, V[d,m], worker_id, key) -> HV
-    """Build a jit/scan-able step(state, key) -> (state, aux): the sweep
-    step specialized at ``hparams_from_config(cfg)`` — identical ops and
-    key stream, so a sweep grid point reproduces the static run exactly."""
-    hp = hparams_from_config(cfg)
-    sweep = make_flecs_sweep_step(cfg, local_grad, local_hvp)
-
-    def step(state: FlecsState, key) -> tuple:
-        return sweep(hp, state, key)
 
     return step
 
@@ -723,32 +698,9 @@ def make_flecs_cohort_sweep_step(cfg: FlecsConfig, local_grad: Callable,
 # Asynchronous buffered aggregation (FedBuff-style staleness)
 # ---------------------------------------------------------------------------
 
-class FlecsAsyncHParams(NamedTuple):
-    """Async sweep point: the synchronous hparams plus the staleness axes.
-
-      hp       — FlecsHParams (alpha possibly auto-damped; see
-                 ``driver.damped_alpha``)
-      tau      — int32 delay-model bound (fixed delay / uniform-geometric
-                 cap), traced per grid point
-      buffer_k — float32 FedBuff flush threshold, traced per grid point
-      traffic  — optional traced ``repro.core.traffic`` leaves (rate
-                 tables, availability transitions, admission caps)
-    """
-    hp: FlecsHParams
-    tau: jnp.ndarray
-    buffer_k: jnp.ndarray
-    traffic: Optional[TrafficHParams] = None
-
-
-def async_hparams_from_config(cfg: FlecsConfig, tau: int,
-                              buffer_k) -> FlecsAsyncHParams:
-    return FlecsAsyncHParams(hparams_from_config(cfg), jnp.int32(tau),
-                             jnp.float32(buffer_k))
-
-
 def async_hparam_grid(taus, buffer_ks, *, alpha=1.0, gamma=1.0, beta=1.0,
                       grad_s=64.0, hess_s=64.0, ps=None,
-                      auto_damp=None) -> FlecsAsyncHParams:
+                      auto_damp=None) -> AsyncHParams:
     """Cartesian (tau × buffer_k [× p]) staleness grid, [G] leaves.
 
     ps: optional traced Bernoulli participation axis (requires a config
@@ -787,120 +739,43 @@ def async_hparam_grid(taus, buffer_ks, *, alpha=1.0, gamma=1.0, beta=1.0,
     hp = FlecsHParams(alphas, full(gamma), full(beta),
                       dither_spec(full(grad_s)), dither_spec(full(hess_s)),
                       None if ps is None else p)
-    return FlecsAsyncHParams(hp, t, K)
+    return AsyncHParams(hp, t, K)
 
 
-class FlecsAsyncState(NamedTuple):
-    """Synchronous server state + the in-flight/aggregation buffers.
-
-    buf holds per-worker messages {c [n,d], Y [n,d,m], M [n,m,m], t [n]}
-    keyed by arrival round (t = compute round, for staleness accounting and
-    compute-time sketch regeneration).  acc_* are the FedBuff running sums
-    since the last flush; acc_n counts buffered updates.
-    """
-    w: jnp.ndarray
-    h: jnp.ndarray
-    B: jnp.ndarray
-    k: jnp.ndarray
-    bits_per_node: jnp.ndarray
-    buf: MessageBuffer
-    acc_g: jnp.ndarray    # [d]    sum of arrived g̃^i = c^i + h^i
-    acc_Y: jnp.ndarray    # [d,m]  sum of arrived Ỹ^i
-    acc_M: jnp.ndarray    # [m,m]  sum of arrived M^i
-    acc_B: jnp.ndarray    # [d,d]  sum of arrived workers' updated B^i
-    acc_n: jnp.ndarray    # scalar buffered-update count
-    traffic: Optional[TrafficState] = None   # availability chain state
-
-
-def init_async_state(w0: jnp.ndarray, n_workers: int, m: int,
-                     max_delay: int) -> FlecsAsyncState:
-    base = init_state(w0, n_workers)
-    d = w0.shape[0]
-    proto = {"c": jnp.zeros((n_workers, d), jnp.float32),
-             "Y": jnp.zeros((n_workers, d, m), jnp.float32),
-             "M": jnp.zeros((n_workers, m, m), jnp.float32),
-             "t": jnp.zeros((n_workers,), jnp.float32)}
-    return FlecsAsyncState(
-        base.w, base.h, base.B, base.k, base.bits_per_node,
-        init_buffer(proto, max_delay),
-        jnp.zeros((d,), jnp.float32), jnp.zeros((d, m), jnp.float32),
-        jnp.zeros((m, m), jnp.float32), jnp.zeros((d, d), jnp.float32),
-        jnp.zeros((), jnp.float32))
-
-
-def make_flecs_async_sweep_step(cfg: FlecsConfig, local_grad: Callable,
-                                local_hvp: Callable,
-                                delay_kind: str = "fixed", q: float = 0.5,
-                                traffic: Optional[TrafficModel] = None):
-    """Build step(ahp: FlecsAsyncHParams, state, key) -> (state, aux) whose
-    delay bound tau, flush threshold buffer_k, step sizes, beta, and
-    compressor specs are ALL traced — ``driver.run_async_sweep`` vmaps a
-    whole staleness grid through one compiled program.  Grid points share
-    the state's max-delay ``MessageBuffer`` shape; a point's own (smaller)
-    tau simply leaves the later slots unused.
-
-    Per round: (1) sample clients, excluding busy workers (message still in
-    flight); (2) sampled workers compute (c, Ỹ, M) at the *current* iterate
-    exactly as the synchronous round; (3) messages are filed under arrival
-    round ``k + delay`` (delays from ``driver.sample_delays`` at the traced
-    tau); (4) this round's arrivals update their shift h^i / approximation
-    B^i, are charged bits, and join the FedBuff buffer; (5) once
-    ``buffer_k`` updates have buffered, the server takes one aggregate step
-    from the buffered means and resets the buffer.
+def make_flecs_async_hooks(cfg: FlecsConfig, local_grad: Callable,
+                           local_hvp: Callable) -> AsyncHooks:
+    """FLECS-CGD's part of the buffered-async engine
+    (``driver.make_async_step``).  A sampled worker's (c, Ỹ, M) is
+    computed as in the synchronous round (``_worker_messages``); on
+    arrival its shift h^i and approximation B^i learn and its bits are
+    billed; a flush takes the direction from the buffered means.
 
     Stale-curvature note: FedSONIA consumes Ỹ/M̄ means over messages from
     *different* compute rounds (different sketches S_t) — exactly the
     staleness a real async federation sees.  The L-SR1 path regenerates
     each message's compute-time sketch from its buffered round stamp.
-
-    A ``traffic`` model (``repro.core.traffic``) layers arrival processes,
-    availability chains, and server admission on the same buffered path —
-    only admitted arrivals bill bits or touch h/B/the FedBuff buffer;
-    ``traffic=None`` is the plain async engine, op-for-op.
     """
-    def step(ahp: FlecsAsyncHParams, state: FlecsAsyncState, key):
-        hp = ahp.hp
-        n, d = state.h.shape
-        m = cfg.m
-        S = sketch(cfg.sketch_kind, d, m, state.k)
-        k_g, k_h, k_q, k_c, k_p = jax.random.split(key, 5)   # == sync split
-        k_tau = jax.random.fold_in(key, ASYNC_SALT)
+    m = cfg.m
 
-        mask = resolve_participation(k_p, n, cfg.participation,
-                                     cfg.sampling, hp.p)
-        base_delays = sample_delays(delay_kind, k_tau, n, ahp.tau, q)
-        if traffic is None:
-            send_mask = mask * (1.0 - buffer_busy(state.buf))
-            delays, tstate = base_delays, state.traffic
-        else:
-            send_mask, delays, tstate = traffic_send(
-                traffic, ahp.traffic, state.traffic, state.buf, mask, key,
-                state.k, ahp.tau, base_delays)
+    def compute(hp: FlecsHParams, state: FlecsState, keys):
+        k_g, k_h, k_q, k_c = keys
+        # the sketch is drawn outside the send gate, as the synchronous
+        # round draws it: inside the gated branch XLA fuses the m = 1
+        # products differently and the tau = 0 run leaves the sync one
+        S = sketch(cfg.sketch_kind, state.h.shape[1], m, state.k)
 
-        # cond-gate the worker compute: in a fixed-delay cycle most rounds
-        # send nothing (everyone is busy), so skip the n gradients/HVPs
-        # entirely on those rounds — the results would be all-masked anyway
-        def compute(_):
-            return _worker_messages(
+        def work():
+            c, M, C, BS = _worker_messages(
                 local_grad, local_hvp, hp.grad_spec, hp.hess_spec,
                 state.w, state.h, state.B, S, k_g, k_h, k_q, k_c,
                 cfg.use_kernel)
+            return {"c": c, "Y": C + BS, "M": M}
 
-        c_all, M_all, C_all, BS_all = jax.lax.cond(
-            jnp.any(send_mask > 0), compute,
-            lambda _: (jnp.zeros((n, d), jnp.float32),
-                       jnp.zeros((n, m, m), jnp.float32),
-                       jnp.zeros((n, d, m), jnp.float32),
-                       jnp.zeros((n, d, m), jnp.float32)), None)
-        msgs = {"c": c_all, "Y": C_all + BS_all, "M": M_all,
-                "t": jnp.full((n,), state.k, jnp.float32)}
+        return work
 
-        buf = buffer_send(state.buf, msgs, send_mask, delays, state.k)
-        buf, msg, arrived = buffer_receive(buf, state.k)
-        arrived = admit_arrivals(traffic, ahp.traffic, arrived, msg["t"],
-                                 state.k)
+    def arrive(hp: FlecsHParams, state: FlecsState, msg, arrived):
+        d = state.h.shape[1]
 
-        # --- arrivals: per-worker server state, bits at the arrival round
         def update_B(_):
             upd = _update_B(
                 cfg, hp.beta, state.B, msg["Y"], msg["M"],
@@ -911,60 +786,25 @@ def make_flecs_async_sweep_step(cfg: FlecsConfig, local_grad: Callable,
         B_new = jax.lax.cond(jnp.any(arrived > 0), update_B,
                              lambda _: state.B, None)
         h_new = state.h + hp.gamma * arrived[:, None] * msg["c"]
-
         round_bits = _round_bits(hp.grad_spec, hp.hess_spec, d, m,
                                  cfg.use_kernel)
         bits_new = (state.bits_per_node
                     + arrived.astype(state.bits_per_node.dtype) * round_bits)
+        return (state._replace(h=h_new, B=B_new, bits_per_node=bits_new),
+                {"g": msg["c"] + state.h, "Y": msg["Y"], "M": msg["M"],
+                 "B": B_new})
 
-        # --- FedBuff buffer + flush once buffer_k updates have accumulated
-        acc, acc_n, means, flush, reset = fedbuff_accumulate(
-            {"g": state.acc_g, "Y": state.acc_Y, "M": state.acc_M,
-             "B": state.acc_B}, state.acc_n,
-            {"g": msg["c"] + state.h, "Y": msg["Y"], "M": msg["M"],
-             "B": B_new}, arrived, ahp.buffer_k)
-
-        # lax.cond so the O(d^3) direction computation runs only on flush
-        # rounds (a tau-round buffered run flushes every ~tau+1 rounds)
+    def flush(hp: FlecsHParams, state: FlecsState, means, flush):
+        # lax.cond so the direction runs only on flush rounds (a
+        # tau-round buffered run flushes every ~tau+1 rounds)
         def flush_step(_):
             p = _direction(cfg, means["g"], means["Y"], means["M"],
                            means["B"])
             return state.w + hp.alpha * p, jnp.linalg.norm(p)
 
-        w_new, dir_norm = jax.lax.cond(
+        w, dir_norm = jax.lax.cond(
             flush, flush_step,
             lambda _: (state.w, jnp.zeros((), state.w.dtype)), None)
+        return w, {"dir_norm": dir_norm}
 
-        new_state = FlecsAsyncState(
-            w_new, h_new, B_new, state.k + 1, bits_new, buf,
-            reset(acc["g"]), reset(acc["Y"]), reset(acc["M"]),
-            reset(acc["B"]), reset(acc_n), tstate)
-        aux = {"g_tilde_norm": jnp.linalg.norm(means["g"]),
-               "dir_norm": dir_norm,
-               "n_active": jnp.sum(send_mask),
-               "n_arrived": jnp.sum(arrived),
-               "buffered": new_state.acc_n,
-               "flushed": flush.astype(jnp.float32),
-               "staleness_mean": applied_staleness(state.k, msg["t"],
-                                                   arrived),
-               "bits_per_node": new_state.bits_per_node}
-        return new_state, aux
-
-    return step
-
-
-def make_flecs_async_step(cfg: FlecsConfig, local_grad: Callable,
-                          local_hvp: Callable,
-                          schedule: StalenessSchedule, buffer_k: int):
-    """Build a scan-able async step(state, key) -> (state, aux): the async
-    sweep step specialized at the concrete (cfg, schedule.tau, buffer_k)
-    point — one implementation for static runs and staleness grids."""
-    ahp = async_hparams_from_config(cfg, schedule.tau, buffer_k)
-    sweep = make_flecs_async_sweep_step(cfg, local_grad, local_hvp,
-                                        delay_kind=schedule.kind,
-                                        q=schedule.q)
-
-    def step(state: FlecsAsyncState, key):
-        return sweep(ahp, state, key)
-
-    return step
+    return AsyncHooks(cfg, 5, compute, arrive, flush)

@@ -1,8 +1,8 @@
 """Production traffic simulation: arrival processes, client availability
-states, and server-side cohort admission for the async engines.
+states, and server-side cohort admission for the async engine.
 
-The async engine (``driver.MessageBuffer`` + the ``make_*_async_sweep_step``
-factories) models staleness with fixed/uniform/geometric per-worker delays.
+The async engine (``driver.make_async_step`` over a ``MessageBuffer``)
+models staleness with fixed/uniform/geometric per-worker delays.
 Real federations see *structured* traffic: bursty arrivals, diurnal load
 cycles, clients that flip between available/busy/dropped, and servers that
 bound their in-flight work and refuse hopelessly stale updates.  This
@@ -221,8 +221,8 @@ class TrafficModel:
 
 class TrafficHParams(NamedTuple):
     """The traced point of a :class:`TrafficModel` — scalars/tables or
-    [G, ...] sweep-axis arrays riding the async hparam pytrees
-    (``FlecsAsyncHParams.traffic`` and friends).
+    [G, ...] sweep-axis arrays riding the async hparam pytree
+    (``driver.AsyncHParams.traffic``).
 
     rate_table:       [P] per-phase completion probabilities (poisson:
                       P = 1; unused by "schedule"/"trace" arrivals).
@@ -334,7 +334,7 @@ def stationary_distribution(transition) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# The async-step plumbing (what the make_*_async_sweep_step factories call)
+# The async-step plumbing (what ``driver.make_async_step`` calls)
 # ---------------------------------------------------------------------------
 
 def traffic_send(model: TrafficModel, thp: Optional[TrafficHParams],

@@ -25,10 +25,8 @@ Each method is a (config, hparams, sweep step) triple exactly like
   probability ``p`` — with ``*_hparam_grid`` / ``*_hparams_from_config``
   constructors;
 * ``make_*_sweep_step(cfg, oracles…)`` builds the single
-  ``step(hp, state, key)`` implementation, and the legacy
-  ``make_*_step(alpha, …)`` entry points are *specializations* of it at a
-  concrete hparams point — same ops, same key stream, so the redesign is
-  pinned bit-for-bit by the pre-existing tests.
+  ``step(hp, state, key)`` implementation; a single run is that step at
+  one hparams point (``*_hparams_from_config``).
 
 This is what lets ``repro.core.api``'s method registry put DIANA / FedNL /
 GD on the same sweep-native footing as FLECS: a (p × level × alpha) grid
@@ -40,24 +38,15 @@ hparams' traced ``p`` (bernoulli) when present, else the static config
 sampled workers enter the server aggregate, update their local server-side
 state (DIANA shift h^i, FedNL Hessian H^i), and pay bits.
 
-Asynchronous buffered aggregation: ``make_diana_async_sweep_step`` /
-``make_gd_async_sweep_step`` / ``make_fednl_async_sweep_step`` give every
-baseline the same FedBuff-style traced staleness axes as FLECS
-(:class:`DianaAsyncHParams` / :class:`GDAsyncHParams` /
-:class:`FedNLAsyncHParams` wrap the sync hparams with traced tau and
-buffer_k); ``make_diana_async_step`` / ``make_gd_async_step`` /
-``make_fednl_async_step`` are their concrete specializations.  Per-round
-delays come from ``driver.sample_delays``, messages buffer in a bounded
-in-flight ``MessageBuffer``, busy workers are excluded from sampling, bits
-are charged at the *arrival* round, and an aggregate step is applied once
-``buffer_k`` updates have buffered.  At ``tau=0`` (with ``buffer_k=1``, or
-``buffer_k=n`` under full participation) they collapse to the synchronous
-steps trace-for-trace, so delay ablations compare methods on one engine —
-with async FedNL the whole registry joins the staleness figures.  Every
-async maker also takes an optional ``repro.core.traffic.TrafficModel``
-threading arrival processes, per-client availability chains, and
-server-side admission through the same buffered path (``traffic=None``
-keeps the plain async engine bit-for-bit).
+Asynchronous buffered aggregation: ``make_diana_async_hooks`` /
+``make_fednl_async_hooks`` / ``make_gd_async_hooks`` give each baseline
+its part of the driver's one FedBuff engine (``driver.make_async_step``):
+the same traced staleness axes as FLECS (``driver.AsyncHParams``), busy
+workers excluded from sampling, bits charged at the *arrival* round, an
+aggregate step once ``buffer_k`` updates have buffered, and an optional
+``repro.core.traffic.TrafficModel``.  At ``tau=0`` (with ``buffer_k=1``,
+or ``buffer_k=n`` under full participation) they collapse to the
+synchronous steps trace-for-trace.
 
 Population scale: DIANA and GD additionally ship sharded
 (``make_*_sharded_sweep_step`` + ``*_sharded_state_specs`` for
@@ -86,16 +75,11 @@ import jax.numpy as jnp
 
 from repro.core.compressors import (CompressorSpec, make_spec, compress,
                                     spec_bits, spec_bits_many)
-from repro.core.driver import (ASYNC_SALT, COHORT_SALT, MessageBuffer,
-                               StalenessSchedule, applied_staleness,
-                               bits_dtype, buffer_busy, buffer_receive,
-                               buffer_send, cohort_indices,
-                               fedbuff_accumulate, init_buffer, masked_mean,
-                               resolve_participation, sample_delays,
-                               validate_ps, SCOPE_COMPRESS_GRAD,
-                               SCOPE_ORACLE, SCOPE_SERVER)
-from repro.core.traffic import (TrafficHParams, TrafficModel, TrafficState,
-                                admit_arrivals, traffic_send)
+from repro.core.driver import (COHORT_SALT, AsyncHooks, bits_dtype,
+                               cohort_indices, masked_mean,
+                               resolve_participation, validate_ps,
+                               SCOPE_COMPRESS_GRAD, SCOPE_ORACLE,
+                               SCOPE_SERVER)
 from repro.numerics import matmul
 
 
@@ -219,7 +203,7 @@ def _diana_round(cfg: DianaConfig, local_grad: Callable, hp: DianaHParams,
 def make_diana_sweep_step(cfg: DianaConfig, local_grad: Callable):
     """Build step(hp: DianaHParams, state, key) -> (state, aux) whose step
     sizes, compressor spec, and participation p are traced — the single
-    round implementation ``make_diana_step`` specializes."""
+    DIANA round implementation."""
 
     def step(hp: DianaHParams, state: DianaState, key):
         return _diana_round(cfg, local_grad, hp, state, key)
@@ -295,21 +279,6 @@ def make_diana_cohort_sweep_step(cfg: DianaConfig, local_grad: Callable,
     return step
 
 
-def make_diana_step(alpha: float, gamma: float, compressor,
-                    local_grad: Callable, participation: float = 1.0,
-                    sampling: str = "bernoulli"):
-    """Legacy entry point: the sweep step specialized at a concrete
-    hparams point — identical ops and key stream."""
-    cfg = DianaConfig(alpha, gamma, compressor, participation, sampling)
-    hp = diana_hparams_from_config(cfg)
-    sweep = make_diana_sweep_step(cfg, local_grad)
-
-    def step(state: DianaState, key):
-        return sweep(hp, state, key)
-
-    return step
-
-
 def init_diana(w0, n_workers):
     return DianaState(w0.astype(jnp.float32),
                       jnp.zeros((n_workers, w0.shape[0]), jnp.float32),
@@ -317,127 +286,38 @@ def init_diana(w0, n_workers):
                       jnp.zeros((n_workers,), bits_dtype()))
 
 
-class DianaAsyncHParams(NamedTuple):
-    """Async sweep point: sync hparams + traced staleness axes (the same
-    shape as ``flecs.FlecsAsyncHParams``).  ``traffic`` carries the traced
-    leaves of a ``repro.core.traffic`` model (rate tables, availability
-    transitions, admission caps) when one is threaded through the step."""
-    hp: DianaHParams
-    tau: jnp.ndarray
-    buffer_k: jnp.ndarray
-    traffic: Optional[TrafficHParams] = None
+def make_diana_async_hooks(cfg: DianaConfig,
+                           local_grad: Callable) -> AsyncHooks:
+    """DIANA's part of the buffered-async engine: compressed gradient
+    differences arrive late, shifts h^i learn and bits are billed at the
+    arrival round (busy workers are not re-sampled, so each c^i
+    reconstructs against its compute-time shift), and a flush steps
+    along the buffered mean."""
 
-
-class DianaAsyncState(NamedTuple):
-    w: jnp.ndarray
-    h: jnp.ndarray               # [n, d]
-    k: jnp.ndarray
-    bits_per_node: jnp.ndarray   # [n]
-    buf: MessageBuffer           # in-flight {c [n,d], t [n]}
-    acc_g: jnp.ndarray           # [d] FedBuff sum of arrived c^i + h^i
-    acc_n: jnp.ndarray           # buffered-update count
-    traffic: Optional[TrafficState] = None   # availability chain state
-
-
-def init_diana_async(w0, n_workers, max_delay: int) -> DianaAsyncState:
-    base = init_diana(w0, n_workers)
-    d = w0.shape[0]
-    proto = {"c": jnp.zeros((n_workers, d), jnp.float32),
-             "t": jnp.zeros((n_workers,), jnp.float32)}
-    return DianaAsyncState(base.w, base.h, base.k, base.bits_per_node,
-                           init_buffer(proto, max_delay),
-                           jnp.zeros((d,), jnp.float32),
-                           jnp.zeros((), jnp.float32))
-
-
-def make_diana_async_sweep_step(cfg: DianaConfig, local_grad: Callable,
-                                delay_kind: str = "fixed", q: float = 0.5,
-                                traffic: Optional[TrafficModel] = None):
-    """DIANA with FedBuff-style buffered aggregation, sweep-native: the
-    delay bound tau, flush threshold buffer_k, step sizes, spec, and
-    participation p are ALL traced — ``driver.run_async_sweep`` vmaps a
-    staleness grid through one compiled program.  Compressed gradient
-    differences arrive late, bits are charged at the arrival round, shifts
-    h^i update on arrival (busy workers are not re-sampled, so each c^i
-    reconstructs against its compute-time shift), and the server steps once
-    ``buffer_k`` updates have buffered.  A ``traffic`` model layers arrival
-    processes, availability chains, and admission on the same path (only
-    admitted arrivals bill, update shifts, or enter the buffer);
-    ``traffic=None`` is the plain async engine, op-for-op."""
-
-    def step(ahp: DianaAsyncHParams, state: DianaAsyncState, key):
-        hp = ahp.hp
-        n, d = state.h.shape
-        k_g, k_q, k_p = jax.random.split(key, 3)            # == sync split
-        k_tau = jax.random.fold_in(key, ASYNC_SALT)
-        mask = resolve_participation(k_p, n, cfg.participation,
-                                     cfg.sampling, hp.p)
-        base_delays = sample_delays(delay_kind, k_tau, n, ahp.tau, q)
-        if traffic is None:
-            send_mask = mask * (1.0 - buffer_busy(state.buf))
-            delays, tstate = base_delays, state.traffic
-        else:
-            send_mask, delays, tstate = traffic_send(
-                traffic, ahp.traffic, state.traffic, state.buf, mask, key,
-                state.k, ahp.tau, base_delays)
+    def compute(hp: DianaHParams, state: DianaState, keys):
+        k_g, k_q = keys
+        n = state.h.shape[0]
 
         def worker(i, hk, kq):
             g = local_grad(state.w, i, jax.random.fold_in(k_g, i))
             return compress(hp.spec, kq, g - hk, cfg.use_kernel)
 
-        # skip the n gradient evaluations on rounds where everyone is busy
-        c = jax.lax.cond(
-            jnp.any(send_mask > 0),
-            lambda _: jax.vmap(worker)(jnp.arange(n), state.h,
-                                       jax.random.split(k_q, n)),
-            lambda _: jnp.zeros((n, d), jnp.float32), None)
-        msgs = {"c": c, "t": jnp.full((n,), state.k, jnp.float32)}
-        buf = buffer_send(state.buf, msgs, send_mask, delays, state.k)
-        buf, msg, arrived = buffer_receive(buf, state.k)
-        arrived = admit_arrivals(traffic, ahp.traffic, arrived, msg["t"],
-                                 state.k)
+        return lambda: {"c": jax.vmap(worker)(jnp.arange(n), state.h,
+                                              jax.random.split(k_q, n))}
 
+    def arrive(hp: DianaHParams, state: DianaState, msg, arrived):
+        d = state.h.shape[1]
         h = state.h + hp.gamma * arrived[:, None] * msg["c"]
         bits = state.bits_per_node + arrived.astype(
             state.bits_per_node.dtype) * spec_bits(hp.spec, d,
                                                    cfg.use_kernel)
-        acc_g, acc_n, g_tilde, flush, reset = fedbuff_accumulate(
-            state.acc_g, state.acc_n, msg["c"] + state.h, arrived,
-            ahp.buffer_k)
+        return (state._replace(h=h, bits_per_node=bits),
+                {"g": msg["c"] + state.h})
 
-        w = jnp.where(flush, state.w - hp.alpha * g_tilde, state.w)
-        new = DianaAsyncState(w, h, state.k + 1, bits, buf,
-                              reset(acc_g), reset(acc_n), tstate)
-        return new, {"g_tilde_norm": jnp.linalg.norm(g_tilde),
-                     "n_active": jnp.sum(send_mask),
-                     "n_arrived": jnp.sum(arrived),
-                     "buffered": new.acc_n,
-                     "flushed": flush.astype(jnp.float32),
-                     "staleness_mean": applied_staleness(state.k, msg["t"],
-                                                         arrived),
-                     "bits_per_node": new.bits_per_node}
+    def flush(hp: DianaHParams, state: DianaState, means, flush):
+        return jnp.where(flush, state.w - hp.alpha * means["g"], state.w), {}
 
-    return step
-
-
-def make_diana_async_step(alpha: float, gamma: float, compressor,
-                          local_grad: Callable,
-                          schedule: StalenessSchedule, buffer_k: int,
-                          participation: float = 1.0,
-                          sampling: str = "bernoulli"):
-    """Legacy async entry point: the async sweep step specialized at the
-    concrete (cfg, schedule.tau, buffer_k) point."""
-    cfg = DianaConfig(alpha, gamma, compressor, participation, sampling)
-    ahp = DianaAsyncHParams(diana_hparams_from_config(cfg),
-                            jnp.int32(schedule.tau), jnp.float32(buffer_k))
-    sweep = make_diana_async_sweep_step(cfg, local_grad,
-                                        delay_kind=schedule.kind,
-                                        q=schedule.q)
-
-    def step(state: DianaAsyncState, key):
-        return sweep(ahp, state, key)
-
-    return step
+    return AsyncHooks(cfg, 3, compute, arrive, flush)
 
 
 # ---------------------------------------------------------------------------
@@ -530,21 +410,6 @@ def make_fednl_sweep_step(cfg: FedNLConfig, local_grad: Callable,
     return step
 
 
-def make_fednl_step(alpha: float, compressor, local_grad: Callable,
-                    local_hessian: Callable, mu: float,
-                    participation: float = 1.0, sampling: str = "bernoulli"):
-    """Legacy entry point: the sweep step specialized at a concrete
-    hparams point — identical ops and key stream."""
-    cfg = FedNLConfig(alpha, compressor, mu, participation, sampling)
-    hp = fednl_hparams_from_config(cfg)
-    sweep = make_fednl_sweep_step(cfg, local_grad, local_hessian)
-
-    def step(state: FedNLState, key):
-        return sweep(hp, state, key)
-
-    return step
-
-
 def init_fednl(w0, n_workers):
     d = w0.shape[0]
     return FedNLState(w0.astype(jnp.float32),
@@ -553,45 +418,9 @@ def init_fednl(w0, n_workers):
                       jnp.zeros((n_workers,), bits_dtype()))
 
 
-class FedNLAsyncHParams(NamedTuple):
-    """Async sweep point: sync hparams + traced staleness axes
-    (``traffic``: optional traced ``repro.core.traffic`` leaves)."""
-    hp: FedNLHParams
-    tau: jnp.ndarray
-    buffer_k: jnp.ndarray
-    traffic: Optional[TrafficHParams] = None
-
-
-class FedNLAsyncState(NamedTuple):
-    w: jnp.ndarray
-    H: jnp.ndarray               # [n, d, d] per-worker Hessian estimates
-    k: jnp.ndarray
-    bits_per_node: jnp.ndarray   # [n]
-    buf: MessageBuffer           # in-flight {g [n,d], D [n,d,d], t [n]}
-    acc_g: jnp.ndarray           # [d] FedBuff sum of arrived gradients
-    acc_H: jnp.ndarray           # [d, d] FedBuff sum of arrived H^i_{k+1}
-    acc_n: jnp.ndarray           # buffered-update count
-    traffic: Optional[TrafficState] = None   # availability chain state
-
-
-def init_fednl_async(w0, n_workers, max_delay: int) -> FedNLAsyncState:
-    base = init_fednl(w0, n_workers)
-    d = w0.shape[0]
-    proto = {"g": jnp.zeros((n_workers, d), jnp.float32),
-             "D": jnp.zeros((n_workers, d, d), jnp.float32),
-             "t": jnp.zeros((n_workers,), jnp.float32)}
-    return FedNLAsyncState(base.w, base.H, base.k, base.bits_per_node,
-                           init_buffer(proto, max_delay),
-                           jnp.zeros((d,), jnp.float32),
-                           jnp.zeros((d, d), jnp.float32),
-                           jnp.zeros((), jnp.float32))
-
-
-def make_fednl_async_sweep_step(cfg: FedNLConfig, local_grad: Callable,
-                                local_hessian: Callable,
-                                delay_kind: str = "fixed", q: float = 0.5,
-                                traffic: Optional[TrafficModel] = None):
-    """FedNL with FedBuff-style buffered aggregation — the compressed d×d
+def make_fednl_async_hooks(cfg: FedNLConfig, local_grad: Callable,
+                           local_hessian: Callable) -> AsyncHooks:
+    """FedNL's part of the buffered-async engine — the compressed d×d
     Hessian DIFFERENCES arrive late, which is what makes second-order
     staleness interesting: a stale difference was compressed against the
     sender's compute-time estimate H^i, so (exactly like the DIANA shift
@@ -601,29 +430,11 @@ def make_fednl_async_sweep_step(cfg: FedNLConfig, local_grad: Callable,
     Hessian diff, FedNL's full wire price — are charged at *arrival*.
     Arrived (gradient, updated-H) pairs accumulate in the FedBuff buffer;
     on flush the server takes one regularized-Newton step from the
-    buffered means.  tau, buffer_k, alpha, spec, and p are all traced, so
-    a staleness grid is one ``driver.run_async_sweep`` program; at tau=0
-    (with buffer_k=n under full participation, or buffer_k=1 under
-    sampling) the step collapses to ``make_fednl_sweep_step`` bit-for-bit
-    — exact bit ledgers included (tests/test_async_aggregation.py).  A
-    ``traffic`` model layers arrivals/availability/admission on the same
-    path; ``traffic=None`` is the plain async engine, op-for-op."""
+    buffered means."""
 
-    def step(ahp: FedNLAsyncHParams, state: FedNLAsyncState, key):
-        hp = ahp.hp
-        n, d = state.H.shape[:2]
-        k_g, k_c, k_p = jax.random.split(key, 3)            # == sync split
-        k_tau = jax.random.fold_in(key, ASYNC_SALT)
-        mask = resolve_participation(k_p, n, cfg.participation,
-                                     cfg.sampling, hp.p)
-        base_delays = sample_delays(delay_kind, k_tau, n, ahp.tau, q)
-        if traffic is None:
-            send_mask = mask * (1.0 - buffer_busy(state.buf))
-            delays, tstate = base_delays, state.traffic
-        else:
-            send_mask, delays, tstate = traffic_send(
-                traffic, ahp.traffic, state.traffic, state.buf, mask, key,
-                state.k, ahp.tau, base_delays)
+    def compute(hp: FedNLHParams, state: FedNLState, keys):
+        k_g, k_c = keys
+        n = state.H.shape[0]
 
         def worker(i, Hk, kc):
             g = local_grad(state.w, i, jax.random.fold_in(k_g, i))
@@ -631,28 +442,24 @@ def make_fednl_async_sweep_step(cfg: FedNLConfig, local_grad: Callable,
             D = compress(hp.spec, kc, Hi - Hk, cfg.use_kernel)
             return g, D
 
-        # skip the n oracle evaluations on rounds where everyone is busy
-        g_all, D_all = jax.lax.cond(
-            jnp.any(send_mask > 0),
-            lambda _: jax.vmap(worker)(jnp.arange(n), state.H,
-                                       jax.random.split(k_c, n)),
-            lambda _: (jnp.zeros((n, d), jnp.float32),
-                       jnp.zeros((n, d, d), jnp.float32)), None)
-        msgs = {"g": g_all, "D": D_all,
-                "t": jnp.full((n,), state.k, jnp.float32)}
-        buf = buffer_send(state.buf, msgs, send_mask, delays, state.k)
-        buf, msg, arrived = buffer_receive(buf, state.k)
-        arrived = admit_arrivals(traffic, ahp.traffic, arrived, msg["t"],
-                                 state.k)
+        def work():
+            g_all, D_all = jax.vmap(worker)(jnp.arange(n), state.H,
+                                            jax.random.split(k_c, n))
+            return {"g": g_all, "D": D_all}
 
-        # Hessian learning + billing strictly at the arrival round
+        return work
+
+    def arrive(hp: FedNLHParams, state: FedNLState, msg, arrived):
+        d = state.H.shape[1]
         H_new = state.H + arrived[:, None, None] * msg["D"]
         bits = state.bits_per_node + arrived.astype(
             state.bits_per_node.dtype) * (
                 d * 32.0 + spec_bits(hp.spec, d * d, cfg.use_kernel))
-        acc, acc_n, means, flush, reset = fedbuff_accumulate(
-            {"g": state.acc_g, "H": state.acc_H}, state.acc_n,
-            {"g": msg["g"], "H": H_new}, arrived, ahp.buffer_k)
+        return (state._replace(H=H_new, bits_per_node=bits),
+                {"g": msg["g"], "H": H_new})
+
+    def flush(hp: FedNLHParams, state: FedNLState, means, flush):
+        d = state.w.shape[0]
 
         def newton(_):
             # positive-definite safeguard: H̄ + μI on the symmetric part —
@@ -667,40 +474,9 @@ def make_fednl_async_sweep_step(cfg: FedNLConfig, local_grad: Callable,
         w, dir_norm = jax.lax.cond(
             flush, newton,
             lambda _: (state.w, jnp.zeros((), state.w.dtype)), None)
-        new = FedNLAsyncState(w, H_new, state.k + 1, bits, buf,
-                              reset(acc["g"]), reset(acc["H"]),
-                              reset(acc_n), tstate)
-        return new, {"g_tilde_norm": jnp.linalg.norm(means["g"]),
-                     "dir_norm": dir_norm,
-                     "n_active": jnp.sum(send_mask),
-                     "n_arrived": jnp.sum(arrived),
-                     "buffered": new.acc_n,
-                     "flushed": flush.astype(jnp.float32),
-                     "staleness_mean": applied_staleness(state.k, msg["t"],
-                                                         arrived),
-                     "bits_per_node": new.bits_per_node}
+        return w, {"dir_norm": dir_norm}
 
-    return step
-
-
-def make_fednl_async_step(alpha: float, compressor, local_grad: Callable,
-                          local_hessian: Callable, mu: float,
-                          schedule: StalenessSchedule, buffer_k: int,
-                          participation: float = 1.0,
-                          sampling: str = "bernoulli"):
-    """Legacy async entry point: the async sweep step specialized at the
-    concrete (cfg, schedule.tau, buffer_k) point."""
-    cfg = FedNLConfig(alpha, compressor, mu, participation, sampling)
-    ahp = FedNLAsyncHParams(fednl_hparams_from_config(cfg),
-                            jnp.int32(schedule.tau), jnp.float32(buffer_k))
-    sweep = make_fednl_async_sweep_step(cfg, local_grad, local_hessian,
-                                        delay_kind=schedule.kind,
-                                        q=schedule.q)
-
-    def step(state: FedNLAsyncState, key):
-        return sweep(ahp, state, key)
-
-    return step
+    return AsyncHooks(cfg, 3, compute, arrive, flush)
 
 
 # ---------------------------------------------------------------------------
@@ -800,125 +576,30 @@ def make_gd_cohort_sweep_step(cfg: GDConfig, local_grad: Callable,
     return step
 
 
-def make_gd_step(alpha: float, local_grad: Callable, n_workers: int,
-                 participation: float = 1.0, sampling: str = "bernoulli"):
-    """Legacy entry point: the sweep step specialized at a concrete
-    hparams point — identical ops and key stream."""
-    cfg = GDConfig(alpha, participation, sampling)
-    hp = gd_hparams_from_config(cfg)
-    sweep = make_gd_sweep_step(cfg, local_grad, n_workers)
-
-    def step(state: GDState, key):
-        return sweep(hp, state, key)
-
-    return step
-
-
 def init_gd(w0, n_workers):
     return GDState(w0.astype(jnp.float32), jnp.zeros((), jnp.int32),
                    jnp.zeros((n_workers,), bits_dtype()))
 
 
-class GDAsyncHParams(NamedTuple):
-    """Async sweep point: sync hparams + traced staleness axes
-    (``traffic``: optional traced ``repro.core.traffic`` leaves)."""
-    hp: GDHParams
-    tau: jnp.ndarray
-    buffer_k: jnp.ndarray
-    traffic: Optional[TrafficHParams] = None
+def make_gd_async_hooks(cfg: GDConfig, local_grad: Callable,
+                        n_workers: int) -> AsyncHooks:
+    """Uncompressed GD's part of the buffered-async engine — the classic
+    stale-gradient baseline: delayed gradients are billed on arrival and a
+    flush steps along their buffered mean."""
 
+    def compute(hp: GDHParams, state: GDState, keys):
+        (k_g,) = keys
+        return lambda: {"g": jax.vmap(
+            lambda i: local_grad(state.w, i, jax.random.fold_in(k_g, i)))(
+                jnp.arange(n_workers))}
 
-class GDAsyncState(NamedTuple):
-    w: jnp.ndarray
-    k: jnp.ndarray
-    bits_per_node: jnp.ndarray   # [n]
-    buf: MessageBuffer           # in-flight {g [n,d], t [n]}
-    acc_g: jnp.ndarray           # [d]
-    acc_n: jnp.ndarray
-    traffic: Optional[TrafficState] = None   # availability chain state
-
-
-def init_gd_async(w0, n_workers, max_delay: int) -> GDAsyncState:
-    base = init_gd(w0, n_workers)
-    proto = {"g": jnp.zeros((n_workers, w0.shape[0]), jnp.float32),
-             "t": jnp.zeros((n_workers,), jnp.float32)}
-    return GDAsyncState(base.w, base.k, base.bits_per_node,
-                        init_buffer(proto, max_delay),
-                        jnp.zeros((w0.shape[0],), jnp.float32),
-                        jnp.zeros((), jnp.float32))
-
-
-def make_gd_async_sweep_step(cfg: GDConfig, local_grad: Callable,
-                             n_workers: int, delay_kind: str = "fixed",
-                             q: float = 0.5,
-                             traffic: Optional[TrafficModel] = None):
-    """Uncompressed GD with buffered delayed gradients, sweep-native — the
-    classic stale-gradient baseline with (tau, buffer_k, alpha, p) all
-    traced grid axes (and, optionally, a ``repro.core.traffic`` model on
-    the buffered path; ``traffic=None`` is op-for-op the plain engine)."""
-
-    def step(ahp: GDAsyncHParams, state: GDAsyncState, key):
-        hp = ahp.hp
+    def arrive(hp: GDHParams, state: GDState, msg, arrived):
         d = state.w.shape[0]
-        k_g, k_p = jax.random.split(key)                    # == sync split
-        k_tau = jax.random.fold_in(key, ASYNC_SALT)
-        mask = resolve_participation(k_p, n_workers, cfg.participation,
-                                     cfg.sampling, hp.p)
-        base_delays = sample_delays(delay_kind, k_tau, n_workers, ahp.tau, q)
-        if traffic is None:
-            send_mask = mask * (1.0 - buffer_busy(state.buf))
-            delays, tstate = base_delays, state.traffic
-        else:
-            send_mask, delays, tstate = traffic_send(
-                traffic, ahp.traffic, state.traffic, state.buf, mask, key,
-                state.k, ahp.tau, base_delays)
-        # skip the n gradient evaluations on rounds where everyone is busy
-        g_all = jax.lax.cond(
-            jnp.any(send_mask > 0),
-            lambda _: jax.vmap(
-                lambda i: local_grad(state.w, i,
-                                     jax.random.fold_in(k_g, i)))(
-                    jnp.arange(n_workers)),
-            lambda _: jnp.zeros((n_workers, d), jnp.float32), None)
-        msgs = {"g": g_all, "t": jnp.full((n_workers,), state.k, jnp.float32)}
-        buf = buffer_send(state.buf, msgs, send_mask, delays, state.k)
-        buf, msg, arrived = buffer_receive(buf, state.k)
-        arrived = admit_arrivals(traffic, ahp.traffic, arrived, msg["t"],
-                                 state.k)
-
         bits = state.bits_per_node + arrived.astype(
             state.bits_per_node.dtype) * (d * 32.0)
-        acc_g, acc_n, g, flush, reset = fedbuff_accumulate(
-            state.acc_g, state.acc_n, msg["g"], arrived, ahp.buffer_k)
+        return state._replace(bits_per_node=bits), {"g": msg["g"]}
 
-        w = jnp.where(flush, state.w - hp.alpha * g, state.w)
-        new = GDAsyncState(w, state.k + 1, bits, buf,
-                           reset(acc_g), reset(acc_n), tstate)
-        return new, {"g_tilde_norm": jnp.linalg.norm(g),
-                     "n_active": jnp.sum(send_mask),
-                     "n_arrived": jnp.sum(arrived),
-                     "buffered": new.acc_n,
-                     "flushed": flush.astype(jnp.float32),
-                     "staleness_mean": applied_staleness(state.k, msg["t"],
-                                                         arrived),
-                     "bits_per_node": new.bits_per_node}
+    def flush(hp: GDHParams, state: GDState, means, flush):
+        return jnp.where(flush, state.w - hp.alpha * means["g"], state.w), {}
 
-    return step
-
-
-def make_gd_async_step(alpha: float, local_grad: Callable, n_workers: int,
-                       schedule: StalenessSchedule, buffer_k: int,
-                       participation: float = 1.0,
-                       sampling: str = "bernoulli"):
-    """Legacy async entry point: the async sweep step specialized at the
-    concrete (cfg, schedule.tau, buffer_k) point."""
-    cfg = GDConfig(alpha, participation, sampling)
-    ahp = GDAsyncHParams(gd_hparams_from_config(cfg),
-                         jnp.int32(schedule.tau), jnp.float32(buffer_k))
-    sweep = make_gd_async_sweep_step(cfg, local_grad, n_workers,
-                                     delay_kind=schedule.kind, q=schedule.q)
-
-    def step(state: GDAsyncState, key):
-        return sweep(ahp, state, key)
-
-    return step
+    return AsyncHooks(cfg, 2, compute, arrive, flush)

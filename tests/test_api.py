@@ -1,8 +1,9 @@
 """Declarative method registry + ExperimentPlan (the api redesign).
 
 Pins the redesign's hard contracts:
-  * ``run_plan`` reproduces the legacy per-method ``run_experiment`` paths
-    with EXACT bit ledgers for all five methods (key rule: run j, point g
+  * ``run_plan`` reproduces single-point ``run_experiment`` runs of each
+    method (its sweep step at one hparams point, the "legacy" path) with
+    EXACT bit ledgers for all five methods (key rule: run j, point g
     steps with ``split(split(fold_in(key(seed), j), G)[g], iters)``);
   * the traced-p participation mask equals the static
     ``participation_mask`` draw-for-draw, matches Bernoulli statistics,
@@ -12,11 +13,14 @@ Pins the redesign's hard contracts:
   * the benchmark figures ``fig1_flecs_vs_cgd`` (8 curves: compressor
     FAMILY axis × structural m segments) and ``participation_ablation``
     each execute as exactly one compiled program, numerically identical to
-    the per-method legacy paths;
-  * async plans (FedBuff staleness) match the legacy async steps;
+    the per-method single-point runs;
+  * async plans (FedBuff staleness) match single-point runs of the
+    driver's async step;
   * the DL dither-level cap is expressed on the traced path
     (``compressors.psum_level_cap``).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,17 +30,22 @@ from repro.core import api
 from repro.core.api import ExperimentPlan, MethodRun, get_method, run_plan
 from repro.core.compressors import (FAMILY_DITHER, FAMILY_IDENTITY,
                                     psum_level_cap, spec_bits, stack_specs)
-from repro.core.driver import (StalenessSchedule, participation_mask,
-                               resolve_participation, run_experiment)
-from repro.core.flecs import (FlecsConfig, hparam_grid, init_state,
-                              make_flecs_step)
+from repro.core.driver import (AsyncHParams, StalenessSchedule,
+                               init_async_state, make_async_step,
+                               participation_mask, resolve_participation,
+                               run_experiment)
+from repro.core.flecs import (FlecsConfig, hparam_grid, hparams_from_config,
+                              init_state, make_flecs_sweep_step)
 from repro.data.logreg import make_problem
-from repro.optim.baselines import (DianaConfig, FedNLConfig,
+from repro.optim.baselines import (DianaConfig, FedNLConfig, GDConfig,
                                    diana_hparam_grid,
-                                   gd_hparam_grid, init_diana,
-                                   init_diana_async, init_fednl, init_gd,
-                                   make_diana_async_step, make_diana_step,
-                                   make_fednl_step, make_gd_step)
+                                   diana_hparams_from_config,
+                                   fednl_hparams_from_config,
+                                   gd_hparam_grid, gd_hparams_from_config,
+                                   init_diana, init_fednl, init_gd,
+                                   make_diana_async_hooks,
+                                   make_diana_sweep_step,
+                                   make_fednl_sweep_step, make_gd_sweep_step)
 
 PROB = make_problem(d=16, n_workers=4, r=16, mu=1e-3, seed=3)
 LG, LH = PROB.make_oracles(batch=0)
@@ -46,6 +55,12 @@ ALL_METHODS = ("flecs", "flecs_cgd", "diana", "fednl", "gd")
 
 def _local_hessian(w, i):
     return jax.hessian(lambda ww: PROB.local_loss(ww, i))(w)
+
+
+def _flecs_step(cfg):
+    """The FLECS sweep step at the config's own hparams point."""
+    return functools.partial(make_flecs_sweep_step(cfg, LG, LH),
+                             hparams_from_config(cfg))
 
 
 def _legacy_key(seed, j, G, g):
@@ -102,18 +117,22 @@ def test_run_plan_matches_legacy_runs_all_five_methods():
     assert res.labels == ALL_METHODS
     rec = lambda st: PROB.metrics(st.w)                     # noqa: E731
     w0 = jnp.zeros(D)
+    diana, fednl = DianaConfig(1.0, 0.5, "dither64"), FedNLConfig(
+        1.0, "topk0.25", 1e-3)
     legacy = {
-        "flecs": (make_flecs_step(
-            FlecsConfig(grad_compressor="identity"), LG, LH),
-            init_state(w0, N)),
-        "flecs_cgd": (make_flecs_step(
-            FlecsConfig(grad_compressor="dither64"), LG, LH),
-            init_state(w0, N)),
-        "diana": (make_diana_step(1.0, 0.5, "dither64", LG),
+        "flecs": (_flecs_step(FlecsConfig(grad_compressor="identity")),
+                  init_state(w0, N)),
+        "flecs_cgd": (_flecs_step(FlecsConfig(grad_compressor="dither64")),
+                      init_state(w0, N)),
+        "diana": (functools.partial(make_diana_sweep_step(diana, LG),
+                                    diana_hparams_from_config(diana)),
                   init_diana(w0, N)),
-        "fednl": (make_fednl_step(1.0, "topk0.25", LG, _local_hessian,
-                                  1e-3), init_fednl(w0, N)),
-        "gd": (make_gd_step(2.0, LG, N), init_gd(w0, N)),
+        "fednl": (functools.partial(
+            make_fednl_sweep_step(fednl, LG, _local_hessian),
+            fednl_hparams_from_config(fednl)), init_fednl(w0, N)),
+        "gd": (functools.partial(make_gd_sweep_step(GDConfig(2.0), LG, N),
+                                 gd_hparams_from_config(GDConfig(2.0))),
+               init_gd(w0, N)),
     }
     for j, lab in enumerate(res.labels):
         step, st0 = legacy[lab]
@@ -223,7 +242,7 @@ def test_traced_p_sweep_matches_static_participation_runs():
     for g, p in enumerate(ps):
         cfg = FlecsConfig(m=1, alpha=0.5, participation=p,
                           sampling="bernoulli")
-        st, tr_g = run_experiment(make_flecs_step(cfg, LG, LH),
+        st, tr_g = run_experiment(_flecs_step(cfg),
                                   init_state(jnp.zeros(D), N),
                                   _legacy_key(4, 0, len(ps), g), 6,
                                   record=rec)
@@ -282,7 +301,7 @@ def test_fig1_plan_single_compile_and_matches_legacy():
             cfg = FlecsConfig(m=m, alpha=1.0, beta=1.0, gamma=1.0,
                               grad_compressor=gc,
                               hess_compressor="dither64")
-            st, tr_g = run_experiment(make_flecs_step(cfg, LG, LH),
+            st, tr_g = run_experiment(_flecs_step(cfg),
                                       init_state(jnp.zeros(D), N),
                                       _legacy_key(0, j, 2, g), iters,
                                       record=rec)
@@ -352,10 +371,13 @@ def test_async_plan_matches_legacy_async_step():
                                         sampling="choice")),),
         iters=8, seed=2, staleness=sched, buffer_k=2)
     res = run_plan(plan)
-    step = make_diana_async_step(1.0, 0.5, "dither64", LG, sched, 2,
-                                 participation=0.5, sampling="choice")
-    st, tr = run_experiment(step, init_diana_async(jnp.zeros(D), N, 1),
-                            _legacy_key(2, 0, 1, 0), 8,
+    cfg = DianaConfig(1.0, 0.5, "dither64", participation=0.5,
+                      sampling="choice")
+    hooks, hp = make_diana_async_hooks(cfg, LG), diana_hparams_from_config(cfg)
+    step = functools.partial(make_async_step(hooks, sched.kind, sched.q),
+                             AsyncHParams(hp, jnp.int32(1), jnp.float32(2)))
+    st0 = init_async_state(hooks, init_diana(jnp.zeros(D), N), hp, 1)
+    st, tr = run_experiment(step, st0, _legacy_key(2, 0, 1, 0), 8,
                             record=lambda s: PROB.metrics(s.w))
     np.testing.assert_array_equal(
         np.asarray(tr["bits_per_node"]),
@@ -367,17 +389,20 @@ def test_async_plan_matches_legacy_async_step():
 
 def test_async_fednl_plan_matches_legacy_async_step():
     """Async FedNL closes the five-method async matrix: the plan path
-    reproduces the legacy buffered step with exact bit ledgers."""
-    from repro.optim.baselines import (init_fednl_async,
-                                       make_fednl_async_step)
+    reproduces a single-point run of the buffered step with exact bit
+    ledgers."""
+    from repro.optim.baselines import make_fednl_async_hooks
     sched = StalenessSchedule("fixed", tau=1)
     plan = ExperimentPlan(problem=PROB, runs=(MethodRun("fednl"),),
                           iters=8, seed=2, staleness=sched, buffer_k=2)
     res = run_plan(plan)
-    step = make_fednl_async_step(1.0, "topk0.25", LG, _local_hessian, 1e-3,
-                                 sched, 2)
-    st, tr = run_experiment(step, init_fednl_async(jnp.zeros(D), N, 1),
-                            _legacy_key(2, 0, 1, 0), 8,
+    cfg = FedNLConfig(1.0, "topk0.25", 1e-3)
+    hooks = make_fednl_async_hooks(cfg, LG, _local_hessian)
+    hp = fednl_hparams_from_config(cfg)
+    step = functools.partial(make_async_step(hooks, sched.kind, sched.q),
+                             AsyncHParams(hp, jnp.int32(1), jnp.float32(2)))
+    st0 = init_async_state(hooks, init_fednl(jnp.zeros(D), N), hp, 1)
+    st, tr = run_experiment(step, st0, _legacy_key(2, 0, 1, 0), 8,
                             record=lambda s: PROB.metrics(s.w))
     np.testing.assert_array_equal(
         np.asarray(tr["bits_per_node"]),
@@ -389,12 +414,11 @@ def test_async_fednl_plan_matches_legacy_async_step():
 
 def test_async_plan_rejects_methods_without_async_variant():
     """All five registry methods now carry async variants, so the guard
-    is pinned with a stripped spec: a custom method without the async
-    triple must fail loudly on a staleness plan."""
+    is pinned with a stripped spec: a custom method without async hooks
+    must fail loudly on a staleness plan."""
     import dataclasses
     no_async = dataclasses.replace(get_method("fednl"), name="_noasync",
-                                   init_async=None, async_sweep_step=None,
-                                   async_wrap=None)
+                                   async_hooks=None)
     plan = ExperimentPlan(problem=PROB, runs=(MethodRun(no_async),),
                           iters=2, staleness=StalenessSchedule("fixed",
                                                                tau=1))
@@ -406,12 +430,10 @@ def test_async_plan_rejects_undersized_buffer_for_user_tau_grid():
     """A user-supplied async hparam grid whose tau exceeds the schedule's
     max_delay must fail loudly — slot indices wrap modulo the buffer size,
     so the oversized-tau point would silently run at a shorter delay."""
-    from repro.optim.baselines import (DianaAsyncHParams,
-                                      diana_hparam_grid)
     hp = jax.tree.map(lambda a: jnp.broadcast_to(a, (2,)),
                       diana_hparam_grid())
-    ahp = DianaAsyncHParams(hp, jnp.asarray([0, 4], jnp.int32),
-                            jnp.ones((2,), jnp.float32))
+    ahp = AsyncHParams(hp, jnp.asarray([0, 4], jnp.int32),
+                       jnp.ones((2,), jnp.float32))
     plan = ExperimentPlan(
         problem=PROB, runs=(MethodRun("diana", hparams=ahp),),
         iters=4, staleness=StalenessSchedule("fixed", tau=1))

@@ -14,23 +14,30 @@ Covers the async engine's hard contracts:
   * a tau=2, p=0.5 FLECS-CGD run on a d=40 logreg problem converges to
     F - F* < 1e-3.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.driver import (StalenessSchedule, buffer_busy,
-                               buffer_receive, buffer_send, init_buffer,
-                               run_experiment)
-from repro.core.flecs import (FlecsConfig, bits_per_round, init_async_state,
-                              init_state, make_flecs_async_step,
-                              make_flecs_step)
+from repro.core.driver import (AsyncHParams, StalenessSchedule, buffer_busy,
+                               buffer_receive, buffer_send, init_async_state,
+                               init_buffer, make_async_step, run_experiment)
+from repro.core.flecs import (FlecsConfig, bits_per_round,
+                              hparams_from_config, init_state,
+                              make_flecs_async_hooks, make_flecs_sweep_step)
 from repro.data.logreg import make_problem
-from repro.optim.baselines import (init_diana, init_diana_async, init_fednl,
-                                   init_fednl_async, init_gd, init_gd_async,
-                                   make_diana_async_step, make_diana_step,
-                                   make_fednl_async_step, make_fednl_step,
-                                   make_gd_async_step, make_gd_step)
+from repro.optim.baselines import (DianaConfig, FedNLConfig, GDConfig,
+                                   diana_hparams_from_config,
+                                   fednl_hparams_from_config,
+                                   gd_hparams_from_config, init_diana,
+                                   init_fednl, init_gd,
+                                   make_diana_async_hooks,
+                                   make_diana_sweep_step,
+                                   make_fednl_async_hooks,
+                                   make_fednl_sweep_step,
+                                   make_gd_async_hooks, make_gd_sweep_step)
 
 PROB = make_problem(d=24, n_workers=4, r=24, mu=1e-3, seed=5)
 LG, LH = PROB.make_oracles(batch=0)
@@ -39,6 +46,27 @@ N, D = PROB.n_workers, PROB.d
 
 def _local_hessian(w, i):
     return jax.hessian(lambda ww: PROB.local_loss(ww, i))(w)
+
+
+def _flecs_step(cfg, lg=LG, lh=LH):
+    """The FLECS sweep step at the config's own hparams point."""
+    return functools.partial(make_flecs_sweep_step(cfg, lg, lh),
+                             hparams_from_config(cfg))
+
+
+def _async(hooks, hp, state, sched, buffer_k):
+    """The driver's async step at one (hp, tau, buffer_k) point, and the
+    async state around the method's initial ``state``."""
+    ahp = AsyncHParams(hp, jnp.int32(sched.tau), jnp.float32(buffer_k))
+    step = functools.partial(make_async_step(hooks, sched.kind, sched.q),
+                             ahp)
+    return step, init_async_state(hooks, state, hp, sched.max_delay)
+
+
+def _flecs_async(cfg, sched, buffer_k, lg=LG, lh=LH, n=N, d=D):
+    return _async(make_flecs_async_hooks(cfg, lg, lh),
+                  hparams_from_config(cfg), init_state(jnp.zeros(d), n),
+                  sched, buffer_k)
 
 
 # ---------------------------------------------------------------------------
@@ -133,8 +161,8 @@ def test_buffer_cyclic_slot_reuse():
 # tau=0 collapse to the synchronous engine
 # ---------------------------------------------------------------------------
 
-def _compare_sync_async(step_sync, st_sync0, step_async, st_async0, iters=30,
-                        seed=11):
+def _compare_sync_async(step_sync, st_sync0, async_run, iters=30, seed=11):
+    step_async, st_async0 = async_run
     rec = lambda s: {"F": PROB.global_loss(s.w)}            # noqa: E731
     st_s, tr_s = run_experiment(step_sync, st_sync0, jax.random.key(seed),
                                 iters, record=rec)
@@ -157,10 +185,8 @@ def test_tau0_flecs_matches_sync_engine(cfg_kw, K):
                       hess_compressor="dither64", **cfg_kw)
     sched = StalenessSchedule("fixed", tau=0)
     _compare_sync_async(
-        make_flecs_step(cfg, LG, LH), init_state(jnp.zeros(D), N),
-        make_flecs_async_step(cfg, LG, LH, sched,
-                              buffer_k=N if K is None else K),
-        init_async_state(jnp.zeros(D), N, cfg.m, sched.max_delay))
+        _flecs_step(cfg), init_state(jnp.zeros(D), N),
+        _flecs_async(cfg, sched, N if K is None else K))
 
 
 def test_tau0_flecs_lsr1_tinv_matches_sync_engine():
@@ -170,25 +196,26 @@ def test_tau0_flecs_lsr1_tinv_matches_sync_engine():
                       direction="truncated_inverse", tinv_floor=1e-3)
     sched = StalenessSchedule("fixed", tau=0)
     _compare_sync_async(
-        make_flecs_step(cfg, LG, LH), init_state(jnp.zeros(D), N),
-        make_flecs_async_step(cfg, LG, LH, sched, buffer_k=N),
-        init_async_state(jnp.zeros(D), N, cfg.m, sched.max_delay))
+        _flecs_step(cfg), init_state(jnp.zeros(D), N),
+        _flecs_async(cfg, sched, N))
 
 
 def test_tau0_diana_gd_match_sync_engine():
     sched = StalenessSchedule("fixed", tau=0)
+    cfg = DianaConfig(1.0, 0.5, "dither64", participation=0.3)
+    hp = diana_hparams_from_config(cfg)
     _compare_sync_async(
-        make_diana_step(1.0, 0.5, "dither64", LG, participation=0.3),
+        functools.partial(make_diana_sweep_step(cfg, LG), hp),
         init_diana(jnp.zeros(D), N),
-        make_diana_async_step(1.0, 0.5, "dither64", LG, sched, 1,
-                              participation=0.3),
-        init_diana_async(jnp.zeros(D), N, 0))
+        _async(make_diana_async_hooks(cfg, LG), hp,
+               init_diana(jnp.zeros(D), N), sched, 1))
+    cfg = GDConfig(1.0, participation=0.5, sampling="choice")
+    hp = gd_hparams_from_config(cfg)
     _compare_sync_async(
-        make_gd_step(1.0, LG, N, participation=0.5, sampling="choice"),
+        functools.partial(make_gd_sweep_step(cfg, LG, N), hp),
         init_gd(jnp.zeros(D), N),
-        make_gd_async_step(1.0, LG, N, sched, 1,
-                           participation=0.5, sampling="choice"),
-        init_gd_async(jnp.zeros(D), N, 0))
+        _async(make_gd_async_hooks(cfg, LG, N), hp,
+               init_gd(jnp.zeros(D), N), sched, 1))
 
 
 @pytest.mark.parametrize("fednl_kw,K", [
@@ -201,13 +228,14 @@ def test_tau0_fednl_matches_sync_engine(fednl_kw, K):
     collapses bit-for-bit onto the synchronous learned-Hessian path at
     tau=0 — the last method to close the five-method async matrix."""
     sched = StalenessSchedule("fixed", tau=0)
+    cfg = FedNLConfig(1.0, "topk0.25", PROB.mu, **fednl_kw)
+    hp = fednl_hparams_from_config(cfg)
     _compare_sync_async(
-        make_fednl_step(1.0, "topk0.25", LG, _local_hessian, PROB.mu,
-                        **fednl_kw),
+        functools.partial(make_fednl_sweep_step(cfg, LG, _local_hessian),
+                          hp),
         init_fednl(jnp.zeros(D), N),
-        make_fednl_async_step(1.0, "topk0.25", LG, _local_hessian, PROB.mu,
-                              sched, N if K is None else K, **fednl_kw),
-        init_fednl_async(jnp.zeros(D), N, sched.max_delay))
+        _async(make_fednl_async_hooks(cfg, LG, _local_hessian), hp,
+               init_fednl(jnp.zeros(D), N), sched, N if K is None else K))
 
 
 # ---------------------------------------------------------------------------
@@ -221,10 +249,9 @@ def test_bits_charged_only_at_arrival_rounds():
     cfg = FlecsConfig(m=1, grad_compressor="dither64",
                       hess_compressor="dither64")
     sched = StalenessSchedule("fixed", tau=2)
-    step = make_flecs_async_step(cfg, LG, LH, sched, buffer_k=N)
+    step, st0 = _flecs_async(cfg, sched, N)
     iters = 12
-    st, tr = run_experiment(step, init_async_state(jnp.zeros(D), N, 1, 2),
-                            jax.random.key(0), iters)
+    st, tr = run_experiment(step, st0, jax.random.key(0), iters)
     per_round = bits_per_round(cfg, D)
     inc = np.diff(np.concatenate([np.zeros((1, N)),
                                   np.asarray(tr["bits_per_node"])]), axis=0)
@@ -247,9 +274,8 @@ def test_arrivals_conserve_sends():
     """Every sent message arrives exactly once (within the horizon)."""
     cfg = FlecsConfig(m=1, participation=0.5, sampling="choice")
     sched = StalenessSchedule("uniform", tau=3)
-    step = make_flecs_async_step(cfg, LG, LH, sched, buffer_k=2)
-    st, tr = run_experiment(step, init_async_state(jnp.zeros(D), N, 1, 3),
-                            jax.random.key(4), 60)
+    step, st0 = _flecs_async(cfg, sched, 2)
+    st, tr = run_experiment(step, st0, jax.random.key(4), 60)
     sent = float(np.sum(np.asarray(tr["n_active"])))
     arrived = float(np.sum(np.asarray(tr["n_arrived"])))
     in_flight = float(np.sum(np.asarray(buffer_busy(st.buf))))
@@ -283,10 +309,9 @@ def test_stale_flecs_cgd_converges_to_1e3():
                       hess_compressor="dither128",
                       participation=0.5, sampling="choice")
     sched = StalenessSchedule("fixed", tau=2)
-    step = make_flecs_async_step(cfg, lg, lh, sched, buffer_k=4)
-    st, tr = run_experiment(
-        step, init_async_state(jnp.zeros(prob.d), 8, cfg.m, sched.max_delay),
-        jax.random.key(0), 2400, record_every=10)
+    step, st0 = _flecs_async(cfg, sched, 4, lg, lh, 8, prob.d)
+    st, tr = run_experiment(step, st0, jax.random.key(0), 2400,
+                            record_every=10)
     F = float(prob.global_loss(st.w))
     assert F - f_star < 1e-3, (F, f_star)
     # thinned traces: 2400 // 10 rows, bits ledger still exact multiples of

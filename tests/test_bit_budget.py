@@ -25,13 +25,13 @@ from repro.core import api
 from repro.core.api import ExperimentPlan, MethodRun, get_method, run_plan
 from repro.core.compressors import spec_bits, topk_spec
 from repro.core.driver import (StalenessSchedule, freeze_on_bit_budget,
-                               hparams_bit_budget, iters_for_bit_budget,
+                               hparams_bit_budget, init_async_state,
+                               iters_for_bit_budget, make_async_step,
                                run_async_sweep, run_sweep)
 from repro.core.flecs import (FlecsConfig, async_hparam_grid, bits_per_round,
-                              hparam_grid, hparams_round_bits,
-                              init_async_state, init_state,
-                              make_flecs_async_sweep_step,
-                              make_flecs_sweep_step)
+                              hparam_grid, hparams_from_config,
+                              hparams_round_bits, init_state,
+                              make_flecs_async_hooks, make_flecs_sweep_step)
 from repro.data.logreg import make_problem
 from repro.optim.baselines import (DianaConfig, FedNLConfig, GDConfig,
                                    diana_round_bits,
@@ -206,8 +206,10 @@ def test_async_budget_freeze_arrival_billing():
     budget = 2.5 * PRICE
     ahp_b = ahp._replace(hp=ahp.hp._replace(
         bit_budget=jnp.full((1,), budget, jnp.float32)))
-    sweep = make_flecs_async_sweep_step(cfg, LG, LH)
-    st0 = init_async_state(jnp.zeros(D), N, cfg.m, tau)
+    hooks = make_flecs_async_hooks(cfg, LG, LH)
+    sweep = make_async_step(hooks)
+    st0 = init_async_state(hooks, init_state(jnp.zeros(D), N),
+                           hparams_from_config(cfg), tau)
     sts_b, tr_b = run_async_sweep(sweep, ahp_b, st0, jax.random.key(5), T)
     sts, tr = run_async_sweep(sweep, ahp, st0, jax.random.key(5), T)
 

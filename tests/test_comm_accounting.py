@@ -8,6 +8,7 @@
 * Bit counters share one x64-aware dtype across flecs and every baseline
   (f32 loses integer counts past 2^24, reachable in long sweeps).
 """
+import functools
 import math
 
 import jax
@@ -17,20 +18,31 @@ import pytest
 
 from repro.core.compressors import dither_bits
 from repro.core.driver import (bits_dtype, participation_mask, run_experiment)
-from repro.core.flecs import (FlecsConfig, bits_per_round, init_state,
-                              make_flecs_step)
+from repro.core.flecs import (FlecsConfig, bits_per_round,
+                              hparams_from_config, init_state,
+                              make_flecs_sweep_step)
 from repro.data.logreg import make_problem
-from repro.optim.baselines import (init_diana, init_fednl, init_gd,
-                                   make_diana_step, make_fednl_step,
-                                   make_gd_step)
+from repro.optim.baselines import (DianaConfig, FedNLConfig, GDConfig,
+                                   diana_hparams_from_config,
+                                   fednl_hparams_from_config,
+                                   gd_hparams_from_config, init_diana,
+                                   init_fednl, init_gd,
+                                   make_diana_sweep_step,
+                                   make_fednl_sweep_step, make_gd_sweep_step)
 
 PROB = make_problem(d=40, n_workers=8, r=32, mu=1e-3, seed=2)
 LG, LH = PROB.make_oracles(batch=0)
+
+
+def _flecs_step(cfg, lg, lh):
+    """The FLECS sweep step at the config's own hparams point."""
+    return functools.partial(make_flecs_sweep_step(cfg, lg, lh),
+                             hparams_from_config(cfg))
 D, N = PROB.d, PROB.n_workers
 
 
 def _one_round(cfg):
-    step = make_flecs_step(cfg, LG, LH)
+    step = _flecs_step(cfg, LG, LH)
     st, _ = run_experiment(step, init_state(jnp.zeros(D), N),
                            jax.random.key(0), 1)
     return st
@@ -65,7 +77,7 @@ def test_skipped_worker_charged_zero_bits():
                       hess_compressor="dither64",
                       participation=0.5, sampling="choice")
     per_round = bits_per_round(cfg, D)
-    step = make_flecs_step(cfg, LG, LH)
+    step = _flecs_step(cfg, LG, LH)
     st, tr = run_experiment(step, init_state(jnp.zeros(D), N),
                             jax.random.key(7), 10)
     bills = np.asarray(tr["bits_per_node"])                 # [10, n] cumulative
@@ -80,7 +92,7 @@ def test_skipped_worker_charged_zero_bits():
 def test_bernoulli_sampling_bills_only_sampled():
     cfg = FlecsConfig(m=1, participation=0.3, sampling="bernoulli")
     per_round = bits_per_round(cfg, D)
-    st, tr = run_experiment(make_flecs_step(cfg, LG, LH),
+    st, tr = run_experiment(_flecs_step(cfg, LG, LH),
                             init_state(jnp.zeros(D), N), jax.random.key(1), 20)
     inc = np.diff(np.concatenate(
         [np.zeros((1, N)), np.asarray(tr["bits_per_node"])]), axis=0)
@@ -143,11 +155,15 @@ def test_bits_dtype_unified_across_methods():
 
 def test_baseline_bits_respect_participation():
     """DIANA / FedNL / GD: skipped workers pay zero."""
+    diana = DianaConfig(0.5, 0.5, "dither64", participation=0.5,
+                        sampling="choice")
+    gd = GDConfig(1.0, participation=0.5, sampling="choice")
     runs = {
-        "diana": (make_diana_step(0.5, 0.5, "dither64", LG,
-                                  participation=0.5, sampling="choice"),
+        "diana": (functools.partial(make_diana_sweep_step(diana, LG),
+                                    diana_hparams_from_config(diana)),
                   init_diana(jnp.zeros(D), N), D * 8.0),
-        "gd": (make_gd_step(1.0, LG, N, participation=0.5, sampling="choice"),
+        "gd": (functools.partial(make_gd_sweep_step(gd, LG, N),
+                                 gd_hparams_from_config(gd)),
                init_gd(jnp.zeros(D), N), D * 32.0),
     }
 
@@ -158,9 +174,11 @@ def test_baseline_bits_respect_participation():
     # values at (32 + ⌈log2 d²⌉) bits each (dimension-aware index cost)
     kept = math.ceil(0.25 * D * D)
     fednl_hess_bits = kept * (32.0 + math.ceil(math.log2(D * D)))
+    fednl = FedNLConfig(1.0, "topk0.25", PROB.mu, participation=0.5,
+                        sampling="choice")
     runs["fednl"] = (
-        make_fednl_step(1.0, "topk0.25", LG, local_hessian, PROB.mu,
-                        participation=0.5, sampling="choice"),
+        functools.partial(make_fednl_sweep_step(fednl, LG, local_hessian),
+                          fednl_hparams_from_config(fednl)),
         init_fednl(jnp.zeros(D), N), D * 32.0 + fednl_hess_bits)
     for name, (step, st0, per_round) in runs.items():
         st, tr = run_experiment(step, st0, jax.random.key(3), 6)

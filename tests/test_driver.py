@@ -4,6 +4,8 @@ Checks the engine against a hand-rolled loop over the same key sequence,
 the trace plumbing (aux stacking + in-scan record hook), and the vmapped
 hyperparameter sweep path.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,18 +13,24 @@ import pytest
 
 from repro.core.driver import (iters_for_bit_budget, masked_mean,
                                run_experiment, run_sweep)
-from repro.core.flecs import (FlecsConfig, hparam_grid, init_state,
-                              make_flecs_step, make_flecs_sweep_step)
+from repro.core.flecs import (FlecsConfig, hparam_grid, hparams_from_config,
+                              init_state, make_flecs_sweep_step)
 from repro.data.logreg import make_problem
 
 PROB = make_problem(d=24, n_workers=4, r=24, mu=1e-3, seed=5)
 LG, LH = PROB.make_oracles(batch=0)
+
+
+def _flecs_step(cfg, lg, lh):
+    """The FLECS sweep step at the config's own hparams point."""
+    return functools.partial(make_flecs_sweep_step(cfg, lg, lh),
+                             hparams_from_config(cfg))
 CFG = FlecsConfig(m=2, grad_compressor="dither64", hess_compressor="dither64")
 
 
 def test_scan_matches_manual_loop():
     """One scan program == stepping the same keys by hand."""
-    step = make_flecs_step(CFG, LG, LH)
+    step = _flecs_step(CFG, LG, LH)
     st0 = init_state(jnp.zeros(PROB.d), PROB.n_workers)
     iters = 7
     st_scan, traces = run_experiment(step, st0, jax.random.key(11), iters)
@@ -40,7 +48,7 @@ def test_scan_matches_manual_loop():
 
 
 def test_traces_stack_and_record_hook():
-    step = make_flecs_step(CFG, LG, LH)
+    step = _flecs_step(CFG, LG, LH)
     st, tr = run_experiment(step, init_state(jnp.zeros(PROB.d),
                                              PROB.n_workers),
                             jax.random.key(0), 12,
@@ -58,7 +66,7 @@ def test_traces_stack_and_record_hook():
 def test_record_every_matches_dense_trace():
     """record_every=E traces have length iters // E and equal the dense
     trace at the recorded indices (rows E-1, 2E-1, …)."""
-    step = make_flecs_step(CFG, LG, LH)
+    step = _flecs_step(CFG, LG, LH)
     st0 = init_state(jnp.zeros(PROB.d), PROB.n_workers)
     iters, every = 30, 10
     rec = lambda s: PROB.metrics(s.w)                       # noqa: E731
@@ -81,7 +89,7 @@ def test_trace_dtype_bf16_keeps_bits_ledger_exact():
     """trace_dtype=bf16 quarters trace memory for long runs, but the bits
     ledger must stay in driver.bits_dtype() (bf16 loses integer counts)."""
     from repro.core.driver import bits_dtype
-    step = make_flecs_step(CFG, LG, LH)
+    step = _flecs_step(CFG, LG, LH)
     st0 = init_state(jnp.zeros(PROB.d), PROB.n_workers)
     _, tr = run_experiment(step, st0, jax.random.key(1), 8,
                            record=lambda s: PROB.metrics(s.w),

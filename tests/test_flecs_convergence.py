@@ -11,21 +11,34 @@ Validates the paper's claims at test scale:
 All runs go through ``repro.core.driver.run_experiment`` — one lax.scan
 program per run, no Python-level step loops.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.driver import iters_for_bit_budget, run_experiment
-from repro.core.flecs import (FlecsConfig, bits_per_round, init_state,
-                              make_flecs_step)
+from repro.core.flecs import (FlecsConfig, bits_per_round,
+                              hparams_from_config, init_state,
+                              make_flecs_sweep_step)
 from repro.data.logreg import make_problem
-from repro.optim.baselines import (init_diana, init_fednl, init_gd,
-                                   make_diana_step, make_fednl_step,
-                                   make_gd_step)
+from repro.optim.baselines import (DianaConfig, FedNLConfig, GDConfig,
+                                   diana_hparams_from_config,
+                                   fednl_hparams_from_config,
+                                   gd_hparams_from_config, init_diana,
+                                   init_fednl, init_gd,
+                                   make_diana_sweep_step,
+                                   make_fednl_sweep_step, make_gd_sweep_step)
 
 PROB = make_problem(d=40, n_workers=8, r=48, mu=1e-3, seed=0)
 LG, LH = PROB.make_oracles(batch=0)
+
+
+def _flecs_step(cfg, lg, lh):
+    """The FLECS sweep step at the config's own hparams point."""
+    return functools.partial(make_flecs_sweep_step(cfg, lg, lh),
+                             hparams_from_config(cfg))
 
 F_STAR = float(PROB.global_loss(PROB.solve()))
 
@@ -39,7 +52,7 @@ def _run(step, state, iters=250, seed=0, record=None):
 def test_flecs_cgd_descends_strongly_convex():
     cfg = FlecsConfig(m=4, grad_compressor="dither128",
                       hess_compressor="dither128")
-    step = make_flecs_step(cfg, LG, LH)
+    step = _flecs_step(cfg, LG, LH)
     st, _ = _run(step, init_state(jnp.zeros(PROB.d), PROB.n_workers))
     F = float(PROB.global_loss(st.w))
     assert F - F_STAR < 5e-3, (F, F_STAR)
@@ -50,7 +63,7 @@ def test_cgd_fewer_bits_than_flecs():
     bits = {}
     for name, gc in [("flecs", "identity"), ("cgd", "dither64")]:
         cfg = FlecsConfig(m=1, grad_compressor=gc, hess_compressor="dither64")
-        step = make_flecs_step(cfg, LG, LH)
+        step = _flecs_step(cfg, LG, LH)
         st, _ = _run(step, init_state(jnp.zeros(PROB.d), PROB.n_workers),
                      iters=5)
         # full participation: every worker pays the same
@@ -75,7 +88,7 @@ def test_cgd_better_loss_per_bit():
     for name, gc in [("flecs", "identity"), ("cgd", "dither128")]:
         cfg = FlecsConfig(m=1, grad_compressor=gc, hess_compressor="dither128")
         iters = iters_for_bit_budget(budget, bits_per_round(cfg, PROB.d))
-        step = make_flecs_step(cfg, LG, LH)
+        step = _flecs_step(cfg, LG, LH)
         st, _ = _run(step, init_state(jnp.zeros(PROB.d), PROB.n_workers),
                      iters=iters, seed=3)
         assert float(st.bits_per_node[0]) >= budget
@@ -89,7 +102,7 @@ def test_stochastic_oracles_converge_to_ball():
     lg, lh = PROB.make_oracles(batch=32)
     cfg = FlecsConfig(m=2, alpha=0.2, grad_compressor="dither128",
                       hess_compressor="dither128")
-    step = make_flecs_step(cfg, lg, lh)
+    step = _flecs_step(cfg, lg, lh)
     st, _ = _run(step, init_state(jnp.zeros(PROB.d), PROB.n_workers),
                  iters=600)
     F = float(PROB.global_loss(st.w))
@@ -103,9 +116,9 @@ def test_partial_participation_converges_with_fewer_bits():
               hess_compressor="dither128")
     full = FlecsConfig(**kw)
     half = FlecsConfig(participation=0.5, sampling="choice", **kw)
-    st_full, _ = _run(make_flecs_step(full, LG, LH),
+    st_full, _ = _run(_flecs_step(full, LG, LH),
                       init_state(jnp.zeros(PROB.d), PROB.n_workers))
-    st_half, tr = _run(make_flecs_step(half, LG, LH),
+    st_half, tr = _run(_flecs_step(half, LG, LH),
                        init_state(jnp.zeros(PROB.d), PROB.n_workers))
     F = float(PROB.global_loss(st_half.w))
     assert F - F_STAR < 5e-2, (F, F_STAR)
@@ -118,8 +131,9 @@ def test_partial_participation_converges_with_fewer_bits():
 
 
 def test_diana_baseline_converges():
-    step = make_diana_step(alpha=1.0, gamma=0.5, compressor="dither64",
-                           local_grad=LG)
+    cfg = DianaConfig(alpha=1.0, gamma=0.5, compressor="dither64")
+    step = functools.partial(make_diana_sweep_step(cfg, LG),
+                             diana_hparams_from_config(cfg))
     st, _ = _run(step, init_diana(jnp.zeros(PROB.d), PROB.n_workers),
                  iters=400)
     assert float(PROB.global_loss(st.w)) - F_STAR < 5e-2
@@ -129,16 +143,18 @@ def test_fednl_baseline_converges():
     def local_hessian(w, i):
         return jax.hessian(lambda ww: PROB.local_loss(ww, i))(w)
 
-    step = make_fednl_step(alpha=1.0, compressor="topk0.25",
-                           local_grad=LG, local_hessian=local_hessian,
-                           mu=PROB.mu)
+    cfg = FedNLConfig(alpha=1.0, compressor="topk0.25", mu=PROB.mu)
+    step = functools.partial(make_fednl_sweep_step(cfg, LG, local_hessian),
+                             fednl_hparams_from_config(cfg))
     st, _ = _run(step, init_fednl(jnp.zeros(PROB.d), PROB.n_workers),
                  iters=60)
     assert float(PROB.global_loss(st.w)) - F_STAR < 1e-3
 
 
 def test_gd_baseline_converges():
-    step = make_gd_step(alpha=2.0, local_grad=LG, n_workers=PROB.n_workers)
+    cfg = GDConfig(alpha=2.0)
+    step = functools.partial(make_gd_sweep_step(cfg, LG, PROB.n_workers),
+                             gd_hparams_from_config(cfg))
     st, _ = _run(step, init_gd(jnp.zeros(PROB.d), PROB.n_workers), iters=300)
     assert float(PROB.global_loss(st.w)) - F_STAR < 1e-2
 
@@ -150,7 +166,7 @@ def test_lyapunov_descent_in_expectation():
     driver's record hook — no host round-trips."""
     cfg = FlecsConfig(m=2, alpha=0.5, gamma=0.5, grad_compressor="dither64",
                       hess_compressor="dither64")
-    step = make_flecs_step(cfg, LG, LH)
+    step = _flecs_step(cfg, LG, LH)
     st0 = init_state(jnp.zeros(PROB.d), PROB.n_workers)
     # h* = local grads at (approximate) optimum
     w_star = PROB.solve()

@@ -6,13 +6,14 @@ Pins the refactor's hard contracts:
   * top-k wire accounting is dimension-aware: ⌈frac·d⌉ kept values at
     (32 + ⌈log2 d⌉) bits each — not the old flat 64·frac per element;
   * a single compiled ``run_sweep`` over a grid varying hess_s AND beta
-    reproduces per-point ``make_flecs_step`` runs trace-for-trace
-    (iterates + exact bit ledgers);
+    reproduces per-point single runs trace-for-trace (iterates + exact
+    bit ledgers);
   * ``run_async_sweep`` over a (tau, buffer_k) grid matches independent
-    ``make_flecs_async_step`` runs, and its tau=0 point collapses to the
-    synchronous engine bit-for-bit;
+    single-point runs of the driver's async step, and its tau=0 point
+    collapses to the synchronous engine bit-for-bit;
   * ``damped_alpha`` implements alpha0 · min(1, p·K/n).
 """
+import functools
 import math
 
 import jax
@@ -30,19 +31,30 @@ from repro.core.compressors import (ALL_FAMILIES, FAMILY_COUNT_SKETCH,
                                     natural_spec, random_dithering,
                                     spec_bits, spec_omega, stack_specs,
                                     topk_spec)
-from repro.core.driver import (StalenessSchedule, damped_alpha,
-                               run_async_sweep, run_experiment, run_sweep,
-                               sample_delays)
+from repro.core.driver import (AsyncHParams, damped_alpha, init_async_state,
+                               make_async_step, run_async_sweep,
+                               run_experiment, run_sweep, sample_delays)
 from repro.core.flecs import (FlecsConfig, async_hparam_grid, bits_per_round,
-                              hparam_grid, init_async_state, init_state,
-                              make_flecs_async_step,
-                              make_flecs_async_sweep_step, make_flecs_step,
-                              make_flecs_sweep_step)
+                              hparam_grid, hparams_from_config, init_state,
+                              make_flecs_async_hooks, make_flecs_sweep_step)
 from repro.data.logreg import make_problem
 
 PROB = make_problem(d=24, n_workers=4, r=24, mu=1e-3, seed=5)
 LG, LH = PROB.make_oracles(batch=0)
 N, D = PROB.n_workers, PROB.d
+
+
+def _flecs_step(cfg, lg, lh):
+    """The FLECS sweep step at the config's own hparams point."""
+    return functools.partial(make_flecs_sweep_step(cfg, lg, lh),
+                             hparams_from_config(cfg))
+
+
+def _flecs_async_state(cfg, lg, lh, d, n, max_delay):
+    """The driver's async state around a fresh FLECS state."""
+    return init_async_state(make_flecs_async_hooks(cfg, lg, lh),
+                            init_state(jnp.zeros(d), n),
+                            hparams_from_config(cfg), max_delay)
 
 
 # ---------------------------------------------------------------------------
@@ -142,7 +154,7 @@ def test_topk_bits_dimension_aware():
     expect = (8 * D + kept * (32 + math.ceil(math.log2(dm)))
               + 32 * cfg.m * cfg.m)
     assert bits_per_round(cfg, D) == expect
-    step = make_flecs_step(cfg, LG, LH)
+    step = _flecs_step(cfg, LG, LH)
     st, _ = run_experiment(step, init_state(jnp.zeros(D), N),
                            jax.random.key(0), 2)
     np.testing.assert_allclose(np.asarray(st.bits_per_node), 2 * expect)
@@ -345,7 +357,7 @@ def test_narrowed_dispatch_is_bit_identical(rng, names, use_kernel):
 
 def test_sweep_over_hess_and_beta_matches_static_steps():
     """ONE compiled run_sweep varying hess_s AND beta reproduces each
-    point's make_flecs_step run trace-for-trace: exact bit ledgers (same
+    point's single run trace-for-trace: exact bit ledgers (same
     key streams => same dither draws) and iterates to batched-kernel ulp."""
     hp = hparam_grid([1.0], [1.0], [16.0], betas=[0.5, 1.0],
                      hess_levels=[8.0, 64.0])
@@ -363,7 +375,7 @@ def test_sweep_over_hess_and_beta_matches_static_steps():
         cfg_g = FlecsConfig(
             m=2, beta=float(hp.beta[g]), grad_compressor="dither16",
             hess_compressor=f"dither{int(hp.hess_s[g])}")
-        st_g, tr_g = run_experiment(make_flecs_step(cfg_g, LG, LH), st0,
+        st_g, tr_g = run_experiment(_flecs_step(cfg_g, LG, LH), st0,
                                     keys[g], iters, record=rec)
         np.testing.assert_array_equal(np.asarray(tr_g["bits_per_node"]),
                                       np.asarray(tr["bits_per_node"][g]))
@@ -396,7 +408,7 @@ def test_hparam_grid_widened_axes():
 
 def test_async_sweep_matches_independent_async_runs():
     """run_async_sweep over a (tau, buffer_k) grid sharing one max-delay
-    buffer shape == independent make_flecs_async_step runs per point; the
+    buffer shape == independent single-point async runs per point; the
     tau=0 point == the synchronous engine bit-for-bit."""
     taus, Ks = [0, 2], [1.0, 2.0]
     cfg = FlecsConfig(m=2, alpha=0.5, grad_compressor="dither64",
@@ -404,9 +416,9 @@ def test_async_sweep_matches_independent_async_runs():
                       participation=0.5, sampling="choice")
     ahp = async_hparam_grid(taus, Ks, alpha=cfg.alpha, gamma=cfg.gamma,
                             beta=cfg.beta, grad_s=64.0, hess_s=64.0)
-    sweep = make_flecs_async_sweep_step(cfg, LG, LH)
+    sweep = make_async_step(make_flecs_async_hooks(cfg, LG, LH))
     max_delay = max(taus)
-    st0 = init_async_state(jnp.zeros(D), N, cfg.m, max_delay)
+    st0 = _flecs_async_state(cfg, LG, LH, D, N, max_delay)
     iters = 20
     rec = lambda s: {"F": PROB.global_loss(s.w)}            # noqa: E731
     sts, tr = run_async_sweep(sweep, ahp, st0, jax.random.key(17), iters,
@@ -416,9 +428,9 @@ def test_async_sweep_matches_independent_async_runs():
     for g in range(G):
         # IMPORTANT: the independent run must use the SAME buffer shape
         # (the shared max-delay slots) to consume identical slot indices
-        step_g = make_flecs_async_step(
-            cfg, LG, LH, StalenessSchedule("fixed", tau=int(ahp.tau[g])),
-            buffer_k=float(ahp.buffer_k[g]))
+        step_g = functools.partial(sweep, AsyncHParams(
+            hparams_from_config(cfg), jnp.int32(int(ahp.tau[g])),
+            jnp.float32(float(ahp.buffer_k[g]))))
         st_g, tr_g = run_experiment(step_g, st0, keys[g], iters, record=rec)
         np.testing.assert_array_equal(np.asarray(tr_g["bits_per_node"]),
                                       np.asarray(tr["bits_per_node"][g]))
@@ -434,10 +446,10 @@ def test_async_sweep_matches_independent_async_runs():
     # with exact ledgers + ulp-tolerance iterates above).
     g0 = int(np.argmax((np.asarray(ahp.tau) == 0)
                        & (np.asarray(ahp.buffer_k) == 1.0)))
-    step_g0 = make_flecs_async_step(
-        cfg, LG, LH, StalenessSchedule("fixed", tau=0), buffer_k=1)
+    step_g0 = functools.partial(sweep, AsyncHParams(
+        hparams_from_config(cfg), jnp.int32(0), jnp.float32(1)))
     st_a, tr_a = run_experiment(step_g0, st0, keys[g0], iters, record=rec)
-    st_s, tr_s = run_experiment(make_flecs_step(cfg, LG, LH),
+    st_s, tr_s = run_experiment(_flecs_step(cfg, LG, LH),
                                 init_state(jnp.zeros(D), N), keys[g0],
                                 iters, record=rec)
     np.testing.assert_allclose(np.asarray(tr_s["F"]),
@@ -451,8 +463,9 @@ def test_async_sweep_matches_independent_async_runs():
 
 def test_async_sweep_rejects_undersized_buffer():
     ahp = async_hparam_grid([0, 3], [1.0], alpha=0.5)
-    sweep = make_flecs_async_sweep_step(FlecsConfig(m=1), LG, LH)
-    st0 = init_async_state(jnp.zeros(D), N, 1, max_delay=1)   # 2 slots < 4
+    cfg = FlecsConfig(m=1)
+    sweep = make_async_step(make_flecs_async_hooks(cfg, LG, LH))
+    st0 = _flecs_async_state(cfg, LG, LH, D, N, max_delay=1)  # 2 slots < 4
     with pytest.raises(ValueError):
         run_async_sweep(sweep, ahp, st0, jax.random.key(0), 4)
 
@@ -502,8 +515,8 @@ def test_async_grid_auto_damping_converges():
                       participation=0.5, sampling="choice")
     ahp = async_hparam_grid([0, 2], [2.0, 4.0], alpha=1.0,
                             auto_damp=(cfg.participation, prob.n_workers))
-    sweep = make_flecs_async_sweep_step(cfg, lg, lh)
-    st0 = init_async_state(jnp.zeros(prob.d), prob.n_workers, cfg.m, 2)
+    sweep = make_async_step(make_flecs_async_hooks(cfg, lg, lh))
+    st0 = _flecs_async_state(cfg, lg, lh, prob.d, prob.n_workers, 2)
     f0 = float(prob.global_loss(st0.w))
     sts, tr = run_async_sweep(sweep, ahp, st0, jax.random.key(1), 400,
                               record_every=100,

@@ -245,14 +245,18 @@ print("TRAINER OK", losses)
 def test_federated_logreg_end_to_end():
     """The paper's experiment end-to-end in-process (single device)."""
     from repro.core.driver import run_experiment
-    from repro.core.flecs import FlecsConfig, init_state, make_flecs_step
+    import functools
+
+    from repro.core.flecs import (FlecsConfig, hparams_from_config,
+                                  init_state, make_flecs_sweep_step)
     from repro.data.logreg import make_problem
 
     prob = make_problem(d=50, n_workers=6, r=40, mu=1e-3, seed=1)
     lg, lh = prob.make_oracles()
     cfg = FlecsConfig(m=2, grad_compressor="dither64",
                       hess_compressor="dither64")
-    step = make_flecs_step(cfg, lg, lh)
+    step = functools.partial(make_flecs_sweep_step(cfg, lg, lh),
+                             hparams_from_config(cfg))
     st0 = init_state(jnp.zeros(prob.d), prob.n_workers)
     f0 = float(prob.global_loss(st0.w))
     st, traces = run_experiment(step, st0, jax.random.key(0), 200,
