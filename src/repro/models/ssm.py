@@ -11,7 +11,10 @@ The depthwise conv over x, B and C likewise keeps one weight (and, with
 ``SSMConfig.conv_bias``, one bias) per channel group.
 
 ``ssd_scan`` runs under the ``jax.named_scope`` ``SCOPE_SSD``, so its
-compiled instructions can be read by name in a profiler trace.
+compiled instructions can be read by name in a profiler trace.  On a TPU
+its intra-chunk term and chunk-end states are the Pallas kernels of
+``repro.kernels.ssd`` (forward and backward, under the same scope) where
+``ssd_ops.applies``.
 """
 from __future__ import annotations
 
@@ -19,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ssd import ops as ssd_ops
+from repro.kernels.ssd import ref as ssd_ref
 from repro.models.layers import causal_conv1d, rms_norm
 
 SCOPE_SSD = "ssm.ssd"           # the chunked SSD scan, fwd and bwd
@@ -65,16 +70,6 @@ def init_ssm(key, cfg, dtype):
     return params
 
 
-def _segsum(a):
-    """a: [..., Q] -> lower-triangular cumulative sums [..., Q, Q]:
-    out[i, j] = sum(a[j+1..i]) for i >= j, -inf above diagonal."""
-    Q = a.shape[-1]
-    cs = jnp.cumsum(a, axis=-1)
-    diff = cs[..., :, None] - cs[..., None, :]
-    mask = jnp.tril(jnp.ones((Q, Q), bool))
-    return jnp.where(mask, diff, -jnp.inf)
-
-
 @jax.named_scope(SCOPE_SSD)
 def ssd_scan(xh, dt, A_log, B_mat, C_mat, chunk, init_state=None):
     """Chunked SSD.  xh: [B,S,H,P]; dt: [B,S,H]; B_mat/C_mat: [B,S,N].
@@ -96,15 +91,15 @@ def ssd_scan(xh, dt, A_log, B_mat, C_mat, chunk, init_state=None):
     B_c = B_mat.astype(jnp.float32).reshape(Bb, nc, Q, N)
     C_c = C_mat.astype(jnp.float32).reshape(Bb, nc, Q, N)
 
-    L = jnp.exp(_segsum(a_c))                               # [B,nc,H,Q,Q]
-    # Intra-chunk (diagonal blocks).
-    y_diag = jnp.einsum("bcln,bcsn,bchls,bcshp->bclhp",
-                        C_c, B_c, L, x_c)
-    # Per-chunk end states.
+    # Intra-chunk (diagonal blocks) and per-chunk end states: the Pallas
+    # kernel on a TPU, for the shapes it supports; else XLA with the
+    # [B,nc,H,Q,Q] decay in HBM.
+    if ssd_ops.applies(Q, N, H, P):
+        y_diag, states = ssd_ops.chunk_terms(C_c, B_c, a_c, x_c,
+                                             scope=SCOPE_SSD)
+    else:
+        y_diag, states = ssd_ref.chunk_terms(C_c, B_c, a_c, x_c)
     a_cum = jnp.cumsum(a_c, axis=-1)                        # [B,nc,H,Q]
-    a_tail = a_cum[..., -1:] - a_cum                        # decay to chunk end
-    states = jnp.einsum("bcsn,bchs,bcshp->bchpn",
-                        B_c, jnp.exp(a_tail), x_c)
     # Inter-chunk recurrence.
     decay = jnp.exp(a_cum[..., -1])                         # [B,nc,H]
 

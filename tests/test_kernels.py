@@ -28,6 +28,9 @@ from repro.kernels.dither.ops import dequantize, quantize
 from repro.kernels.dither.ref import dither_decode_ref, dither_encode_ref
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
+from repro.kernels.ssd import ops as ssd_ops
+from repro.kernels.ssd import ref as ssd_ref
+from repro.models import ssm
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -108,6 +111,98 @@ def test_flash_attention_block_size_invariance(rng):
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                    rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# SSD intra-chunk kernel (repro.kernels.ssd) — tolerance suite
+# ---------------------------------------------------------------------------
+
+def _ssd_inputs(rng, B, S, H, P, N, Q):
+    """Chunked C, B, a = dt·A (decays as Mamba-2 draws them) and x·dt."""
+    nc = S // Q
+    C = rng.normal(size=(B, nc, Q, N)) / np.sqrt(N)
+    Bm = rng.normal(size=(B, nc, Q, N)) / np.sqrt(N)
+    dt = rng.uniform(1e-3, 0.1, size=(B, nc, H, Q))
+    a = -dt * np.linspace(1.0, 16.0, H)[None, None, :, None]
+    x = rng.normal(size=(B, nc, Q, H, P)) * dt.transpose(0, 1, 3, 2)[..., None]
+    return tuple(jnp.asarray(v, jnp.float32) for v in (C, Bm, a, x))
+
+
+def _close(got, want, tol):
+    """Within ``tol`` of the largest entry of ``want``, entry by entry."""
+    want = np.asarray(want)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=tol,
+                               atol=tol * float(np.max(np.abs(want))))
+
+
+@pytest.mark.parametrize("B,S,H,P,N,Q", [
+    (1, 256, 2, 64, 128, 128),      # two chunks, one head pair
+    (2, 256, 4, 64, 128, 256),      # one chunk, two head pairs
+    (1, 512, 4, 64, 128, 128),      # four chunks
+    (2, 512, 2, 64, 128, 256),      # the cell's chunk size
+])
+@pytest.mark.parametrize("mxu,tol", [(jnp.float32, 2e-5),
+                                     (jnp.bfloat16, 2e-2)])
+def test_ssd_chunk_terms_match_ref(rng, B, S, H, P, N, Q, mxu, tol):
+    """y_diag and the chunk-end states, and the gradients of a weighted sum
+    of both with respect to C, B, a and x·dt, kernel (fwd and bwd,
+    interpret mode) against the XLA reference.  f32 products agree to 2e-5
+    of the largest entry (the tile sums in another order); bf16 operands,
+    as compiled for the TPU, to 2e-2."""
+    assert ssd_ops.supports(Q, N, H, P)
+    args = _ssd_inputs(rng, B, S, H, P, N, Q)
+    w_y = jnp.asarray(rng.normal(size=args[3].shape), jnp.float32)
+    w_s = jnp.asarray(rng.normal(size=(B, S // Q, H, P, N)), jnp.float32)
+    kern = lambda *v: ssd_ops.chunk_terms(*v, scope=ssm.SCOPE_SSD,  # noqa
+                                          interpret=True, mxu=mxu)
+    for got, want in zip(jax.jit(kern)(*args),
+                         jax.jit(ssd_ref.chunk_terms)(*args)):
+        _close(got, want, tol)
+
+    def loss(f):
+        def weighted(*v):
+            y, st = f(*v)
+            return jnp.sum(y * w_y) + jnp.sum(st * w_s)
+        return jax.jit(jax.grad(weighted, argnums=(0, 1, 2, 3)))
+
+    for got, want in zip(loss(kern)(*args), loss(ssd_ref.chunk_terms)(*args)):
+        _close(got, want, tol)
+
+
+def test_ssd_supports_refuses_unaligned_or_oversize_blocks():
+    assert ssd_ops.supports(256, 128, 64, 64)         # mamba2-1.3b
+    assert ssd_ops.supports(128, 256, 8, 128)
+    assert not ssd_ops.supports(96, 128, 64, 64)      # Q not a lane tile
+    assert not ssd_ops.supports(256, 64, 64, 64)      # N not a lane tile
+    assert not ssd_ops.supports(256, 128, 3, 64)      # a head pair split
+    assert not ssd_ops.supports(256, 128, 8, 12)      # P not in sublanes
+    assert not ssd_ops.supports(1024, 128, 64, 64)    # [Q, Q] tiles too big
+
+
+@pytest.mark.parametrize("tpu,N,path", [(True, 128, "kernel"),
+                                        (True, 5, "ref"),
+                                        (False, 128, "ref")])
+def test_ssd_scan_dispatch(rng, monkeypatch, tpu, N, path):
+    """``ssd_scan`` takes the kernel on a TPU where ``supports`` accepts
+    the shapes, and ``ref.chunk_terms`` otherwise; both give its result."""
+    B, S, H, P, Q = 1, 256, 2, 64, 128
+    xh = jnp.asarray(rng.normal(size=(B, S, H, P)), jnp.float32)
+    dt = jnp.asarray(rng.uniform(0.01, 0.1, size=(B, S, H)), jnp.float32)
+    A_log = jnp.asarray(rng.uniform(-1, 1, size=(H,)), jnp.float32)
+    Bm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32)
+    Cm = jnp.asarray(rng.normal(size=(B, S, N)), jnp.float32)
+    want = ssm.ssd_scan(xh, dt, A_log, Bm, Cm, Q)
+    taken = []
+    kernel, reference = ssd_ops.chunk_terms, ssd_ref.chunk_terms
+    monkeypatch.setattr(ssd_ops, "on_tpu", lambda: tpu)
+    monkeypatch.setattr(ssd_ops, "chunk_terms", lambda *a, **k: (
+        taken.append("kernel"), kernel(*a, **k, interpret=True))[1])
+    monkeypatch.setattr(ssd_ref, "chunk_terms", lambda *a: (
+        taken.append("ref"), reference(*a))[1])
+    got = ssm.ssd_scan(xh, dt, A_log, Bm, Cm, Q)
+    assert taken == [path]
+    for g, w in zip(got, want):
+        _close(g, w, 2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Compile the fused compressor kernels for a described TPU v5e.
+"""Compile the fused compressor kernels and the SSD kernels for a
+described TPU v5e.
 
 Interpret mode (tests/test_kernels.py) proves the kernels bit-identical
 to the jnp reference, but it cannot show what the TPU compiler refuses:
@@ -8,20 +9,28 @@ for one chip of a described ``v5e:2x2`` topology (no chip is attached)
 at the widths the federated engine uses — unbatched, at the size limit
 ``ops.MAX_FUSED_ELEMS``, and vmapped over 16 workers as the sweep engine
 calls them — and assert that the Mosaic kernel is in the compiled
-program (``tpu_custom_call``), not an interpret-mode lowering.
+program (``tpu_custom_call``), not an interpret-mode lowering.  The SSD
+intra-chunk kernels are compiled inside ``jax.grad`` of ``ssd_scan`` at
+the ``mamba2.flecs-cgd`` cell's shapes, forward and backward.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU library, so under several
 pytest workers only the worker that runs this file loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
+from repro.compat import shard_map
 from repro.kernels.compressor import ops
+from repro.kernels.ssd import ops as ssd_ops
+from repro.models import ssm
 
 
 @pytest.fixture(scope="module")
@@ -86,3 +95,43 @@ def test_bits_kernel_compiles_for_v5e(one_chip, name):
     fn = getattr(ops, name)
     scalar = _sds((), jnp.float32, one_chip)
     _assert_mosaic(lambda p, d: fn(p, d, interpret=False), scalar, scalar)
+
+
+@pytest.mark.parametrize("context", ["jit", "dl_shard_map"])
+def test_ssd_kernels_compile_for_v5e_under_the_ssd_scope(one_chip,
+                                                        monkeypatch,
+                                                        context):
+    """``jax.grad`` of ``ssd_scan`` at mamba2-1.3b's widths and the cell's
+    batch (B 8, S 2048, H 64, P 64, N 128, Q 256), under a plain jit and
+    inside the DL trainer's shard_map (data manual, model automatic, where
+    Mosaic lowers only in a shard_map of its own): the forward and the
+    backward Mosaic kernels are in the compiled program, and the
+    ``op_name`` of each carries the ``ssm.ssd`` scope (the backward's
+    through its own ``named_scope``), where ``ssm.ssd_ms`` reads it."""
+    B, S, H, P, N, Q = 8, 2048, 64, 64, 128, 256
+    monkeypatch.setattr(ssd_ops, "on_tpu", lambda: True)
+
+    def loss(xh, dt, A_log, B_mat, C_mat):
+        y, state = ssm.ssd_scan(xh, dt, A_log, B_mat, C_mat, Q)
+        return jnp.sum(y * y) + jnp.sum(state)
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    sharding = one_chip
+    if context == "dl_shard_map":
+        mesh = Mesh(np.asarray(list(one_chip.device_set)).reshape(1, 1),
+                    ("data", "model"))
+        fn = shard_map(fn, mesh, in_specs=PartitionSpec(),
+                       out_specs=PartitionSpec(), axis_names={"data"})
+        sharding = NamedSharding(mesh, PartitionSpec())
+    args = [_sds(s, jnp.float32, sharding) for s in
+            ((B, S, H, P), (B, S, H), (H,), (B, S, N), (B, S, N))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    names = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 2, names
+    for name in names:
+        assert ssm.SCOPE_SSD in [re.sub(r"^(?:[\w.-]*\()+|\)+$", "", part)
+                                 for part in name.split("/")], name
+    bwd, = [n for n in names if "transpose(" in n]
+    assert ssm.SCOPE_SSD in bwd.split("/"), bwd       # its own named_scope
